@@ -106,6 +106,8 @@ def main() -> None:
                          "FACTOR x its baseline (default 2.0)")
     args = ap.parse_args()
 
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     from benchmarks import (fault_bench, fig2_refresh, fig2_timing,
                             fig3_population, fig4_system, fig_bank,
                             fig_region, fleet_bench, framework,

@@ -55,4 +55,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     main()

@@ -19,10 +19,12 @@ Profiling is fully batched through `repro.core.sweep.MarginEngine`:
 `profile()` is one refresh campaign plus ONE fused
 (temperature bins x read/write) timing campaign, and `verify()` is ONE
 dispatch over every (module, bin) pair — no per-bin or per-module
-Python-loop kernel calls anywhere.  `evaluate_system()` closes the
-loop on the system side: the profiled tables feed a batched
-`repro.core.sim_engine` campaign that produces a temperature-resolved
-Fig. 4 in two more dispatches.
+Python-loop kernel calls anywhere.  Past a grid-size budget both run
+their campaign over module groups instead (the paper-scale timing
+campaign is 1.5e9 margin cells, more than one chip holds).
+`evaluate_system()` closes the loop on the system side: the profiled
+tables feed a batched `repro.core.sim_engine` campaign that produces a
+temperature-resolved Fig. 4 in two more dispatches.
 
 `evaluate_dynamic()` goes one step further and exercises the *online*
 half of the mechanism: the profiled per-bin table stack
@@ -51,6 +53,20 @@ from repro.core.sweep import Op, param_reductions
 from repro.core.variation import Population
 
 DEFAULT_TEMP_BINS = (45.0, 55.0, 65.0, 75.0, 85.0)
+
+# Margin-grid cells (tail cells x combo columns) per profiling
+# dispatch.  The paper-scale timing campaign (115 modules x 1536 cells
+# x 8505 columns = 1.5e9 cells) emits two float32 grids of 6.1 GB
+# each, which with their unpadding copies exceed a 16 GB chip, so
+# `profile` runs larger campaigns in module groups of at most this
+# many cells (2 GB of grids per dispatch).
+PROFILE_GRID_ELEMS = 1 << 28
+
+# the per-module selection views of a `SweepResult`, which module
+# groups concatenate along their leading axis
+_MODULE_VIEWS = ("ok", "chosen", "latency_sum", "ok_bank",
+                 "chosen_bank", "latency_sum_bank", "ok_region",
+                 "chosen_region", "latency_sum_region")
 
 
 def default_scenarios():
@@ -481,9 +497,8 @@ class ALDRAMController:
         campaign — the per-bank axis costs zero extra dispatches."""
         prof = self.profiler
         rp_read, rp_write = prof.refresh_campaign(pop, 85.0)
-        res = self.engine.sweep(
-            pop, prof.campaign_spec(self.temp_bins, rp_read, rp_write),
-            regions=self.regions)
+        res = self._sweep(
+            pop, prof.campaign_spec(self.temp_bins, rp_read, rp_write))
         # keep the selection views for reporting (evaluate_bank_system's
         # reduction statistics, tests) but drop the O(cells x combos)
         # raw margin grids — at calibrated scale they are gigabytes the
@@ -533,6 +548,37 @@ class ALDRAMController:
             self.table = TimingTable(self.temp_bins, params_module,
                                      rp_read.safe, rp_write.safe)
         return self.table
+
+    def _sweep(self, pop: Population, spec):
+        """`engine.sweep` of the timing campaign — ONE dispatch up to
+        `PROFILE_GRID_ELEMS` margin cells, else one per module group.
+        Every selection view is per module (a module's envelope reads
+        only its own cells' margins), so the groups' views concatenate
+        to exactly the single-dispatch result, minus the raw margins."""
+        m = pop.n_modules
+        cpm = int(np.prod(pop.cells.shape[1:4]))
+        cols = len(spec.temps) * sum(t.combos.shape[0]
+                                     for t in spec.tests)
+        g = max(1, PROFILE_GRID_ELEMS // (cpm * cols))
+        if g >= m:
+            return self.engine.sweep(pop, spec, regions=self.regions)
+        parts = []
+        for lo in range(0, m, g):
+            sl = slice(lo, min(lo + g, m))
+            tests = tuple(
+                dataclasses.replace(
+                    t, trefi_ms=(None if t.trefi_ms is None
+                                 else t.trefi_per_module(m)[sl]))
+                for t in spec.tests)
+            parts.append(self.engine.sweep(
+                Population(pop.cells[sl]),
+                dataclasses.replace(spec, tests=tests),
+                regions=self.regions))
+        views = {f: tuple(np.concatenate([getattr(r, f)[k] for r in parts])
+                          for k in range(len(getattr(parts[0], f))))
+                 for f in _MODULE_VIEWS}
+        return dataclasses.replace(parts[0], spec=spec, margins=(),
+                                   **views)
 
     # ----------------------------------------------- resolution levels
     def region_table(self, level: int) -> TimingTable:
@@ -717,7 +763,7 @@ class ALDRAMController:
         timing rows in 2 traced dispatches.
 
         Returns per-temperature-bin speedup summaries plus the raw
-        latency/speedup grids.
+        latency/speedup grids and the campaign's `SimResult`.
         """
         from repro.core import dram_sim, perf_model
         if self.table is None:
@@ -757,6 +803,7 @@ class ALDRAMController:
             per_policy.append(d)
         return {"temps": temps, "rows": rows, "speedups": sp,
                 "mean_latency_ns": em["mean_latency_ns"],
+                "result": em["result"],
                 "workloads": em["workloads"], "per_temp": per_policy[0],
                 "per_policy": per_policy, "policies": policies,
                 "source": "profiled-table"}

@@ -163,9 +163,10 @@ REPLAY_SIZE_BINS = (10.0, 12.0, 14.0, 17.0, 24.0)
 
 # candidate 0 is ALWAYS the conservative scan default — it is the
 # static worst case unprofiled bins fall back to
+# (a 64-lane block does not lower on the TPU once the padded lane axis
+# exceeds one block, so the kernel candidates start at 128 lanes)
 _CANDIDATES = {
     "tpu": (ReplayConfig("scan"),
-            ReplayConfig("pallas", 64),
             ReplayConfig("pallas", 128),
             ReplayConfig("pallas", 256),
             ReplayConfig("merged"),
@@ -199,8 +200,12 @@ class ReplayTuner:
 
     def __post_init__(self):
         if not self.candidates:
-            self.candidates = _CANDIDATES.get(
-                self.platform, _CANDIDATES["cpu"])
+            if self.platform not in _CANDIDATES:
+                raise ValueError(
+                    f"ReplayTuner has no candidate list for platform "
+                    f"{self.platform!r} (known: {sorted(_CANDIDATES)}); "
+                    f"pass `candidates` explicitly")
+            self.candidates = _CANDIDATES[self.platform]
         self.table = AdaptiveTable(condition_bins=REPLAY_SIZE_BINS,
                                    static_worst_case=0.0,
                                    higher_is_safer=False)

@@ -198,6 +198,16 @@ def synth_trace(key, n: int, n_banks: int = 8, n_rows: int = 4096,
     return Trace(arrival, bank, row, is_write)
 
 
+def _runtime_knobs(knobs):
+    """The per-stream knob rows as values computed on the device, not
+    compile-time constants.  Under jit the spec's knobs would be
+    constants, and XLA folds what it can compute from them (a tenant
+    mix's log-probabilities) on the host, with the host's math: on a
+    TPU that synthesized 2 of 16 tenant streams differently from the
+    sharded campaign, whose knob rows arrive as device inputs."""
+    return jax.lax.optimization_barrier(knobs)
+
+
 @dataclasses.dataclass(frozen=True)
 class SynthSpec:
     """DECLARATIVE trace batch: the `synth_trace` knobs of every
@@ -273,8 +283,8 @@ class SynthSpec:
 
     def synth(self):
         """The in-dispatch synthesis prologue: [T, n] `Trace` batch as
-        traced arrays (call under jit)."""
-        return self.synth_traced(self.stream_knobs())
+        traced arrays (call under jit) — see `_runtime_knobs`."""
+        return self.synth_traced(_runtime_knobs(self.stream_knobs()))
 
     def materialize(self) -> tuple[Trace, ...]:
         """Host-side tuple-of-`Trace`s view (one synthesis launch,
@@ -405,8 +415,8 @@ class TenantSpec:
 
     def synth(self):
         """The in-dispatch synthesis prologue: [T, n] `Trace` batch as
-        traced arrays (call under jit)."""
-        return self.synth_traced(self.stream_knobs())
+        traced arrays (call under jit) — see `_runtime_knobs`."""
+        return self.synth_traced(_runtime_knobs(self.stream_knobs()))
 
     def materialize(self) -> tuple[Trace, ...]:
         """Host-side tuple-of-`Trace`s view (one synthesis launch,
@@ -1283,10 +1293,13 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
     `aldram.TimingTable.lookup_many`) — or a PER-BANK stack
     [S+1, banks, 6] (`aldram.TimingTable.safe_stack_banks`): the scan
     then gathers row (selected bin, request's bank), so a FLY-DRAM
-    deployment rides the same dispatch; constant-across-banks input
-    replays bit-identical to the [S+1, 6] path.  `bins`: [S] ascending
-    bin edges (C).  `scn_row`: [thermal.SCN_COLS] ambient-scenario row;
-    `tcfg_row`: `thermal.ThermalConfig.as_row()`.
+    deployment rides the same dispatch.  An [S+1, 6] stack replays as
+    the constant per-bank stack through that same gather, so the two
+    forms are bit-identical by construction (the two gathers compiled
+    to different fusions whose bank heat differed in the last bit).
+    `bins`: [S] ascending bin edges (C).  `scn_row`:
+    [thermal.SCN_COLS] ambient-scenario row; `tcfg_row`:
+    `thermal.ThermalConfig.as_row()`.
 
     Per request the scan (1) decays the per-bank heat toward the
     scenario ambient over the inter-arrival gap, (2) senses
@@ -1340,14 +1353,15 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
     (structurally identical across columns, so degradation semantics
     match the per-bank stack exactly)."""
     from repro.core.power import access_energy_from_terms
-    from repro.core.thermal import ambient_at
+    from repro.core.thermal import ambient_at, heat_decay, overheat_sum
     tau, c_heat, hyst_c = tcfg_row[0], tcfg_row[1], tcfg_row[2]
     e_burst, e_act_pre, p_as = tcfg_row[3], tcfg_row[4], tcfg_row[5]
     hyst = hyst_c * scn_row[8]                   # per-scenario scale
-    banked = table.ndim == 3
     regioned = region_map is not None
+    if table.ndim == 2:
+        table = jnp.broadcast_to(jnp.asarray(table)[:, None, :],
+                                 (table.shape[0], n_banks, 6))
     if regioned:
-        assert banked, "region_map requires an [S+1, U, 6] stack"
         region_map = region_map.reshape(-1)
         n_regions = region_map.shape[0] // n_banks
         assert region_map.shape[0] == n_banks * n_regions
@@ -1368,13 +1382,13 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
         if faulted:
             carry, fstate = carry
             lag_p, held_p, psen_p, wd, cnt = fstate
-            t, b, r, w, v, u_k, k_idx = req
+            t, b, r, w, v, amb, decay, u_k, k_idx = req
         else:
-            t, b, r, w, v = req
+            t, b, r, w, v, amb, decay = req
         s, cf = carry if multi else (carry, None)
         dt = jnp.maximum(t - s.t_prev, 0.0)
-        heat = s.heat * jnp.exp(-dt / tau)
-        sensed = ambient_at(scn_row, t) + heat.sum()
+        heat = s.heat * decay
+        sensed = amb + overheat_sum(heat)
         if faulted:
             reading, lag2, held2 = faults.fault_sensor(
                 f_row, t, dt, sensed, lag_p, held_p, k_idx)
@@ -1396,13 +1410,12 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
             u_col = region_map[b * n_regions + region_of(r, n_regions)]
             tp = table[use_bin, u_col]
         else:
-            tp = table[use_bin, b] if banked else table[use_bin]
+            tp = table[use_bin, b]
         if faulted:
             if regioned:
                 jed = table[n_rows_t - 1, u_col]
             else:
-                jed = table[n_rows_t - 1, b] if banked \
-                    else table[n_rows_t - 1]
+                jed = table[n_rows_t - 1, b]
             jsum = jed[0] + jed[1] + jed[2] + jed[3]
             red = jnp.maximum(
                 1.0 - (tp[0] + tp[1] + tp[2] + tp[3]) / jsum, 0.0)
@@ -1431,8 +1444,11 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
         miss = 1.0 - is_hit.astype(jnp.float32)
         energy = access_energy_from_terms(e_burst, e_act_pre, p_as,
                                           miss, tp[1])
-        s2 = AdaptiveState(bank=s2b,
-                           heat=heat.at[gb].add(c_heat * energy),
+        # a one-hot masked add, as the kernel deposits it (a scatter-add
+        # here rounded the bank heat differently from the kernel)
+        deposit = jnp.where(jnp.arange(nb_tot) == gb, c_heat * energy,
+                            0.0)
+        s2 = AdaptiveState(bank=s2b, heat=heat + deposit,
                            cur_bin=new_bin.astype(jnp.int32),
                            t_prev=t + 0.0)
         c2 = (s2, cf.at[ch].set(done - tp[5] + t_burst)) if multi \
@@ -1463,7 +1479,11 @@ def replay_adaptive(arrival, bank, row, is_write, valid, table, bins,
                        cur_bin=jnp.zeros((), jnp.int32),
                        t_prev=jnp.zeros(()))
     carry0 = (s0, jnp.zeros((n_channels,))) if multi else s0
-    xs = (arrival, bank, row, is_write, valid)
+    # the thermal drive (ambient, RC decay over each arrival gap) as
+    # precomputed streams — the transcendentals stay out of the scan,
+    # computed exactly as the Pallas kernel's launcher computes them
+    xs = (arrival, bank, row, is_write, valid,
+          ambient_at(scn_row, arrival), heat_decay(arrival, tau))
     if faulted:
         no_r = jnp.asarray(faults.NO_READING, jnp.float32)
         carry0 = (carry0, (no_r, no_r, no_r, faults.wd_state0(),

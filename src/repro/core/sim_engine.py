@@ -40,8 +40,9 @@ device-resident:
 device stats match it within 1e-5 relative (the raw latency grid is
 bit-identical either way — only the reduction order differs).
 `backend="pallas"` swaps the vmapped `lax.scan` replay for the
-`repro.kernels.replay` Pallas kernel (interpret-mode fallback off-TPU);
-the adaptive (thermal) path always uses the scan.
+`repro.kernels.replay` Pallas kernels compiled for the TPU (static, and
+single-channel adaptive); it raises off the TPU, where
+`backend="pallas_interpret"` runs the same kernel bodies on the host.
 
 Attaching a `thermal.ThermalSpec` opens the fourth campaign axis —
 thermal scenarios — and switches the replay to the closed-loop
@@ -562,6 +563,20 @@ def _p99_k(valid: np.ndarray) -> int:
     return int((c - lo).max())
 
 
+def _valid_count(valid, ndim: int):
+    """(valid mask broadcast against an ndim-rank [T, ..., N] grid,
+    per-trace valid count as float32 [T, 1, ...]).  The count passes an
+    optimization barrier: a fused synthetic campaign's all-True mask is
+    a compile-time constant, and XLA would then rewrite `sum / cnt` as
+    `sum * (1 / cnt)` — one ulp away from the materialized twin's
+    division."""
+    mid = (1,) * (ndim - 2)
+    v = valid.reshape((valid.shape[0],) + mid + (valid.shape[1],))
+    cnt = valid.sum(-1).astype(jnp.float32).reshape(
+        (valid.shape[0],) + mid)
+    return v, jax.lax.optimization_barrier(cnt)
+
+
 def _device_stats(lat, valid, k: int):
     """In-dispatch masked mean / interpolated p99 over the last axis.
     Same interpolation arithmetic as the host `_masked_stats`
@@ -572,10 +587,7 @@ def _device_stats(lat, valid, k: int):
     the selected VALUES are identical (order statistics don't depend
     on how they're found) and XLA's top-k is ~20x cheaper than its
     sort on a [grid, N] latency tensor."""
-    mid = (1,) * (lat.ndim - 2)
-    v = valid.reshape((valid.shape[0],) + mid + (valid.shape[1],))
-    cnt = valid.sum(-1).astype(jnp.float32).reshape(
-        (valid.shape[0],) + mid)
+    v, cnt = _valid_count(valid, lat.ndim)
     mean = jnp.where(v, lat, 0.0).sum(-1) / cnt
     # descending top-k; -inf padding sorts last, so entry j is the
     # (j+1)-th largest VALID latency and ascending position i maps to
@@ -601,10 +613,7 @@ def _device_thermal_diag(temps, bin_sel, valid):
     (temp_max [grid], temp_mean [grid], bin_switches [grid]).  max and
     switch counts are exact; the mean matches the host loop within
     float-reduction noise."""
-    mid = (1,) * (temps.ndim - 2)
-    v = valid.reshape((valid.shape[0],) + mid + (valid.shape[1],))
-    cnt = valid.sum(-1).astype(jnp.float32).reshape(
-        (valid.shape[0],) + mid)
+    v, cnt = _valid_count(valid, temps.ndim)
     tmax = jnp.where(v, temps, -jnp.inf).max(-1)
     tmean = jnp.where(v, temps, 0.0).sum(-1) / cnt
     pair = v[..., 1:] & v[..., :-1]          # padding is a suffix
@@ -774,10 +783,11 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
     else:
         a3, b3, r3, w3 = arrival, bank, row, is_write
 
-    # the adaptive Pallas kernel is single-channel: multi-channel
-    # adaptive campaigns ride the (channelized) scan instead
     if n_ch * n_rk > 1 and backend in ("pallas", "pallas_interpret"):
-        backend = "scan"
+        raise ValueError(
+            f"backend={backend!r}: the adaptive replay kernel is "
+            f"single-channel; replay this {n_ch}-channel, "
+            f"{n_rk}-rank adaptive campaign with backend='scan'")
     diag = None
     cnt = None
     if backend in ("pallas", "pallas_interpret"):
@@ -988,7 +998,6 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
     `pmax`es the per-scenario temperature peaks across shards between
     the two replay halves, so worst-bin provisioning still sees the
     GLOBAL peak."""
-    from jax.experimental.shard_map import shard_map
     P_ = jax.sharding.PartitionSpec
     (synth, n_banks, mlp_window, plan, backend, want, p99_k, bs, chan,
      n_real) = statics
@@ -1041,8 +1050,8 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
     out_specs = (sh if kind != "bracket" else
                  {"adaptive": sh, "static": sh, "worst_bin": rep,
                   "temp_peak": rep})
-    return shard_map(body, mesh=mesh, in_specs=(sh, rep),
-                     out_specs=out_specs, check_rep=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=(sh, rep),
+                         out_specs=out_specs, check_vma=False)(
         per_stream, extras)
 
 
@@ -1122,11 +1131,13 @@ class SimEngine:
 
       backend — "scan" (default: vmapped lax.scan), "merged"
                 (FR-FCFS fused into the replay scan — no [T, P, N]
-                streams materialize), "pallas" / "pallas_interpret"
-                (the repro.kernels.replay kernels, static AND
-                adaptive; plain "pallas" falls back to interpret mode
-                off-TPU), "auto" (the attached `tuner`'s profiled
-                choice, else pallas on TPU / scan elsewhere).
+                streams materialize), "pallas" (the
+                repro.kernels.replay kernels compiled for the TPU,
+                static AND single-channel adaptive; raises off the
+                TPU), "pallas_interpret" (the same kernel bodies run
+                on the host), "auto" (the attached `tuner`'s profiled
+                choice, else pallas on TPU — scan for a multi-channel
+                adaptive campaign — and scan elsewhere).
       stats   — "device" (default: in-dispatch reductions, only
                 [grid]-shaped summaries transferred, raw grids gated
                 by SimSpec.collect) or "host" (bit-exact numpy
@@ -1195,7 +1206,10 @@ class SimEngine:
         answers with the profiled candidate for this campaign's
         (kind, size) bin — falling back, AdaptiveTable-style, to
         candidate 0 (the conservative scan default) on unprofiled
-        bins; plain "pallas" degrades to interpret mode off-TPU."""
+        bins.  "auto" without a tuner picks the kernel on the TPU
+        (the scan for a multi-channel adaptive campaign, which the
+        single-channel adaptive kernel cannot replay) and the scan
+        elsewhere; an explicit "pallas" off the TPU raises."""
         cfg = config
         if cfg is None and self.backend == "auto" and \
                 self.tuner is not None:
@@ -1205,11 +1219,18 @@ class SimEngine:
         else:
             backend, fuse, bs = cfg.backend, cfg.fuse_synth, \
                 cfg.block_rows
-        on_tpu = jax.default_backend() == "tpu"
+        platform = jax.default_backend()
         if backend == "auto":
-            backend = "pallas" if on_tpu else "scan"
-        if backend == "pallas" and not on_tpu:
-            backend = "pallas_interpret"  # CPU fallback: kernel body
+            multi_adaptive = (spec.thermal is not None
+                              and spec.n_channels * spec.n_ranks > 1)
+            backend = ("pallas" if platform == "tpu" and not multi_adaptive
+                       else "scan")
+        if backend == "pallas" and platform != "tpu":
+            raise ValueError(
+                f"backend='pallas' compiles the replay kernels for a "
+                f"TPU, and JAX's default backend is {platform!r}; ask "
+                f"for backend='pallas_interpret' to run the kernel "
+                f"bodies on the host")
         return backend, fuse, bs
 
     def _backend(self) -> str:

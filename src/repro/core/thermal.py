@@ -198,6 +198,34 @@ def ambient_at(scn_row, t):
     return base + sin_part + step_part + burst_part
 
 
+def heat_decay(arrival, tau):
+    """Per-request RC decay factor of a request stream: exp(-dt / tau)
+    over the gap to the previous request in issue order (the first one
+    decays from t = 0), [..., N] -> [..., N].
+
+    The adaptive replay cores (the `dram_sim.replay_adaptive` scan and
+    the Pallas kernel) take this and `ambient_at` as precomputed
+    streams, so the transcendental functions run once, vectorized, in
+    the same XLA lowering for both — computed inside the kernel they
+    would come from the kernel compiler's own `exp`/`sin`, which need
+    not round like XLA's, and one ulp of sensed temperature can move a
+    bin edge."""
+    prev = jnp.concatenate([jnp.zeros_like(arrival[..., :1]),
+                            arrival[..., :-1]], axis=-1)
+    dt = jnp.maximum(arrival - prev, 0.0)
+    return jnp.exp(-dt / tau)
+
+
+def overheat_sum(heat):
+    """Sum of the per-bank overheat over the leading bank axis, added
+    in bank order: the scan ([B]) and the kernel ([B, lanes]) then
+    round identically, which a reduction leaves to the backend."""
+    total = heat[0]
+    for i in range(1, heat.shape[0]):
+        total = total + heat[i]
+    return total
+
+
 def ambient_at_host(scn: ThermalScenario, t: float) -> float:
     """Host-side reference of `ambient_at` (used by tests and by the
     static-worst-case bin estimate)."""

@@ -15,10 +15,10 @@ pure-jnp oracle on CPU, then unpad.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.charge import ChargeConstants, DEFAULT_CONSTANTS
+from repro.kernels import resolve_impl
 from repro.kernels.charge_sim import charge_sim, ref
 
 
@@ -53,12 +53,11 @@ def margin_sweep(cells: jnp.ndarray, combos: jnp.ndarray,
     trefi_read_cells / trefi_write_cells: optional [n] per-cell refresh
     intervals for the read / write test (folds per-module, per-op safe
     refresh intervals into one batched sweep).
-    impl: 'auto' (pallas on TPU, ref elsewhere), 'pallas' (compiled),
-    'pallas_interpret' (kernel body on CPU — used by kernel tests),
-    'ref'.
+    impl: 'auto' (pallas on TPU, ref elsewhere), 'pallas' (compiled
+    for the TPU; raises elsewhere), 'pallas_interpret' (kernel body on
+    the host — used by kernel tests), 'ref'.
     """
-    if impl == "auto":
-        impl = ("pallas" if jax.default_backend() == "tpu" else "ref")
+    impl = resolve_impl(impl)
     if impl == "ref":
         return ref.margin_sweep(cells, combos, temps_combo, constants,
                                 trefi_read_cells, trefi_write_cells)

@@ -10,17 +10,17 @@ unpads/reshapes the outputs back to the scan path's [T, P, S, N] /
 [T, P, S] shapes — so the two backends are drop-in interchangeable
 inside the one-dispatch campaign.
 
-impl: 'auto' (pallas on TPU, ref elsewhere), 'pallas' (compiled),
-'pallas_interpret' (kernel body on CPU — the off-TPU fallback and the
-parity-test mode), 'ref' (vmapped lax.scan oracle).
+impl: 'auto' (pallas on TPU, ref elsewhere), 'pallas' (compiled for
+the TPU; raises elsewhere), 'pallas_interpret' (kernel body on the
+host — the parity-test mode), 'ref' (vmapped lax.scan oracle).
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.dram_sim import check_prefix_valid
+from repro.kernels import resolve_impl
 from repro.kernels.replay import ref, replay
 
 
@@ -62,8 +62,7 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
     gathers through it in VMEM.
     """
     check_prefix_valid(valid, "replay_grid")
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         return ref.replay_grid(arrival, bank, row, is_write, valid,
                                timings, closed, n_banks, mlp_window,
@@ -180,8 +179,7 @@ def replay_grid_adaptive(arrival, bank, row, is_write, valid, tables,
     VMEM.
     """
     check_prefix_valid(valid, "replay_grid_adaptive")
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         out = ref.replay_grid_adaptive(
             arrival, bank, row, is_write, valid, tables, bins, scns,

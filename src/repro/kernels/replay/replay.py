@@ -12,8 +12,10 @@ whole controller state lives in VMEM scratch as [banks, lanes] /
   done_ring (bounded-MLP completion gate): [mlp_window, BLOCK_ROWS]
 
 A `fori_loop` walks the N requests of the stream; per request the
-scalar (arrival, bank, row, is_write, valid) fields broadcast against
-the lane axis, the bank/ring rows are selected with one-hot sublane
+scalar (arrival, bank, row, is_write, valid) fields — read from the
+cell's [1, 1, N] SMEM blocks, since a VMEM block cannot serve a scalar
+at a dynamic lane offset — broadcast against the lane axis, the
+bank/ring rows are selected with one-hot sublane
 masks (no dynamic lane indexing), and the per-request service
 arithmetic mirrors `repro.core.dram_sim._service` operation for
 operation — the kernel is numerics-parity-tested against the vmapped
@@ -34,7 +36,7 @@ Multi-channel campaigns (`chan=(n_channels, n_ranks, t_burst)` with
 C*R > 1) widen the state tiles to [C*R*n_banks, BLOCK_ROWS] — the
 global FSM index is (channel*n_ranks + rank)*n_banks + bank, computed
 in-loop by `dram_sim.chan_rank` from the per-policy interleave code
-(an `il_ref` scalar-prefetch column) — and add one [n_channels,
+(the `il_ref` per-cell SMEM column) — and add one [n_channels,
 BLOCK_ROWS] bus-free scratch tile: the issue gate maxes in the
 request's channel-bus row (selected by the same one-hot trick, here
 over the channel axis) and the bus stays busy for `t_burst` after
@@ -43,10 +45,15 @@ each data transfer.  Per-bank timing tables keep their rank-level
 per-channel.  C*R == 1 compiles the exact single-channel kernel (the
 channel branches are static).
 
-VMEM per grid step: 5 request streams of N float32/int32 + the
-[6, 128] timing tile + the [N, 128] latency out tile + ~14 KB of
-state scratch (x C*R on the bank tiles for multi-channel) — ~4.3 MB
-at N = 8192, under the ~16 MB budget.
+Per-cell flags (closed page, interleave code) and the small constant
+rows (JEDEC row, thermal constants) sit whole in SMEM; per-lane
+outputs are [G, 1, lanes] rows so every block meets the (8, 128)
+tiling.  On-chip memory per program: the request fields in SMEM
+(double-buffered, 40 B per request, 48 B with faults, of 1 MiB) and the
+[N, 128] latency tile in VMEM (double-buffered, 1 KiB per request; the
+adaptive kernel adds its [N, lanes] ambient input, and two more for
+the raw temperature/bin traces) under a 64 MiB scoped-VMEM limit — `max_requests` turns both into the largest
+N a launch accepts, and larger launches raise before lowering.
 """
 
 from __future__ import annotations
@@ -61,10 +68,66 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import faults
 from repro.core.dram_sim import chan_rank, region_of, service_math
 from repro.core.power import access_energy_from_terms
-from repro.core.thermal import ambient_at
+from repro.core.thermal import ambient_at, heat_decay, overheat_sum
 
 # Timing rows per program, on the 128-lane minor axis.
 BLOCK_ROWS = 128
+
+# On-chip memory of one replay program.  Each request field of a cell
+# ([N] int32/float32) is double-buffered in SMEM; each raw [N, lanes]
+# output tile (latency, and the adaptive kernel's raw temperature/bin
+# traces) is double-buffered in VMEM, a lane block padding to 128
+# lanes.  The reserves cover the flags, tables and state tiles.
+VMEM_LIMIT_BYTES = 64 * 2**20
+_SMEM_BYTES = 2**20
+_SMEM_RESERVE = 64 * 2**10
+_VMEM_RESERVE = 4 * 2**20
+
+
+def max_requests(n_fields: int, n_raw: int) -> int:
+    """Largest request count N per stream that a launch with
+    `n_fields` per-request fields in SMEM and `n_raw` [N, lanes] tiles
+    in VMEM holds on chip.  Static kernel (5 fields, the latency
+    tile): 24576, 20480 with faults (6 fields).  Adaptive kernel (6
+    fields with the heat decay; latency + ambient tiles): 20480, 17554
+    with faults, 15360 with the raw temperature/bin traces (4
+    tiles)."""
+    smem = (_SMEM_BYTES - _SMEM_RESERVE) // (n_fields * 4 * 2)
+    vmem = (VMEM_LIMIT_BYTES - _VMEM_RESERVE) // (n_raw * 128 * 4 * 2)
+    return min(smem, vmem)
+
+
+def _check_requests(n: int, n_fields: int, n_raw: int, name: str):
+    cap = max_requests(n_fields, n_raw)
+    if n > cap:
+        raise ValueError(
+            f"{name}: {n} requests per stream exceed the {cap} one "
+            f"program holds on chip ({n_fields} request fields in "
+            f"SMEM, {n_raw} [N, lanes] tiles in VMEM); split the "
+            f"traces or replay them with backend='scan'")
+
+
+# A whole small array (per-cell page/interleave flags, the JEDEC row,
+# the thermal constants) held in SMEM and read as scalars.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _cell_stream(n: int) -> pl.BlockSpec:
+    """One cell's [1, 1, N] request field of a [G, 1, N] stream, in
+    SMEM: the per-request loop reads it one scalar at a time, which a
+    VMEM block cannot serve at a dynamic lane offset."""
+    return pl.BlockSpec((1, 1, n), lambda i, j: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _lane_row(bs: int) -> pl.BlockSpec:
+    """One cell's lane block of a [G, 1, L] per-lane output row."""
+    return pl.BlockSpec((1, 1, bs), lambda i, j: (i, 0, j))
+
+
+def _cells3(*streams):
+    """[G, N] request streams -> the [G, 1, N] layout of `_cell_stream`."""
+    return [x.reshape(x.shape[0], 1, x.shape[1]) for x in streams]
 
 
 def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
@@ -95,7 +158,8 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
     n_ch, n_rk, t_burst = chan
     multi = n_ch * n_rk > 1          # static: C*R == 1 keeps the
     nb_tot = n_ch * n_rk * n_banks   # original single-channel kernel
-    closed = closed_ref[0, 0] > 0.5
+    cell = pl.program_id(0)
+    closed = closed_ref[cell] > 0.5
     if not banked:
         trcd, tras, twr, trp, tcl = (tim_ref[0, :], tim_ref[1, :],
                                      tim_ref[2, :], tim_ref[3, :],
@@ -109,7 +173,7 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
         uniq_iota = jax.lax.broadcasted_iota(
             jnp.int32, (tim_ref.shape[0], bs), 0)
     if multi:
-        il = il_ref[0, 0]
+        il = il_ref[cell]
         # the timing tile stays keyed on the rank-level bank id
         bank_iota_b = jax.lax.broadcasted_iota(jnp.int32,
                                                (n_banks, bs), 0)
@@ -124,22 +188,21 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
     cf_s[...] = jnp.zeros((n_ch, bs), jnp.float32)
     if faulted:
         flt = flt_ref[...]                    # [F_COLS, bs] lane rows
-        j6 = (jed_ref[0, 0], jed_ref[1, 0], jed_ref[2, 0],
-              jed_ref[3, 0], jed_ref[5, 0])
-        jsum = (jed_ref[0, 0] + jed_ref[1, 0] + jed_ref[2, 0]
-                + jed_ref[3, 0])
+        j6 = (jed_ref[0], jed_ref[1], jed_ref[2], jed_ref[3],
+              jed_ref[5])
+        jsum = jed_ref[0] + jed_ref[1] + jed_ref[2] + jed_ref[3]
         for r_ in (det_ref, sil_ref, trp_ref, deg_ref, prb_ref):
-            r_[...] = jnp.zeros((1, bs), jnp.int32)
+            r_[...] = jnp.zeros((1, 1, bs), jnp.int32)
         for s_ in (wde_s, wdb_s, wdc_s, wdp_s, wdt_s):
             s_[...] = jnp.zeros((1, bs), jnp.int32)
 
     def body(k, _):
-        t = arr_ref[0, k]
-        b = bank_ref[0, k]
-        r_i = row_ref[0, k]
+        t = arr_ref[0, 0, k]
+        b = bank_ref[0, 0, k]
+        r_i = row_ref[0, 0, k]
         rf = r_i.astype(jnp.float32)
-        w = wr_ref[0, k] > 0
-        v = val_ref[0, k] > 0
+        w = wr_ref[0, 0, k] > 0
+        v = val_ref[0, 0, k] > 0
         if multi:
             # global FSM index of the request's (channel, rank, bank)
             ch, rank = chan_rank(b, r_i, il, n_ch, n_rk, n_banks)
@@ -189,7 +252,7 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
             red = jnp.maximum(
                 1.0 - (tc[0] + tc[1] + tc[2] + tc[3]) / jsum, 0.0)
             p_e = faults.error_prob(flt, red, 0.0)
-            _e, det, sil = faults.error_draw(flt, u_ref[0, k], p_e)
+            _e, det, sil = faults.error_draw(flt, u_ref[0, 0, k], p_e)
             sur = jnp.where(det, j6[4] + flt[faults.RETRY_NS], 0.0)
 
         # the per-request timing model itself is the SHARED elementwise
@@ -227,26 +290,23 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
             wdp_s[0, :] = jnp.where(v, wd2[3], wd[3])
             wdt_s[0, :] = jnp.where(v, wd2[4], wd[4])
             vi = v.astype(jnp.int32)
-            det_ref[0, :] = det_ref[0, :] + det.astype(jnp.int32) * vi
-            sil_ref[0, :] = sil_ref[0, :] + sil.astype(jnp.int32) * vi
-            trp_ref[0, :] = (trp_ref[0, :]
-                             + new_trip.astype(jnp.int32) * vi)
-            deg_ref[0, :] = (deg_ref[0, :]
-                             + degraded.astype(jnp.int32) * vi)
-            prb_ref[0, :] = (prb_ref[0, :]
-                             + is_probe.astype(jnp.int32) * vi)
+            for r_, f_ in zip((det_ref, sil_ref, trp_ref, deg_ref,
+                               prb_ref),
+                              (det, sil, new_trip, degraded, is_probe)):
+                r_[0, 0, :] = r_[0, 0, :] + f_.astype(jnp.int32) * vi
 
         lat_ref[0, k, :] = jnp.where(v, lat, 0.0)
         return 0
 
     jax.lax.fori_loop(0, n_req, body, 0)
-    total_ref[0, :] = jnp.maximum(jnp.max(rdy_s[...], axis=0),
-                                  jnp.max(wrd_s[...], axis=0))
+    total_ref[0, 0, :] = jnp.maximum(jnp.max(rdy_s[...], axis=0),
+                                     jnp.max(wrd_s[...], axis=0))
 
 
 def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
-                     val_ref, tim_ref, scn_ref, bins_ref, tcfg_ref,
-                     *refs, n_banks: int, mlp_window: int, n_req: int,
+                     val_ref, dec_ref, amb_ref, tim_ref, scn_ref,
+                     bins_ref, tcfg_ref, *refs, n_banks: int,
+                     mlp_window: int, n_req: int,
                      banked: bool, emit_raw: bool,
                      faulted: bool = False, regioned: bool = False):
     """Closed-loop (adaptive) replay cell: the static kernel's layout
@@ -295,21 +355,20 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
     if faulted:
         det_ref, sil_ref, trp_ref, deg_ref, prb_ref = refs[:5]
         del refs[:5]
-    (open_s, act_s, wrd_s, rdy_s, ring_s, heat_s, bin_s,
-     tprev_s) = refs[:8]
-    del refs[:8]
+    (open_s, act_s, wrd_s, rdy_s, ring_s, heat_s, bin_s, tprev_s,
+     tcomp_s) = refs[:9]
+    del refs[:9]
     if faulted:
         (lag_s, held_s, psen_s, pbin_s, wde_s, wdb_s, wdc_s, wdp_s,
          wdt_s) = refs
     bs = lat_ref.shape[-1]
     n_bins = tim_ref.shape[-3]                 # S+1 (JEDEC row last)
-    closed = closed_ref[0, 0] > 0.5
+    closed = closed_ref[pl.program_id(0)] > 0.5
     scn = scn_ref[...]                         # [SCN_COLS, bs]
     bins_t = bins_ref[...]                     # [S(pad), bs]
-    tau, c_heat = tcfg_ref[0, 0], tcfg_ref[1, 0]
-    e_burst, e_act_pre, p_as = (tcfg_ref[3, 0], tcfg_ref[4, 0],
-                                tcfg_ref[5, 0])
-    hyst = tcfg_ref[2, 0] * scn[8]             # per-scenario scale [bs]
+    c_heat = tcfg_ref[1]
+    e_burst, e_act_pre, p_as = tcfg_ref[3], tcfg_ref[4], tcfg_ref[5]
+    hyst = tcfg_ref[2] * scn[8]                # per-scenario scale [bs]
     bank_iota = jax.lax.broadcasted_iota(jnp.int32, (n_banks, bs), 0)
     ring_iota = jax.lax.broadcasted_iota(jnp.int32, (mlp_window, bs), 0)
     bin_iota = jax.lax.broadcasted_iota(jnp.int32, (n_bins, bs), 0)
@@ -329,9 +388,10 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
     heat_s[...] = jnp.zeros((n_banks, bs), jnp.float32)
     bin_s[...] = jnp.zeros((1, bs), jnp.int32)
     tprev_s[...] = jnp.zeros((1, bs), jnp.float32)
-    tmax_ref[...] = jnp.full((1, bs), -jnp.inf, jnp.float32)
-    tmean_ref[...] = jnp.zeros((1, bs), jnp.float32)   # sum until /cnt
-    sw_ref[...] = jnp.zeros((1, bs), jnp.int32)
+    tmax_ref[...] = jnp.full((1, 1, bs), -jnp.inf, jnp.float32)
+    tmean_ref[...] = jnp.zeros((1, 1, bs), jnp.float32)  # sum until /cnt
+    tcomp_s[...] = jnp.zeros((1, bs), jnp.float32)
+    sw_ref[...] = jnp.zeros((1, 1, bs), jnp.int32)
     if faulted:
         flt = flt_ref[...]                  # [F_COLS, bs] lane rows
         # the JEDEC fallback row is a STATIC index (last in the stack)
@@ -345,16 +405,17 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
         psen_s[...] = no_r
         pbin_s[...] = jnp.zeros((1, bs), jnp.int32)
         for r_ in (det_ref, sil_ref, trp_ref, deg_ref, prb_ref):
-            r_[...] = jnp.zeros((1, bs), jnp.int32)
+            r_[...] = jnp.zeros((1, 1, bs), jnp.int32)
         for s_ in (wde_s, wdb_s, wdc_s, wdp_s, wdt_s):
             s_[...] = jnp.zeros((1, bs), jnp.int32)
 
-    def body(k, _):
-        t = arr_ref[0, k]
-        b = bank_ref[0, k]
-        rf = row_ref[0, k].astype(jnp.float32)
-        w = wr_ref[0, k] > 0
-        v = val_ref[0, k] > 0
+    def body(k, n_valid):
+        t = arr_ref[0, 0, k]
+        b = bank_ref[0, 0, k]
+        r_i = row_ref[0, 0, k]
+        rf = r_i.astype(jnp.float32)
+        w = wr_ref[0, 0, k] > 0
+        v = val_ref[0, 0, k] > 0
         bm = bank_iota == b
         rm = ring_iota == (k % mlp_window)
 
@@ -362,8 +423,8 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
         # sense ambient + summed bank overheat, re-select the bin
         tprev = tprev_s[0, :]
         dt = jnp.maximum(t - tprev, 0.0)
-        heat = heat_s[...] * jnp.exp(-dt / tau)[None, :]
-        sensed = ambient_at(scn, t) + jnp.sum(heat, axis=0)
+        heat = heat_s[...] * dec_ref[0, 0, k]
+        sensed = amb_ref[0, k, :] + overheat_sum(heat)
         if faulted:
             # the controller reads the FAULTED sensor register
             lag_p, held_p, psen_p = (lag_s[0, :], held_s[0, :],
@@ -395,7 +456,7 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
         if regioned:
             # chained one-hot gather: (bank, region) slot -> unique
             # column index (per lane, via the map tile) -> bin row
-            g_id = b * n_regions + region_of(row_ref[0, k], n_regions)
+            g_id = b * n_regions + region_of(r_i, n_regions)
             u_lane = jnp.sum(jnp.where(map_iota == g_id, map_ref[...],
                                        0), axis=0)       # [bs] int32
             tmask = uniq_iota == u_lane[None, :]
@@ -423,7 +484,7 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
             edge = jnp.where(use_bin >= n_bins - 1, jnp.inf, edge)
             excess = jnp.maximum(sensed - edge, 0.0)
             p_e = faults.error_prob(flt, red, excess)
-            _e, det, sil = faults.error_draw(flt, u_ref[0, k], p_e)
+            _e, det, sil = faults.error_draw(flt, u_ref[0, 0, k], p_e)
             sur = jnp.where(det, jed[5] + flt[faults.RETRY_NS], 0.0)
 
         open_b = jnp.sum(jnp.where(bm, open_s[...], 0.0), axis=0)
@@ -478,41 +539,43 @@ def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
             wdp_s[0, :] = jnp.where(v, wd2[3], wd[3])
             wdt_s[0, :] = jnp.where(v, wd2[4], wd[4])
             vi = v.astype(jnp.int32)
-            det_ref[0, :] = det_ref[0, :] + det.astype(jnp.int32) * vi
-            sil_ref[0, :] = sil_ref[0, :] + sil.astype(jnp.int32) * vi
-            trp_ref[0, :] = (trp_ref[0, :]
-                             + new_trip.astype(jnp.int32) * vi)
-            deg_ref[0, :] = (deg_ref[0, :]
-                             + degraded.astype(jnp.int32) * vi)
-            prb_ref[0, :] = (prb_ref[0, :]
-                             + is_probe.astype(jnp.int32) * vi)
+            for r_, f_ in zip((det_ref, sil_ref, trp_ref, deg_ref,
+                               prb_ref),
+                              (det, sil, new_trip, degraded, is_probe)):
+                r_[0, 0, :] = r_[0, 0, :] + f_.astype(jnp.int32) * vi
 
         # diagnostics accumulate in their own output tiles; the temp
         # stats and raw traces report the CONTROLLER's view (the
         # faulted reading, the bin actually served) — exactly what the
         # scan path emits
-        tmax_ref[0, :] = jnp.maximum(tmax_ref[0, :],
-                                     jnp.where(v, reading, -jnp.inf))
-        tmean_ref[0, :] = tmean_ref[0, :] + jnp.where(v, reading, 0.0)
+        tmax_ref[0, 0, :] = jnp.maximum(tmax_ref[0, 0, :],
+                                        jnp.where(v, reading, -jnp.inf))
+        # compensated (Kahan) sum: a plain float32 running sum over N
+        # requests drifts ~N ulps (7.9e-5 relative at N = 8192 on the
+        # chip), far from the scan's tree reduction
+        acc = tmean_ref[0, 0, :]
+        y = jnp.where(v, reading, 0.0) - tcomp_s[0, :]
+        acc2 = acc + y
+        tcomp_s[0, :] = (acc2 - acc) - y
+        tmean_ref[0, 0, :] = acc2
         if faulted:
             pb = pbin_s[0, :]
-            sw_ref[0, :] = sw_ref[0, :] + (
+            sw_ref[0, 0, :] = sw_ref[0, 0, :] + (
                 (use_bin != pb) & v & (k > 0)).astype(jnp.int32)
             pbin_s[0, :] = jnp.where(v, use_bin, pb)
         else:
-            sw_ref[0, :] = sw_ref[0, :] + (
+            sw_ref[0, 0, :] = sw_ref[0, 0, :] + (
                 (new_bin != cur) & v & (k > 0)).astype(jnp.int32)
         lat_ref[0, k, :] = jnp.where(v, lat, 0.0)
         if emit_raw:
             traw_ref[0, k, :] = jnp.where(v, reading, 0.0)
             braw_ref[0, k, :] = jnp.where(v, use_bin, -1)
-        return 0
+        return n_valid + v.astype(jnp.int32)
 
-    jax.lax.fori_loop(0, n_req, body, 0)
-    total_ref[0, :] = jnp.maximum(jnp.max(rdy_s[...], axis=0),
-                                  jnp.max(wrd_s[...], axis=0))
-    cnt = jnp.sum(val_ref[0, :]).astype(jnp.float32)
-    tmean_ref[0, :] = tmean_ref[0, :] / cnt
+    cnt = jax.lax.fori_loop(0, n_req, body, jnp.int32(0))
+    total_ref[0, 0, :] = jnp.maximum(jnp.max(rdy_s[...], axis=0),
+                                     jnp.max(wrd_s[...], axis=0))
+    tmean_ref[0, 0, :] = tmean_ref[0, 0, :] / cnt.astype(jnp.float32)
     heat_ref[0, :, :] = heat_s[...]
 
 
@@ -552,6 +615,8 @@ def adaptive_blocks(closed_col, arrival, bank, row, is_write, valid,
         (tables_t.shape, bs)
     if banked and not regioned:
         assert tables_t.shape[0] == n_banks, (tables_t.shape, n_banks)
+    _check_requests(n, 6 + faulted, 4 if emit_raw else 2,
+                    "adaptive_blocks")
     grid = (g, length // bs)
     kernel = functools.partial(_adaptive_kernel, n_banks=n_banks,
                                mlp_window=mlp_window, n_req=n,
@@ -562,38 +627,39 @@ def adaptive_blocks(closed_col, arrival, bank, row, is_write, valid,
                 if banked else
                 pl.BlockSpec((n_bins, 6, bs), lambda i, j: (0, 0, j)))
     s_bins = bins_t.shape[0]
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),      # closed
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # arrival
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # bank
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # row
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # is_write
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # valid
+    # closed, the five request streams + the per-request heat decay,
+    # the per-lane ambient tile, the table tile, ...
+    in_specs = [_SMEM] + [_cell_stream(n)] * 6 + [
+        pl.BlockSpec((1, n, bs), lambda i, j: (i, 0, j)),  # ambient
         tab_spec,                                       # table tile
         pl.BlockSpec((scn_t.shape[0], bs), lambda i, j: (0, j)),
         pl.BlockSpec((s_bins, bs), lambda i, j: (0, j)),  # bins
-        pl.BlockSpec((6, 1), lambda i, j: (0, 0)),      # tcfg
+        _SMEM,                                          # tcfg
     ]
-    inputs = [closed_col, arrival, bank, row, is_write, valid,
-              tables_t, scn_t, bins_t, tcfg_col]
+    # the thermal drive, precomputed exactly as the scan precomputes it
+    decay = heat_decay(arrival, tcfg_col[0, 0])            # [G, N]
+    ambient = ambient_at(scn_t, arrival[:, :, None])       # [G, N, L]
+    inputs = ([closed_col.reshape(g)]
+              + _cells3(arrival, bank, row, is_write, valid, decay)
+              + [ambient, tables_t, scn_t, bins_t, tcfg_col.reshape(-1)])
     if regioned:
         in_specs.append(pl.BlockSpec((region_map.shape[0], bs),
                                      lambda i, j: (0, j)))
         inputs.append(region_map)
     out_specs = [
         pl.BlockSpec((1, n, bs), lambda i, j: (i, 0, j)),   # lat
-        pl.BlockSpec((1, bs), lambda i, j: (i, j)),         # total
-        pl.BlockSpec((1, bs), lambda i, j: (i, j)),         # tmax
-        pl.BlockSpec((1, bs), lambda i, j: (i, j)),         # tmean
-        pl.BlockSpec((1, bs), lambda i, j: (i, j)),         # switches
+        _lane_row(bs),                                      # total
+        _lane_row(bs),                                      # tmax
+        _lane_row(bs),                                      # tmean
+        _lane_row(bs),                                      # switches
         pl.BlockSpec((1, n_banks, bs), lambda i, j: (i, 0, j)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((g, n, length), jnp.float32),
-        jax.ShapeDtypeStruct((g, length), jnp.float32),
-        jax.ShapeDtypeStruct((g, length), jnp.float32),
-        jax.ShapeDtypeStruct((g, length), jnp.float32),
-        jax.ShapeDtypeStruct((g, length), jnp.int32),
+        jax.ShapeDtypeStruct((g, 1, length), jnp.float32),
+        jax.ShapeDtypeStruct((g, 1, length), jnp.float32),
+        jax.ShapeDtypeStruct((g, 1, length), jnp.float32),
+        jax.ShapeDtypeStruct((g, 1, length), jnp.int32),
         jax.ShapeDtypeStruct((g, n_banks, length), jnp.float32),
     ]
     scratch = [
@@ -605,6 +671,7 @@ def adaptive_blocks(closed_col, arrival, bank, row, is_write, valid,
         pltpu.VMEM((n_banks, bs), jnp.float32),   # RC bank heat
         pltpu.VMEM((1, bs), jnp.int32),           # current bin
         pltpu.VMEM((1, bs), jnp.float32),         # last arrival
+        pltpu.VMEM((1, bs), jnp.float32),         # temp-sum compensation
     ]
     if emit_raw:
         out_specs += [pl.BlockSpec((1, n, bs), lambda i, j: (i, 0, j)),
@@ -615,23 +682,28 @@ def adaptive_blocks(closed_col, arrival, bank, row, is_write, valid,
         flt_t, u = fault
         in_specs += [
             pl.BlockSpec((flt_t.shape[0], bs), lambda i, j: (0, j)),
-            pl.BlockSpec((1, n), lambda i, j: (i, 0)),   # uniforms
+            _cell_stream(n),                             # uniforms
         ]
-        inputs += [flt_t, u]
-        out_specs += [pl.BlockSpec((1, bs),
-                                   lambda i, j: (i, j))] * 5
-        out_shape += [jax.ShapeDtypeStruct((g, length), jnp.int32)] * 5
+        inputs += [flt_t] + _cells3(u)
+        out_specs += [_lane_row(bs)] * 5
+        out_shape += [jax.ShapeDtypeStruct((g, 1, length), jnp.int32)] * 5
         scratch += ([pltpu.VMEM((1, bs), jnp.float32)] * 3   # lag/held
                     + [pltpu.VMEM((1, bs), jnp.int32)] * 6)  # pbin+wd
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*inputs)
+    # [G, 1, L] lane rows -> [G, L]
+    rows = (1, 2, 3, 4) + ((tuple(range(len(out) - 5, len(out))))
+                           if faulted else ())
+    return tuple(x[:, 0] if i in rows else x for i, x in enumerate(out))
 
 
 @functools.partial(jax.jit,
@@ -670,6 +742,7 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
     assert timings_t.shape[-2] == 6 and s % bs == 0, (timings_t.shape, bs)
     if banked and not regioned:
         assert timings_t.shape[0] == n_banks, (timings_t.shape, n_banks)
+    _check_requests(n, 5 + faulted, 1, "replay_blocks")
     grid = (g, s // bs)
     kernel = functools.partial(_kernel, n_banks=n_banks,
                                mlp_window=mlp_window, n_req=n,
@@ -679,29 +752,22 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
                              lambda i, j: (0, 0, j))
                 if banked else
                 pl.BlockSpec((6, bs), lambda i, j: (0, j)))
-    in_specs = [
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),      # closed
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),      # ileave
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # arrival
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # bank
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # row
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # is_write
-        pl.BlockSpec((1, n), lambda i, j: (i, 0)),      # valid
-        tim_spec,                                       # timing tile
-    ]
-    inputs = [closed_col, ileave_col, arrival, bank, row, is_write,
-              valid, timings_t]
+    # closed, ileave, the five request streams, the timing tile
+    in_specs = [_SMEM, _SMEM] + [_cell_stream(n)] * 5 + [tim_spec]
+    inputs = ([closed_col.reshape(g), ileave_col.reshape(g)]
+              + _cells3(arrival, bank, row, is_write, valid)
+              + [timings_t])
     if regioned:
         in_specs.append(pl.BlockSpec((region_map.shape[0], bs),
                                      lambda i, j: (0, j)))
         inputs.append(region_map)
     out_specs = [
         pl.BlockSpec((1, n, bs), lambda i, j: (i, 0, j)),
-        pl.BlockSpec((1, bs), lambda i, j: (i, j)),
+        _lane_row(bs),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((g, n, s), jnp.float32),
-        jax.ShapeDtypeStruct((g, s), jnp.float32),
+        jax.ShapeDtypeStruct((g, 1, s), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((nb_tot, bs), jnp.float32),    # open_row
@@ -715,20 +781,23 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
         flt_t, jed_col, u = fault
         in_specs += [
             pl.BlockSpec((flt_t.shape[0], bs), lambda i, j: (0, j)),
-            pl.BlockSpec((6, 1), lambda i, j: (0, 0)),   # JEDEC row
-            pl.BlockSpec((1, n), lambda i, j: (i, 0)),   # uniforms
+            _SMEM,                                       # JEDEC row
+            _cell_stream(n),                             # uniforms
         ]
-        inputs += [flt_t, jed_col, u]
-        out_specs += [pl.BlockSpec((1, bs),
-                                   lambda i, j: (i, j))] * 5
-        out_shape += [jax.ShapeDtypeStruct((g, s), jnp.int32)] * 5
+        inputs += [flt_t, jed_col.reshape(-1)] + _cells3(u)
+        out_specs += [_lane_row(bs)] * 5
+        out_shape += [jax.ShapeDtypeStruct((g, 1, s), jnp.int32)] * 5
         scratch += [pltpu.VMEM((1, bs), jnp.int32)] * 5   # watchdog
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*inputs)
+    # [G, 1, S] lane rows -> [G, S]
+    return (out[0],) + tuple(x[:, 0] for x in out[1:])

@@ -7,22 +7,31 @@ tier), everything latency-sensitive stays inside a pod.
 
 Defined as functions, not module constants, so importing never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
+Every axis is `Auto`: the model code places arrays through sharding
+constraints (`repro.pspec.constrain`) and lets the compiler propagate
+the rest, which `jax.make_mesh`'s default `Explicit` axes refuse.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names, for CPU tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_campaign_mesh(n_devices: int | None = None):
@@ -38,4 +47,4 @@ def make_campaign_mesh(n_devices: int | None = None):
     if n_devices is not None:
         assert 1 <= n_devices <= len(devs), (n_devices, len(devs))
         devs = devs[:n_devices]
-    return jax.make_mesh((len(devs),), ("campaign",), devices=devs)
+    return _mesh((len(devs),), ("campaign",), devices=devs)

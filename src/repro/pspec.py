@@ -1,7 +1,7 @@
 """Sharding-constraint helpers usable from model code.
 
 `constrain(x, *axes)` applies a `with_sharding_constraint` when running
-under a mesh (pjit / jax.set_mesh); it is a no-op otherwise, so model
+under a mesh (`jax.set_mesh`); it is a no-op otherwise, so model
 code stays runnable in plain CPU tests.  Axis names follow the
 production mesh ("pod", "data", "model"); the data-parallel group is
 ("pod","data") when the pod axis exists.
@@ -13,34 +13,14 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def _active_mesh():
-    """The active (abstract or physical) mesh, or None.
-
-    jax >= 0.5 exposes `jax.sharding.get_abstract_mesh`; on older
-    releases fall back to the thread-local physical mesh that the
-    `with mesh:` context manager sets."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    try:
-        from jax.interpreters.pxla import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except (ImportError, AttributeError):
-        return None
-
-
 def _mesh_axes() -> frozenset[str]:
-    m = _active_mesh()
-    return frozenset(m.axis_names) if m is not None and m.axis_names else frozenset()
+    m = jax.sharding.get_abstract_mesh()
+    return frozenset(m.axis_names) if not m.empty else frozenset()
 
 
 def set_mesh(mesh):
-    """Context manager activating `mesh`: `jax.set_mesh` on jax >= 0.5,
-    the Mesh object's own context manager (thread-local physical mesh)
-    on older releases."""
-    sm = getattr(jax, "set_mesh", None)
-    return sm(mesh) if sm is not None else mesh
+    """Context manager activating `mesh` for `constrain`."""
+    return jax.set_mesh(mesh)
 
 
 def dp_axes() -> tuple[str, ...]:
@@ -68,8 +48,8 @@ def resolve(*spec) -> P:
 
 
 def axis_size(name: str) -> int:
-    m = _active_mesh()
-    if m is None or name not in (m.axis_names or ()):
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or name not in m.axis_names:
         return 1
     return m.shape[name]
 

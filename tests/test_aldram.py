@@ -117,6 +117,38 @@ class TestController:
         assert controller.verify(small_pop)
         assert calls["n"] == 1 and calls["rows"][0] == (m * cpm, m * cols)
 
+    @pytest.mark.parametrize("regions", [1, 2])
+    def test_profile_chunked_module_groups(self, small_pop, monkeypatch,
+                                           regions):
+        """A grid budget below the campaign's size runs the timing
+        campaign over module groups — tables and selection views are
+        bit-identical to the single dispatch."""
+        from repro.core import aldram
+
+        def make():
+            return ALDRAMController(
+                Profiler(constants=CALIBRATED_CONSTANTS, grid_step=2.5),
+                temp_bins=(55.0, 85.0), regions=regions)
+
+        one = make()
+        one.profile(small_pop)
+        cpm = int(np.prod(small_pop.cells.shape[1:4]))
+        cols = 2 * sum(t.combos.shape[0]
+                       for t in one.sweep_result.spec.tests)
+        monkeypatch.setattr(aldram, "PROFILE_GRID_ELEMS", 3 * cpm * cols)
+        grp = make()
+        grp.profile(small_pop)
+        # refresh + ceil(10 / 3) module groups
+        assert grp.engine.dispatch_count == 1 + 4
+        assert np.array_equal(one.table.params, grp.table.params)
+        assert np.array_equal(one.table.module_params,
+                              grp.table.module_params)
+        for f in aldram._MODULE_VIEWS:
+            for a, b in zip(getattr(one.sweep_result, f),
+                            getattr(grp.sweep_result, f)):
+                assert np.array_equal(a, b), f
+        assert grp.sweep_result.margins == ()
+
     def test_reductions_deeper_when_cooler(self, controller):
         r55 = controller.average_reductions(55.0)
         r85 = controller.average_reductions(85.0)

@@ -175,9 +175,10 @@ class TestAdaptiveKernel:
 
 class TestEngineBackend:
     def test_pallas_backend_passes_parity_suite(self):
-        """SimEngine(backend='pallas') — interpret fallback off-TPU —
-        replays the same campaign as the scan backend, raw latencies
-        and summaries alike, with FR-FCFS reorder in the mix."""
+        """SimEngine(backend='pallas_interpret') — the kernel bodies on
+        the host — replays the same campaign as the scan backend, raw
+        latencies and summaries alike, with FR-FCFS reorder in the
+        mix."""
         traces = (dram_sim.synth_trace(jax.random.PRNGKey(0), 128),
                   dram_sim.synth_trace(jax.random.PRNGKey(1), 96,
                                        row_hit=0.2))
@@ -188,7 +189,7 @@ class TestEngineBackend:
                       Policy(reorder_window=4)),
             collect=("latencies",))
         scan = SimEngine().run(spec)
-        pallas = SimEngine(backend="pallas").run(spec)
+        pallas = SimEngine(backend="pallas_interpret").run(spec)
         np.testing.assert_allclose(pallas.latencies, scan.latencies,
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(pallas.mean_latency_ns,
@@ -208,14 +209,14 @@ class TestEngineBackend:
             return real(*a, **k)
 
         monkeypatch.setattr(sim_engine, "_replay_grid", spy)
-        SimEngine(backend="pallas").run(
+        SimEngine(backend="pallas_interpret").run(
             SimSpec(traces=(dram_sim.synth_trace(
                 jax.random.PRNGKey(2), 64),), timings=DDR3_1600))
         assert calls["replay"] == 1
 
     def test_adaptive_campaign_runs_kernel_with_scan_parity(self,
                                                             monkeypatch):
-        """backend='pallas' routes the adaptive (thermal) campaign
+        """The kernel backend routes the adaptive (thermal) campaign
         through the adaptive kernel — no scan fallback — and its
         stats match the scan backend's, FR-FCFS reorder included."""
         calls = {"adaptive": 0}
@@ -238,7 +239,7 @@ class TestEngineBackend:
                            diurnal(40.0, 90.0, period_ns=2.0e4)),
                 temp_bins=(55.0,),
                 config=ThermalConfig(tau_ns=5.0e3, c_heat=2.0e-4)))
-        res_pl = SimEngine(backend="pallas").run(spec)
+        res_pl = SimEngine(backend="pallas_interpret").run(spec)
         assert calls["adaptive"] >= 1, "adaptive kernel never invoked"
         res_sc = SimEngine().run(spec)
         for f in ("mean_latency_ns", "p99_latency_ns", "total_ns",
@@ -247,3 +248,70 @@ class TestEngineBackend:
                                        getattr(res_sc, f), rtol=1e-5,
                                        atol=1e-4, err_msg=f)
         assert np.array_equal(res_pl.bin_switches, res_sc.bin_switches)
+
+
+class TestNoSilentFallback:
+    """Off the TPU a request for the compiled kernels raises; nothing
+    reroutes it to interpret mode, the scan or the jnp reference."""
+
+    @staticmethod
+    def _spec(**kw):
+        return SimSpec(traces=(dram_sim.synth_trace(
+            jax.random.PRNGKey(5), 32),), timings=DDR3_1600, **kw)
+
+    def test_pallas_backend_raises_off_tpu(self):
+        assert jax.default_backend() != "tpu"
+        with pytest.raises(ValueError, match="pallas_interpret"):
+            SimEngine(backend="pallas").run(self._spec())
+
+    @pytest.mark.parametrize("wrapper", ["static", "adaptive"])
+    def test_replay_impl_pallas_raises_off_tpu(self, wrapper):
+        if wrapper == "static":
+            args = _grid_inputs(1, 1, 32, 1)
+            fn = replay_ops.replay_grid
+        else:
+            args = _adaptive_inputs(t=1, p=1, n=32, k=1, s=2)
+            fn = replay_ops.replay_grid_adaptive
+        with pytest.raises(ValueError, match="pallas_interpret"):
+            fn(*args, impl="pallas")
+
+    def test_margin_impl_pallas_raises_off_tpu(self):
+        from repro.kernels.charge_sim import ops as charge_ops
+        cells = jnp.ones((4, 5), jnp.float32)
+        combos = jnp.asarray(np.stack([DDR3_1600.as_array()] * 2))
+        with pytest.raises(ValueError, match="pallas_interpret"):
+            charge_ops.combo_margins(cells, combos, 55.0, impl="pallas")
+
+    def test_multichannel_adaptive_kernel_raises(self):
+        """The adaptive kernel is single-channel: a multi-channel
+        adaptive campaign that asks for it raises instead of riding
+        the scan."""
+        stack = np.stack([ALDRAM_55C_EVAL.as_row(),
+                          DDR3_1600.as_row()])[None]
+        spec = SimSpec(
+            traces=(dram_sim.synth_trace(jax.random.PRNGKey(6), 32),),
+            timings=stack, n_channels=2,
+            thermal=ThermalSpec(scenarios=(steady(48.0),),
+                                temp_bins=(55.0,)))
+        with pytest.raises(ValueError, match="single-channel"):
+            SimEngine(backend="pallas_interpret").run(spec)
+        # the scan replays it when asked
+        res = SimEngine(backend="scan").run(spec)
+        assert np.isfinite(res.mean_latency_ns).all()
+
+    def test_tuner_refuses_unknown_platform(self):
+        from repro.core.autotune import ReplayTuner
+        with pytest.raises(ValueError, match="no candidate list"):
+            ReplayTuner(platform="gpu", path="")
+
+    def test_request_count_above_chip_limit_raises(self):
+        """Above the SMEM/VMEM bound a launch fails with a clear error
+        before any compiler sees it."""
+        from repro.kernels.replay import replay
+        n = replay.max_requests(5, 1) + 1
+        z = jnp.zeros((1, n), jnp.float32)
+        zi = jnp.zeros((1, n), jnp.int32)
+        with pytest.raises(ValueError, match="requests per stream"):
+            replay.replay_blocks(
+                jnp.zeros((1, 1)), jnp.zeros((1, 1), jnp.int32), z, zi,
+                zi, zi, zi, jnp.zeros((6, 8)), bs=8, interpret=True)
