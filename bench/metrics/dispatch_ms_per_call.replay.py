@@ -1,0 +1,20 @@
+"""dispatch_ms_per_call.replay (ms): per entry call, the self time of
+the program's `sim.dispatch` spans: the host side of launching the
+synthesis and replay programs, the transfer of their arguments
+included (program spans, `repro.core.spans`, summed in the run's
+process over the traced window)."""
+
+NAMES = ("sim.dispatch",)
+SCALE = 1e3
+
+
+def value(ctx: dict):
+    try:
+        from repro.core import spans
+    except ImportError:                 # a program without spans
+        return None
+    s = spans.summary()
+    if not ctx["trace"] or not s["roots"] or s["roots"] != ctx["calls"]:
+        return None
+    got = [s["spans"][n]["self_s"] for n in NAMES if n in s["spans"]]
+    return sum(got) * SCALE / s["roots"] if got else None

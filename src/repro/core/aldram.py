@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.core import timing as T
 from repro.core.profiler import Profiler
+from repro.core.spans import span
 from repro.core.sweep import Op, param_reductions
 from repro.core.variation import Population
 
@@ -495,59 +496,62 @@ class ALDRAMController:
         """Build the full (module x bin[, bank]) table from one refresh
         campaign and ONE fused multi-temperature, read+write timing
         campaign — the per-bank axis costs zero extra dispatches."""
-        prof = self.profiler
-        rp_read, rp_write = prof.refresh_campaign(pop, 85.0)
-        res = self._sweep(
-            pop, prof.campaign_spec(self.temp_bins, rp_read, rp_write))
-        # keep the selection views for reporting (evaluate_bank_system's
-        # reduction statistics, tests) but drop the O(cells x combos)
-        # raw margin grids — at calibrated scale they are gigabytes the
-        # controller would otherwise pin for its whole lifetime
-        self.sweep_result = dataclasses.replace(res, margins=())
-        kr, kw = res.index(Op.READ), res.index(Op.WRITE)
+        with span("aldram.profile", modules=pop.n_modules):
+            prof = self.profiler
+            rp_read, rp_write = prof.refresh_campaign(pop, 85.0)
+            res = self._sweep(
+                pop, prof.campaign_spec(self.temp_bins, rp_read, rp_write))
+            # keep the selection views for reporting (evaluate_bank_system's
+            # reduction statistics, tests) but drop the O(cells x combos)
+            # raw margin grids — at calibrated scale they are gigabytes the
+            # controller would otherwise pin for its whole lifetime
+            self.sweep_result = dataclasses.replace(res, margins=())
+            kr, kw = res.index(Op.READ), res.index(Op.WRITE)
 
-        def combine(cr, cw):
-            # one register set must satisfy both tests: take the safer
-            # (larger) of the read/write choices per parameter
-            p = np.empty(cr.shape[:-1] + (4,), np.float32)
-            p[..., 0] = np.maximum(cr[..., 0], cw[..., 0])
-            p[..., 1] = cr[..., 1]               # tRAS: read test
-            p[..., 2] = cw[..., 2]               # tWR: write test
-            p[..., 3] = np.maximum(cr[..., 3], cw[..., 3])
-            return p
+            def combine(cr, cw):
+                # one register set must satisfy both tests: take the safer
+                # (larger) of the read/write choices per parameter
+                p = np.empty(cr.shape[:-1] + (4,), np.float32)
+                p[..., 0] = np.maximum(cr[..., 0], cw[..., 0])
+                p[..., 1] = cr[..., 1]               # tRAS: read test
+                p[..., 2] = cw[..., 2]               # tWR: write test
+                p[..., 3] = np.maximum(cr[..., 3], cw[..., 3])
+                return p
 
-        params_module = combine(res.chosen[kr], res.chosen[kw])
-        if self.regions > 1:
-            # [modules, banks, bins, 4] -> [modules, bins, banks, 4]
-            params_bank = combine(res.chosen_bank[kr],
-                                  res.chosen_bank[kw]).transpose(0, 2, 1, 3)
-            # [modules, banks, regions, bins, 4]
-            # -> [modules, bins, banks * regions, 4], mask-compressed
-            # per (module, bin) into the unique-row store + index map
-            from repro.runtime.compression import compress_rows
-            m = params_module.shape[0]
-            dense = combine(res.chosen_region[kr], res.chosen_region[kw]
-                            ).transpose(0, 3, 1, 2, 4)
-            nb, banks, regions = dense.shape[1:4]
-            store, idx = compress_rows(
-                dense.reshape(m, nb, banks * regions, 4))
-            self.table = TimingTable(
-                self.temp_bins, store.astype(np.float32),
-                rp_read.safe, rp_write.safe,
-                params_module=params_module,
-                region_index=idx.reshape(m, nb, banks, regions),
-                params_bank=params_bank)
-        elif self.per_bank:
-            # [modules, banks, bins, 4] -> [modules, bins, banks, 4]
-            params_bank = combine(res.chosen_bank[kr],
-                                  res.chosen_bank[kw]).transpose(0, 2, 1, 3)
-            self.table = TimingTable(self.temp_bins, params_bank,
-                                     rp_read.safe, rp_write.safe,
-                                     params_module=params_module)
-        else:
-            self.table = TimingTable(self.temp_bins, params_module,
-                                     rp_read.safe, rp_write.safe)
-        return self.table
+            params_module = combine(res.chosen[kr], res.chosen[kw])
+            if self.regions > 1:
+                # [modules, banks, bins, 4] -> [modules, bins, banks, 4]
+                params_bank = combine(res.chosen_bank[kr],
+                                      res.chosen_bank[kw]
+                                      ).transpose(0, 2, 1, 3)
+                # [modules, banks, regions, bins, 4]
+                # -> [modules, bins, banks * regions, 4], mask-compressed
+                # per (module, bin) into the unique-row store + index map
+                from repro.runtime.compression import compress_rows
+                m = params_module.shape[0]
+                dense = combine(res.chosen_region[kr], res.chosen_region[kw]
+                                ).transpose(0, 3, 1, 2, 4)
+                nb, banks, regions = dense.shape[1:4]
+                store, idx = compress_rows(
+                    dense.reshape(m, nb, banks * regions, 4))
+                self.table = TimingTable(
+                    self.temp_bins, store.astype(np.float32),
+                    rp_read.safe, rp_write.safe,
+                    params_module=params_module,
+                    region_index=idx.reshape(m, nb, banks, regions),
+                    params_bank=params_bank)
+            elif self.per_bank:
+                # [modules, banks, bins, 4] -> [modules, bins, banks, 4]
+                params_bank = combine(res.chosen_bank[kr],
+                                      res.chosen_bank[kw]
+                                      ).transpose(0, 2, 1, 3)
+                self.table = TimingTable(self.temp_bins, params_bank,
+                                         rp_read.safe, rp_write.safe,
+                                         params_module=params_module)
+            else:
+                self.table = TimingTable(self.temp_bins, params_module,
+                                         rp_read.safe, rp_write.safe)
+            return self.table
 
     def _sweep(self, pop: Population, spec):
         """`engine.sweep` of the timing campaign — ONE dispatch up to
@@ -766,47 +770,50 @@ class ALDRAMController:
         latency/speedup grids and the campaign's `SimResult`.
         """
         from repro.core import dram_sim, perf_model
-        if self.table is None:
-            self.profile(pop)
-        tbl = self.table
-        temps = tuple(temps if temps is not None else tbl.temp_bins)
-        policies = policies or (dram_sim.OPEN_FCFS,)
-        m = tbl.params.shape[0]
-        rows = np.empty((1 + len(temps), 6), np.float32)
-        rows[0] = T.DDR3_1600.as_row()
-        mods = np.arange(m)
-        for si, tc in enumerate(temps):
-            # all-safe row: max over modules per parameter at this bin
-            rows[1 + si] = tbl.lookup_many(mods, np.full(m, tc)).max(axis=0)
-
-        em = perf_model.evaluate_many(rows, n=n, seed=seed, engine=engine,
-                                      policies=policies,
-                                      n_banks=pop.n_banks)
-        sp = perf_model.cpi_speedups(em["mean_latency_ns"])
-        intensive = np.array([w.intensive for w in perf_model.WORKLOADS])
-        # summaries for EVERY policy of the campaign; `per_temp` is the
-        # first policy's view (the headline the benchmarks report)
-        per_policy = []
-        for pi in range(len(policies)):
-            d = {}
+        with span("aldram.evaluate_system") as s:
+            if self.table is None:
+                self.profile(pop)
+            tbl = self.table
+            temps = tuple(temps if temps is not None else tbl.temp_bins)
+            policies = policies or (dram_sim.OPEN_FCFS,)
+            m = tbl.params.shape[0]
+            rows = np.empty((1 + len(temps), 6), np.float32)
+            rows[0] = T.DDR3_1600.as_row()
+            mods = np.arange(m)
             for si, tc in enumerate(temps):
-                s_multi = sp[1, :, pi, 1 + si]       # multi-core
-                d[float(tc)] = {
-                    "multi_intensive_gmean":
-                        perf_model.gmean_speedup(s_multi[intensive]),
-                    "multi_nonintensive_gmean":
-                        perf_model.gmean_speedup(s_multi[~intensive]),
-                    "multi_all_gmean": perf_model.gmean_speedup(s_multi),
-                    "single_all_gmean":
-                        perf_model.gmean_speedup(sp[0, :, pi, 1 + si]),
-                }
-            per_policy.append(d)
-        return {"temps": temps, "rows": rows, "speedups": sp,
-                "mean_latency_ns": em["mean_latency_ns"],
-                "result": em["result"],
-                "workloads": em["workloads"], "per_temp": per_policy[0],
-                "per_policy": per_policy, "policies": policies,
-                "source": "profiled-table"}
+                # all-safe row: max over modules per parameter at this bin
+                rows[1 + si] = tbl.lookup_many(
+                    mods, np.full(m, tc)).max(axis=0)
+            s.count(rows=len(rows), policies=len(policies))
+
+            em = perf_model.evaluate_many(rows, n=n, seed=seed, engine=engine,
+                                          policies=policies,
+                                          n_banks=pop.n_banks)
+            sp = perf_model.cpi_speedups(em["mean_latency_ns"])
+            intensive = np.array([w.intensive for w in perf_model.WORKLOADS])
+            # summaries for EVERY policy of the campaign; `per_temp` is the
+            # first policy's view (the headline the benchmarks report)
+            per_policy = []
+            for pi in range(len(policies)):
+                d = {}
+                for si, tc in enumerate(temps):
+                    s_multi = sp[1, :, pi, 1 + si]       # multi-core
+                    d[float(tc)] = {
+                        "multi_intensive_gmean":
+                            perf_model.gmean_speedup(s_multi[intensive]),
+                        "multi_nonintensive_gmean":
+                            perf_model.gmean_speedup(s_multi[~intensive]),
+                        "multi_all_gmean": perf_model.gmean_speedup(s_multi),
+                        "single_all_gmean":
+                            perf_model.gmean_speedup(sp[0, :, pi, 1 + si]),
+                    }
+                per_policy.append(d)
+            return {"temps": temps, "rows": rows, "speedups": sp,
+                    "mean_latency_ns": em["mean_latency_ns"],
+                    "result": em["result"],
+                    "workloads": em["workloads"], "per_temp": per_policy[0],
+                    "per_policy": per_policy, "policies": policies,
+                    "source": "profiled-table"}
 
     # -------------------------------------------------- per-bank closure
     def evaluate_bank_system(self, pop: Population,
@@ -1046,20 +1053,22 @@ class ALDRAMController:
         dispatch (`SimEngine.run_bracket`).
         """
         from repro.core import dram_sim, perf_model, thermal
-        if self.table is None:
-            self.profile(pop)
-        if scenarios is None:
-            scenarios = default_scenarios()
-        policies = policies or (dram_sim.OPEN_FCFS,)
-        rows, bins = (self.table.safe_stack_banks() if per_bank
-                      else self.table.safe_stack())
-        out = perf_model.evaluate_adaptive(
-            rows, bins, scenarios, config=config, n=n, seed=seed,
-            engine=engine, policies=policies, n_banks=pop.n_banks,
-            fused=fused)
-        out["source"] = "profiled-table-dynamic"
-        out["policies"] = policies
-        return out
+        with span("aldram.evaluate_dynamic") as s:
+            if self.table is None:
+                self.profile(pop)
+            scenarios = tuple(default_scenarios() if scenarios is None
+                              else scenarios)
+            policies = policies or (dram_sim.OPEN_FCFS,)
+            s.count(scenarios=len(scenarios), policies=len(policies))
+            rows, bins = (self.table.safe_stack_banks() if per_bank
+                          else self.table.safe_stack())
+            out = perf_model.evaluate_adaptive(
+                rows, bins, scenarios, config=config, n=n, seed=seed,
+                engine=engine, policies=policies, n_banks=pop.n_banks,
+                fused=fused)
+            out["source"] = "profiled-table-dynamic"
+            out["policies"] = policies
+            return out
 
     # ----------------------------------------------------------- reporting
     def average_reductions(self, temp_c: float,

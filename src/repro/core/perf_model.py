@@ -50,6 +50,7 @@ from repro.core import dram_sim
 from repro.core import thermal as TH
 from repro.core import timing as T
 from repro.core.sim_engine import SimEngine, SimResult, SimSpec
+from repro.core.spans import span
 from repro.core.timing import ALDRAM_55C_EVAL, DDR3_1600, TimingParams
 
 
@@ -214,11 +215,12 @@ def trace_batch(n: int = 8192, seed: int = 0,
     global synth_dispatch_count
     offs, rhs, wfs, ias = _pool_knobs()
     synth_dispatch_count += 1
-    return _synth_batch(jax.random.PRNGKey(seed), n, n_banks,
-                        jnp.asarray(offs, jnp.int32),
-                        jnp.asarray(rhs, jnp.float32),
-                        jnp.asarray(wfs, jnp.float32),
-                        jnp.asarray(ias, jnp.float32))
+    with span("sim.dispatch"):
+        return _synth_batch(jax.random.PRNGKey(seed), n, n_banks,
+                            jnp.asarray(offs, jnp.int32),
+                            jnp.asarray(rhs, jnp.float32),
+                            jnp.asarray(wfs, jnp.float32),
+                            jnp.asarray(ias, jnp.float32))
 
 
 def synth_spec(n: int = 8192, seed: int = 0,

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core import timing as T
 from repro.core.charge import ChargeConstants, DEFAULT_CONSTANTS
+from repro.core.spans import span
 from repro.core.sweep import (MarginEngine, Op, SweepSpec,
                               param_reductions, select_combos)
 from repro.core.variation import Population
@@ -113,8 +114,9 @@ class Profiler:
         combos[:, 4] = grid
         read_m, write_m = self.engine.margins(pop.flat_cells(), combos,
                                               temp_c=temp)
-        return (self._refresh_envelopes(pop, read_m, grid),
-                self._refresh_envelopes(pop, write_m, grid))
+        with span("margin.reduce"):
+            return (self._refresh_envelopes(pop, read_m, grid),
+                    self._refresh_envelopes(pop, write_m, grid))
 
     def refresh_profile(self, pop: Population, temp: float, op: Op | str,
                         grid_ms: np.ndarray | None = None) -> RefreshProfile:

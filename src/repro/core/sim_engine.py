@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +75,7 @@ from repro.core.dram_sim import (OPEN_FCFS, SYNTH_SPECS, Policy,
                                  check_prefix_valid, frfcfs_perm,
                                  frfcfs_reorder, replay_adaptive,
                                  replay_rows, replay_rows_frfcfs)
+from repro.core.spans import span
 from repro.core.thermal import ThermalSpec
 
 COLLECTABLE = ("latencies", "temps", "bins")
@@ -165,9 +167,12 @@ class SimSpec:
     def __post_init__(self):
         tr = self.traces
         if isinstance(tr, Trace):
-            tr = (tuple(Trace(*(np.asarray(f)[i] for f in tr))
-                        for i in range(np.asarray(tr.arrival).shape[0]))
-                  if np.asarray(tr.arrival).ndim == 2 else (tr,))
+            shape = np.shape(tr.arrival)            # [T, N] or [N]
+            with span("sim.prep", streams=shape[0] if shape[1:] else 1,
+                      requests=math.prod(shape)):
+                tr = (tuple(Trace(*(np.asarray(f)[i] for f in tr))
+                            for i in range(np.asarray(tr.arrival).shape[0]))
+                      if np.asarray(tr.arrival).ndim == 2 else (tr,))
         if not isinstance(tr, SYNTH_SPECS):
             tr = tuple(tr)
         object.__setattr__(self, "traces", tr)
@@ -1366,8 +1371,10 @@ class SimEngine:
     def run(self, spec: SimSpec,
             config: "ReplayConfig | None" = None) -> SimResult:
         backend, fuse, bs = self._resolve(spec, config)
-        (synth, arrival, bank, row, is_write, valid_d, valid, closed,
-         slacks, caps, plan) = self._streams(spec, fuse)
+        with span("sim.prep") as s:
+            (synth, arrival, bank, row, is_write, valid_d, valid, closed,
+             slacks, caps, plan) = self._streams(spec, fuse)
+            s.count(streams=valid.shape[0], requests=valid.size)
         self.dispatch_count += 1
         fa = spec.faults
         f_on = spec.fault_on
@@ -1390,42 +1397,44 @@ class SimEngine:
             want = (("stats",) + (("lat",)
                                   if "latencies" in spec.collect else ())
                     if self.stats == "device" else ("lat",))
-            rm = (None if spec.region_map is None
-                  else jnp.asarray(spec.region_map))
-            out = self._dispatch(
-                "static", spec, synth, plan, backend, want,
-                _p99_k(valid), bs,
-                (arrival, bank, row, is_write, valid_d),
-                (jnp.asarray(timings), closed, slacks, caps,
-                 jnp.asarray(spec.ileave_codes), rm), fault=fault)
-            if self.stats == "host":
-                lat = np.asarray(out["lat"])
-                mean, p99 = _masked_stats(lat, valid)
-            else:
-                mean, p99 = np.asarray(out["mean"]), np.asarray(out["p99"])
-                lat = (np.asarray(out["lat"]) if "lat" in out else None)
-            total = np.asarray(out["total"])
-            cnt = None
-            if f_on:
-                # unflatten the (timing x fault) lane axis: [T, P,
-                # S*F, ...] -> [T, P, S, F, ...]
-                def uf(x):
-                    return (None if x is None else
-                            x.reshape(x.shape[:2] + (s_rows, nf)
-                                      + x.shape[3:]))
+            with span("sim.dispatch"):
+                rm = (None if spec.region_map is None
+                      else jnp.asarray(spec.region_map))
+                out = self._dispatch(
+                    "static", spec, synth, plan, backend, want,
+                    _p99_k(valid), bs,
+                    (arrival, bank, row, is_write, valid_d),
+                    (jnp.asarray(timings), closed, slacks, caps,
+                     jnp.asarray(spec.ileave_codes), rm), fault=fault)
+            with span("sim.fetch"):
+                if self.stats == "host":
+                    lat = np.asarray(out["lat"])
+                    mean, p99 = _masked_stats(lat, valid)
+                else:
+                    mean, p99 = np.asarray(out["mean"]), np.asarray(out["p99"])
+                    lat = (np.asarray(out["lat"]) if "lat" in out else None)
+                total = np.asarray(out["total"])
+                cnt = None
+                if f_on:
+                    # unflatten the (timing x fault) lane axis: [T, P,
+                    # S*F, ...] -> [T, P, S, F, ...]
+                    def uf(x):
+                        return (None if x is None else
+                                x.reshape(x.shape[:2] + (s_rows, nf)
+                                          + x.shape[3:]))
 
-                mean, p99, total, lat = map(uf, (mean, p99, total, lat))
-                cnt = uf(np.asarray(out["cnt"]))
-            elif fa is not None:      # inert spec: F copies + zeros
-                mean, p99, total, lat = (
-                    _expand_fault_axis(x, nf, 3)
-                    for x in (mean, p99, total, lat))
-                cnt = np.zeros(total.shape + (faults.N_COUNTERS,),
-                               np.int32)
-            return SimResult(spec=spec, mean_latency_ns=mean,
-                             p99_latency_ns=p99, total_ns=total,
-                             latencies=lat, valid=valid,
-                             fault_counters=cnt)
+                    mean, p99, total, lat = map(uf, (mean, p99, total, lat))
+                    cnt = uf(np.asarray(out["cnt"]))
+                elif fa is not None:      # inert spec: F copies + zeros
+                    mean, p99, total, lat = (
+                        _expand_fault_axis(x, nf, 3)
+                        for x in (mean, p99, total, lat))
+                    cnt = np.zeros(total.shape + (faults.N_COUNTERS,),
+                                   np.int32)
+                return SimResult(spec=spec, mean_latency_ns=mean,
+                                 p99_latency_ns=p99, total_ns=total,
+                                 latencies=lat, valid=valid,
+                                 fault_counters=cnt)
 
         scns, bins, tcfg = spec.thermal.pack()
         fault = (None if not f_on else
@@ -1437,57 +1446,59 @@ class SimEngine:
             want += ("bins",) if "bins" in spec.collect else ()
         else:
             want = ("lat", "temps", "bins")
-        out = self._dispatch(
-            "adaptive", spec, synth, plan, backend, want,
-            _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
-            (jnp.asarray(spec.timings), jnp.asarray(bins),
-             jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
-             caps, jnp.asarray(spec.ileave_codes),
-             None if spec.region_map is None
-             else jnp.asarray(spec.region_map)), fault=fault)
+        with span("sim.dispatch"):
+            out = self._dispatch(
+                "adaptive", spec, synth, plan, backend, want,
+                _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
+                (jnp.asarray(spec.timings), jnp.asarray(bins),
+                 jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
+                 caps, jnp.asarray(spec.ileave_codes),
+                 None if spec.region_map is None
+                 else jnp.asarray(spec.region_map)), fault=fault)
 
-        if self.stats == "host":
-            lat, temps, bin_sel = (np.asarray(out["lat"]),
-                                   np.asarray(out["temps"]),
-                                   np.asarray(out["bins"]))
-            mean, p99 = _masked_stats(lat, valid)
-            # thermal diagnostics over each trace's valid prefix
-            tmax = np.empty(lat.shape[:-1], np.float32)
-            tmean = np.empty(lat.shape[:-1], np.float32)
-            switches = np.empty(lat.shape[:-1], np.int64)
-            for t in range(lat.shape[0]):            # padding is a suffix
-                c = int(valid[t].sum())
-                tmax[t] = temps[t, ..., :c].max(-1)
-                tmean[t] = temps[t, ..., :c].mean(-1)
-                switches[t] = (np.diff(bin_sel[t, ..., :c], axis=-1)
-                               != 0).sum(-1)
-        else:
-            mean, p99 = np.asarray(out["mean"]), np.asarray(out["p99"])
-            tmax, tmean = (np.asarray(out["temp_max"]),
-                           np.asarray(out["temp_mean"]))
-            switches = np.asarray(out["bin_switches"])
-            lat = np.asarray(out["lat"]) if "lat" in out else None
-            temps = np.asarray(out["temps"]) if "temps" in out else None
-            bin_sel = np.asarray(out["bins"]) if "bins" in out else None
-        total = np.asarray(out["total"])
-        heat = np.asarray(out["bank_heat"])
-        cnt = np.asarray(out["cnt"]) if f_on else None
-        if fa is not None and not f_on:
-            # inert spec: the unfaulted [T, P, K, C] grid broadcast
-            # across the F copies (axis 4, before N/banks) + zeros
-            mean, p99, total, tmax, tmean, switches, lat, temps, \
-                bin_sel, heat = (
-                    _expand_fault_axis(x, nf, 4)
-                    for x in (mean, p99, total, tmax, tmean, switches,
-                              lat, temps, bin_sel, heat))
-            cnt = np.zeros(total.shape + (faults.N_COUNTERS,),
-                           np.int32)
-        return SimResult(spec=spec, mean_latency_ns=mean,
-                         p99_latency_ns=p99, total_ns=total,
-                         latencies=lat, valid=valid, temps=temps,
-                         bins=bin_sel, temp_max=tmax, temp_mean=tmean,
-                         bin_switches=switches, bank_heat=heat,
-                         fault_counters=cnt)
+        with span("sim.fetch"):
+            if self.stats == "host":
+                lat, temps, bin_sel = (np.asarray(out["lat"]),
+                                       np.asarray(out["temps"]),
+                                       np.asarray(out["bins"]))
+                mean, p99 = _masked_stats(lat, valid)
+                # thermal diagnostics over each trace's valid prefix
+                tmax = np.empty(lat.shape[:-1], np.float32)
+                tmean = np.empty(lat.shape[:-1], np.float32)
+                switches = np.empty(lat.shape[:-1], np.int64)
+                for t in range(lat.shape[0]):            # padding is a suffix
+                    c = int(valid[t].sum())
+                    tmax[t] = temps[t, ..., :c].max(-1)
+                    tmean[t] = temps[t, ..., :c].mean(-1)
+                    switches[t] = (np.diff(bin_sel[t, ..., :c], axis=-1)
+                                   != 0).sum(-1)
+            else:
+                mean, p99 = np.asarray(out["mean"]), np.asarray(out["p99"])
+                tmax, tmean = (np.asarray(out["temp_max"]),
+                               np.asarray(out["temp_mean"]))
+                switches = np.asarray(out["bin_switches"])
+                lat = np.asarray(out["lat"]) if "lat" in out else None
+                temps = np.asarray(out["temps"]) if "temps" in out else None
+                bin_sel = np.asarray(out["bins"]) if "bins" in out else None
+            total = np.asarray(out["total"])
+            heat = np.asarray(out["bank_heat"])
+            cnt = np.asarray(out["cnt"]) if f_on else None
+            if fa is not None and not f_on:
+                # inert spec: the unfaulted [T, P, K, C] grid broadcast
+                # across the F copies (axis 4, before N/banks) + zeros
+                mean, p99, total, tmax, tmean, switches, lat, temps, \
+                    bin_sel, heat = (
+                        _expand_fault_axis(x, nf, 4)
+                        for x in (mean, p99, total, tmax, tmean, switches,
+                                  lat, temps, bin_sel, heat))
+                cnt = np.zeros(total.shape + (faults.N_COUNTERS,),
+                               np.int32)
+            return SimResult(spec=spec, mean_latency_ns=mean,
+                             p99_latency_ns=p99, total_ns=total,
+                             latencies=lat, valid=valid, temps=temps,
+                             bins=bin_sel, temp_max=tmax, temp_mean=tmean,
+                             bin_switches=switches, bank_heat=heat,
+                             fault_counters=cnt)
 
     def run_bracket(self, spec: SimSpec, base_row,
                     n_real: int | None = None,
@@ -1515,28 +1526,32 @@ class SimEngine:
         assert spec.region_map is None, \
             "run_bracket carries no region axis — run() the spec"
         backend, fuse, bs = self._resolve(spec, config)
-        (synth, arrival, bank, row, is_write, valid_d, valid, closed,
-         slacks, caps, plan) = self._streams(spec, fuse)
+        with span("sim.prep") as s:
+            (synth, arrival, bank, row, is_write, valid_d, valid, closed,
+             slacks, caps, plan) = self._streams(spec, fuse)
+            s.count(streams=valid.shape[0], requests=valid.size)
         scns, bins, tcfg = spec.thermal.pack()
         n_real = len(scns) if n_real is None else int(n_real)
         self.dispatch_count += 1
-        out = self._dispatch(
-            "bracket", spec, synth, plan, backend, ("stats",),
-            _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
-            (jnp.asarray(spec.timings), jnp.asarray(bins),
-             jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
-             caps, jnp.asarray(base_row, jnp.float32),
-             jnp.asarray(spec.ileave_codes)),
-            n_real=n_real)
+        with span("sim.dispatch"):
+            out = self._dispatch(
+                "bracket", spec, synth, plan, backend, ("stats",),
+                _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
+                (jnp.asarray(spec.timings), jnp.asarray(bins),
+                 jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
+                 caps, jnp.asarray(base_row, jnp.float32),
+                 jnp.asarray(spec.ileave_codes)),
+                n_real=n_real)
 
         def host(d):
             return {k: np.asarray(v) for k, v in d.items()}
 
-        return {"adaptive": host(out["adaptive"]),
-                "static": host(out["static"]),
-                "worst_bin": np.asarray(out["worst_bin"]),
-                "temp_peak": np.asarray(out["temp_peak"]),
-                "valid": valid}
+        with span("sim.fetch"):
+            return {"adaptive": host(out["adaptive"]),
+                    "static": host(out["static"]),
+                    "worst_bin": np.asarray(out["worst_bin"]),
+                    "temp_peak": np.asarray(out["temp_peak"]),
+                    "valid": valid}
 
 
 _DEFAULT: SimEngine | None = None

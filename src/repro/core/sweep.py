@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core import timing as T
 from repro.core.charge import ChargeConstants, DEFAULT_CONSTANTS
+from repro.core.spans import span
 from repro.core.variation import Population
 
 
@@ -291,7 +292,8 @@ class MarginEngine:
             impl=self.impl,
             trefi_read_cells=_as_jnp(trefi_read),
             trefi_write_cells=_as_jnp(trefi_write))
-        return np.asarray(read_m), np.asarray(write_m)
+        with span("margin.fetch", bytes=read_m.nbytes + write_m.nbytes):
+            return np.asarray(read_m), np.asarray(write_m)
 
     # ------------------------------------------------------------ campaign
     def sweep(self, pop: Population, spec: SweepSpec,
@@ -338,43 +340,44 @@ class MarginEngine:
             trefi_read=trefi_cells[Op.READ],
             trefi_write=trefi_cells[Op.WRITE])
 
-        margins, ok, chosen, sums = [], [], [], []
-        ok_b, chosen_b, sums_b = [], [], []
-        ok_r, chosen_r, sums_r = [], [], []
-        off = 0
-        for test in spec.tests:
-            c = test.combos.shape[0]
-            block = (read_m if test.op is Op.READ else write_m)
-            block = block[:, off:off + n_temps * c]
-            off += n_temps * c
-            m3 = block.reshape(-1, n_temps, c)        # [n_cells, T, C]
-            # per-(bank, region) envelope: reduce over chips and the
-            # cells WITHIN each region's row-position group
-            # ([modules, banks, regions, T, C]); the bank envelope is
-            # its intersection over regions and the module envelope the
-            # intersection over banks — identical booleans to the old
-            # collapse over the whole cell hierarchy at every level
-            okr_k = (m3.reshape(n_mod, ch, bk, regions, kc // regions,
-                                n_temps, c) >= 0.0).all(4).all(1)
-            okb_k = okr_k.all(2)
-            ok_k = okb_k.all(1)
-            ch_k, s_k = select_combos(test.combos, ok_k, test.op,
-                                      trefi_mod[test.op], self.std)
-            chb_k, sb_k = select_combos(test.combos, okb_k, test.op,
-                                        trefi_mod[test.op], self.std)
-            margins.append(m3)
-            ok.append(ok_k)
-            chosen.append(ch_k)
-            sums.append(s_k)
-            ok_b.append(okb_k)
-            chosen_b.append(chb_k)
-            sums_b.append(sb_k)
-            if regions > 1:
-                chr_k, sr_k = select_combos(test.combos, okr_k, test.op,
+        with span("margin.reduce"):
+            margins, ok, chosen, sums = [], [], [], []
+            ok_b, chosen_b, sums_b = [], [], []
+            ok_r, chosen_r, sums_r = [], [], []
+            off = 0
+            for test in spec.tests:
+                c = test.combos.shape[0]
+                block = (read_m if test.op is Op.READ else write_m)
+                block = block[:, off:off + n_temps * c]
+                off += n_temps * c
+                m3 = block.reshape(-1, n_temps, c)        # [n_cells, T, C]
+                # per-(bank, region) envelope: reduce over chips and the
+                # cells WITHIN each region's row-position group
+                # ([modules, banks, regions, T, C]); the bank envelope is
+                # its intersection over regions and the module envelope the
+                # intersection over banks — identical booleans to the old
+                # collapse over the whole cell hierarchy at every level
+                okr_k = (m3.reshape(n_mod, ch, bk, regions, kc // regions,
+                                    n_temps, c) >= 0.0).all(4).all(1)
+                okb_k = okr_k.all(2)
+                ok_k = okb_k.all(1)
+                ch_k, s_k = select_combos(test.combos, ok_k, test.op,
+                                          trefi_mod[test.op], self.std)
+                chb_k, sb_k = select_combos(test.combos, okb_k, test.op,
                                             trefi_mod[test.op], self.std)
-                ok_r.append(okr_k)
-                chosen_r.append(chr_k)
-                sums_r.append(sr_k)
+                margins.append(m3)
+                ok.append(ok_k)
+                chosen.append(ch_k)
+                sums.append(s_k)
+                ok_b.append(okb_k)
+                chosen_b.append(chb_k)
+                sums_b.append(sb_k)
+                if regions > 1:
+                    chr_k, sr_k = select_combos(test.combos, okr_k, test.op,
+                                                trefi_mod[test.op], self.std)
+                    ok_r.append(okr_k)
+                    chosen_r.append(chr_k)
+                    sums_r.append(sr_k)
         return SweepResult(spec=spec, std=self.std,
                            margins=tuple(margins), ok=tuple(ok),
                            chosen=tuple(chosen), latency_sum=tuple(sums),
