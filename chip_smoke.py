@@ -10,7 +10,8 @@ One chip, paper scale:
   * profile   the 115-module calibrated population, non-fast profiler,
               with the Pallas margin kernel; margins of a module subset
               against the jnp reference on the same chip (max |diff|,
-              pass/fail flips — flips must be zero);
+              pass/fail flips — flips must be zero), and the module
+              and bank pass envelopes reduced on the device (equal);
   * verify    the zero-error invariant of the profiled table;
   * static    the Fig. 4 campaign (35 workloads x 2 modes, n=8192)
               with the Pallas replay kernel against the scan;
@@ -84,6 +85,26 @@ def check_parity(phase: str, parity: dict) -> None:
     check(not bad, f"{phase}: {bad} out of bounds")
 
 
+def margin_parity(engine, ref_engine, pop, spec) -> dict:
+    """`engine`'s dense margins of the campaign `spec` on `pop` against
+    `ref_engine`'s (max |diff|, pass/fail flips, margins compared), and
+    the module and bank pass envelopes of the two engines' sweeps, which
+    reduce the grids on the device (entries differing)."""
+    import numpy as np
+    kern = engine.campaign_margins(pop, spec)
+    ref = ref_engine.campaign_margins(pop, spec)
+    a, b = engine.sweep(pop, spec), ref_engine.sweep(pop, spec)
+    return {
+        "margin_max_abs_diff": max(float(np.abs(k - r).max())
+                                   for k, r in zip(kern, ref)),
+        "pass_fail_flips": sum(int(((k >= 0.0) != (r >= 0.0)).sum())
+                               for k, r in zip(kern, ref)),
+        "margin_cells_compared": int(sum(k.size for k in kern)),
+        "envelopes_differing": sum(
+            int((x != y).sum()) for f in ("ok", "ok_bank")
+            for x, y in zip(getattr(a, f), getattr(b, f)))}
+
+
 # ------------------------------------------------------------- phases
 def phase_profile(pop, prof, subset: int = 4):
     """Profile the population with the Pallas margin kernel (cold and
@@ -113,18 +134,17 @@ def phase_profile(pop, prof, subset: int = 4):
     sub = Population(pop.cells[:subset])
     rp_r, rp_w = prof.refresh_campaign(sub, 85.0)
     sub_spec = prof.campaign_spec(ctrl.temp_bins, rp_r, rp_w)
-    kern = prof.engine.sweep(sub, sub_spec).margins
-    ref = MarginEngine(constants=prof.constants, std=prof.std,
-                       impl="ref").sweep(sub, sub_spec).margins
-    diff = max(float(np.abs(k - r).max()) for k, r in zip(kern, ref))
-    flips = sum(int(((k >= 0.0) != (r >= 0.0)).sum())
-                for k, r in zip(kern, ref))
-    check(flips == 0, f"{flips} pass/fail flips against the reference")
+    parity = margin_parity(prof.engine, MarginEngine(
+        constants=prof.constants, std=prof.std, impl="ref"), sub, sub_spec)
+    check(parity["pass_fail_flips"] == 0,
+          f"{parity['pass_fail_flips']} pass/fail flips against the "
+          f"reference")
+    check(parity["envelopes_differing"] == 0,
+          f"{parity['envelopes_differing']} envelope entries differ from "
+          f"the reference")
     report("profile", cold_s=cold, warm_s=warm, modules=m,
            cells=int(m * cpm), combo_columns=cols,
-           dispatches=expect, parity_modules=subset,
-           margin_max_abs_diff=diff, pass_fail_flips=flips,
-           margin_cells_compared=int(sum(k.size for k in kern)))
+           dispatches=expect, parity_modules=subset, **parity)
     return ctrl
 
 

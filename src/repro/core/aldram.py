@@ -58,7 +58,8 @@ DEFAULT_TEMP_BINS = (45.0, 55.0, 65.0, 75.0, 85.0)
 # Margin-grid cells (tail cells x combo columns) per profiling
 # dispatch.  The paper-scale timing campaign (115 modules x 1536 cells
 # x 8505 columns = 1.5e9 cells) emits two float32 grids of 6.1 GB
-# each, which with their unpadding copies exceed a 16 GB chip, so
+# each (12.3 GB as the kernel pads them) that live on the device until
+# they are reduced to pass envelopes there — most of a 16 GB chip — so
 # `profile` runs larger campaigns in module groups of at most this
 # many cells (2 GB of grids per dispatch).
 PROFILE_GRID_ELEMS = 1 << 28
@@ -501,11 +502,9 @@ class ALDRAMController:
             rp_read, rp_write = prof.refresh_campaign(pop, 85.0)
             res = self._sweep(
                 pop, prof.campaign_spec(self.temp_bins, rp_read, rp_write))
-            # keep the selection views for reporting (evaluate_bank_system's
-            # reduction statistics, tests) but drop the O(cells x combos)
-            # raw margin grids — at calibrated scale they are gigabytes the
-            # controller would otherwise pin for its whole lifetime
-            self.sweep_result = dataclasses.replace(res, margins=())
+            # the selection views, for reporting (evaluate_bank_system's
+            # reduction statistics, tests)
+            self.sweep_result = res
             kr, kw = res.index(Op.READ), res.index(Op.WRITE)
 
             def combine(cr, cw):
@@ -555,10 +554,11 @@ class ALDRAMController:
 
     def _sweep(self, pop: Population, spec):
         """`engine.sweep` of the timing campaign — ONE dispatch up to
-        `PROFILE_GRID_ELEMS` margin cells, else one per module group.
-        Every selection view is per module (a module's envelope reads
-        only its own cells' margins), so the groups' views concatenate
-        to exactly the single-dispatch result, minus the raw margins."""
+        `PROFILE_GRID_ELEMS` margin cells, else one per module group,
+        each launched before the one before it is fetched.  Every
+        selection view is per module (a module's envelope reads only
+        its own cells' margins), so the groups' views concatenate to
+        exactly the single-dispatch result."""
         m = pop.n_modules
         cpm = int(np.prod(pop.cells.shape[1:4]))
         cols = len(spec.temps) * sum(t.combos.shape[0]
@@ -566,7 +566,7 @@ class ALDRAMController:
         g = max(1, PROFILE_GRID_ELEMS // (cpm * cols))
         if g >= m:
             return self.engine.sweep(pop, spec, regions=self.regions)
-        parts = []
+        groups = []
         for lo in range(0, m, g):
             sl = slice(lo, min(lo + g, m))
             tests = tuple(
@@ -574,15 +574,13 @@ class ALDRAMController:
                     t, trefi_ms=(None if t.trefi_ms is None
                                  else t.trefi_per_module(m)[sl]))
                 for t in spec.tests)
-            parts.append(self.engine.sweep(
-                Population(pop.cells[sl]),
-                dataclasses.replace(spec, tests=tests),
-                regions=self.regions))
+            groups.append((Population(pop.cells[sl]),
+                           dataclasses.replace(spec, tests=tests)))
+        parts = self.engine.sweeps(groups, regions=self.regions)
         views = {f: tuple(np.concatenate([getattr(r, f)[k] for r in parts])
                           for k in range(len(getattr(parts[0], f))))
                  for f in _MODULE_VIEWS}
-        return dataclasses.replace(parts[0], spec=spec, margins=(),
-                                   **views)
+        return dataclasses.replace(parts[0], spec=spec, **views)
 
     # ----------------------------------------------- resolution levels
     def region_table(self, level: int) -> TimingTable:

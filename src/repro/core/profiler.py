@@ -112,11 +112,16 @@ class Profiler:
         std_combo = np.asarray(self.std.as_array())
         combos = np.repeat(std_combo[None, :], len(grid), axis=0)
         combos[:, 4] = grid
-        read_m, write_m = self.engine.margins(pop.flat_cells(), combos,
-                                              temp_c=temp)
+        # the device reduces each grid over the tail cells, keeping
+        # chips and banks: [modules, chips, banks, grid] pass booleans
+        m, ch, bk, k = pop.cells.shape[:4]
+        g = len(grid)
+        per_cellmin = self.engine.envelopes(
+            pop.flat_cells(), combos, cell_shape=(m, ch, bk, k), axes=(3,),
+            blocks=((Op.READ, 0, g), (Op.WRITE, 0, g)), temp_c=temp).fetch()
         with span("margin.reduce"):
-            return (self._refresh_envelopes(pop, read_m, grid),
-                    self._refresh_envelopes(pop, write_m, grid))
+            return tuple(self._refresh_envelopes(ok, grid)
+                         for ok in per_cellmin)
 
     def refresh_profile(self, pop: Population, temp: float, op: Op | str,
                         grid_ms: np.ndarray | None = None) -> RefreshProfile:
@@ -124,10 +129,10 @@ class Profiler:
         rp_read, rp_write = self.refresh_campaign(pop, temp, grid_ms)
         return rp_read if Op.parse(op) is Op.READ else rp_write
 
-    def _refresh_envelopes(self, pop: Population, margins: np.ndarray,
+    def _refresh_envelopes(self, per_cellmin: np.ndarray,
                            grid: np.ndarray) -> RefreshProfile:
-        m, ch, bk, k = pop.cells.shape[:4]
-        ok = margins.reshape(m, ch, bk, k, len(grid)) >= 0.0    # pass/fail
+        """Envelopes from the [modules, chips, banks, grid] booleans:
+        every tail cell of the (module, chip, bank) passes."""
 
         def max_passing(mask: np.ndarray) -> np.ndarray:
             # mask: [..., n_grid]; the envelope is monotone (longer
@@ -138,7 +143,6 @@ class Profiler:
             idx = np.maximum(idx - 1, 0)
             return grid[idx]
 
-        per_cellmin = ok.all(3)                                 # [m,ch,bk,g]
         # rank-level bank b = bank b of EVERY chip -> worst chip governs
         per_bank = max_passing(per_cellmin.all(1))              # [m, banks]
         per_chip = max_passing(per_cellmin.all(2))              # [m, chips]

@@ -34,8 +34,12 @@ in them:
                  requests: the packed [streams, n] slots]
   sim.dispatch   the launch of a synthesis or replay program
   sim.fetch      the replay's results turned into numpy arrays
-  margin.fetch   the margin grids copied to the host [bytes on device]
-  margin.reduce  the pass envelopes and the combo selection
+  margin.fetch   the wait for one margin dispatch and the copy of its
+                 pass envelopes to the host (of its dense grids, for
+                 `MarginEngine.margins`) [bytes copied, evals: the
+                 margins the dispatch evaluated]
+  margin.reduce  the host's part of the envelopes (bank, module,
+                 refresh interval) and the combo selection
 """
 
 from __future__ import annotations

@@ -12,19 +12,22 @@ sweep axis is just a block structure on that grid:
                          override columns folded into the cell side.
 
 `SweepSpec` declares the campaign, `MarginEngine` compiles it into a
-single padded dispatch (Pallas on TPU, jnp oracle on CPU) and returns a
-structured `SweepResult` with margins, pass envelopes, the per-module
-argmin-latency combo choice (vectorised — no Python loops) and
-reduction statistics.  Callers that used to issue one `combo_margins`
-call per (module, temperature, op) now issue one engine call per
-campaign.
+single padded dispatch (Pallas on TPU, jnp oracle on CPU), reduces the
+margin grids to pass envelopes on the device, and returns a structured
+`SweepResult` with the pass envelopes, the per-module argmin-latency
+combo choice (vectorised — no Python loops) and reduction statistics.
+Callers that used to issue one `combo_margins` call per (module,
+temperature, op) now issue one engine call per campaign.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
+from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -129,7 +132,6 @@ class SweepResult:
     """Structured result of one fused campaign.
 
     Per test k (aligned with spec.tests):
-      margins[k]:     [n_cells, n_temps, n_combos_k] raw test margins
       ok[k]:          [modules, n_temps, n_combos_k] pass envelope
                       (every cell of the module passes)
       chosen[k]:      [modules, n_temps, 5] minimum-latency passing
@@ -161,11 +163,14 @@ class SweepResult:
     The spatial hierarchy is exact at every level:
     `ok_bank[k] == ok_region[k].all(2)` and
     `ok[k] == ok_region[k].all(2).all(1)` — booleans, not tolerances.
+
+    The raw margin grids are reduced on the device and never copied
+    to the host (`MarginEngine.campaign_margins` returns them where a
+    caller needs them).
     """
 
     spec: SweepSpec
     std: T.TimingParams
-    margins: tuple[np.ndarray, ...]
     ok: tuple[np.ndarray, ...]
     chosen: tuple[np.ndarray, ...]
     latency_sum: tuple[np.ndarray, ...]
@@ -251,6 +256,39 @@ def select_combos(combos: np.ndarray, ok: np.ndarray, op: Op | str,
     return chosen, lat_sum[pick].astype(np.float32)
 
 
+class Envelopes(NamedTuple):
+    """The pass envelopes of one `MarginEngine.envelopes` dispatch,
+    still on the device: launched, not waited for."""
+
+    arrays: tuple[jax.Array, ...]
+    evals: int                  # margins the dispatch evaluated
+
+    def fetch(self) -> tuple[np.ndarray, ...]:
+        """Wait for the dispatch and copy the envelopes to the host."""
+        with span("margin.fetch", bytes=sum(a.nbytes for a in self.arrays),
+                  evals=self.evals):
+            return tuple(jax.device_get(self.arrays))
+
+
+@functools.partial(jax.jit, static_argnames=("cell_shape", "axes",
+                                             "blocks"))
+def _pass_envelopes(read_m: jax.Array, write_m: jax.Array, *,
+                    cell_shape: tuple[int, ...], axes: tuple[int, ...],
+                    blocks: tuple[tuple[int, int, int], ...]
+                    ) -> tuple[jax.Array, ...]:
+    """Per block (grid 0 read / 1 write, first, end column): every cell
+    of the reduced `axes` passes, `(margin >= 0).all(axes)`, with the
+    grid's rows viewed as `cell_shape`.  The grids may carry the
+    kernel's padding beyond the `prod(cell_shape)` rows and the block's
+    columns: the slice fuses into the test, so each float32 grid is
+    read once and never copied."""
+    n = int(np.prod(cell_shape))
+    grids = (read_m, write_m)
+    return tuple((grids[g][:n, lo:hi].reshape(cell_shape + (hi - lo,))
+                  >= 0.0).all(axes)
+                 for g, lo, hi in blocks)
+
+
 @dataclasses.dataclass
 class MarginEngine:
     """Facade that compiles a `SweepSpec` into one kernel dispatch.
@@ -271,13 +309,21 @@ class MarginEngine:
                 temps_combo: np.ndarray | None = None,
                 temp_c: float | None = None,
                 trefi_read: np.ndarray | None = None,
-                trefi_write: np.ndarray | None = None
-                ) -> tuple[np.ndarray, np.ndarray]:
-        """One dispatch: dense (read, write) margin grids [n, m].
+                trefi_write: np.ndarray | None = None,
+                *, on_device: bool = False
+                ) -> tuple[np.ndarray, np.ndarray] | tuple[jax.Array,
+                                                           jax.Array]:
+        """One dispatch: dense (read, write) margin grids [n, m], copied
+        to the host.
 
         Give either `temps_combo` ([m] per-combo temperature) or a
         scalar `temp_c`.  `trefi_read`/`trefi_write`: optional [n]
         per-cell refresh-interval overrides for the two tests.
+
+        `on_device` instead returns the grids as jax arrays left on the
+        device, uncopied and padded to the kernel's blocks (the margins
+        in the first n rows and m columns), for a program that reduces
+        them there (`envelopes`).
         """
         from repro.kernels.charge_sim import ops as charge_ops
         combos = np.asarray(combos, np.float32)
@@ -286,14 +332,42 @@ class MarginEngine:
             temps_combo = np.full((combos.shape[0],), float(temp_c),
                                   np.float32)
         self.dispatch_count += 1
-        read_m, write_m = charge_ops.margin_sweep(
+        read_m, write_m = charge_ops.padded_margin_sweep(
             jnp.asarray(cells), jnp.asarray(combos),
             jnp.asarray(temps_combo, jnp.float32), self.constants,
             impl=self.impl,
             trefi_read_cells=_as_jnp(trefi_read),
             trefi_write_cells=_as_jnp(trefi_write))
-        with span("margin.fetch", bytes=read_m.nbytes + write_m.nbytes):
+        if on_device:
+            return read_m, write_m
+        n, m = np.shape(cells)[0], combos.shape[0]
+        read_m, write_m = read_m[:n, :m], write_m[:n, :m]
+        with span("margin.fetch", bytes=read_m.nbytes + write_m.nbytes,
+                  evals=read_m.size + write_m.size):
             return np.asarray(read_m), np.asarray(write_m)
+
+    def envelopes(self, cells: np.ndarray | jnp.ndarray, combos: np.ndarray,
+                  cell_shape: tuple[int, ...], axes: tuple[int, ...],
+                  blocks: tuple[tuple[Op, int, int], ...],
+                  temps_combo: np.ndarray | None = None,
+                  temp_c: float | None = None,
+                  trefi_read: np.ndarray | None = None,
+                  trefi_write: np.ndarray | None = None) -> Envelopes:
+        """One dispatch reduced to pass envelopes on the device: per
+        block (op, first, end column), `(margin >= 0).all(axes)` of the
+        op's grid with its rows viewed as `cell_shape`, a bool array
+        [kept cell axes..., end - first].  The grids never leave the
+        device; only the envelopes cross, at `fetch()` of the result.
+        Arguments otherwise as `margins`."""
+        read_m, write_m = self.margins(
+            cells, combos, temps_combo=temps_combo, temp_c=temp_c,
+            trefi_read=trefi_read, trefi_write=trefi_write, on_device=True)
+        arrays = _pass_envelopes(
+            read_m, write_m, cell_shape=tuple(cell_shape), axes=tuple(axes),
+            blocks=tuple((int(Op.parse(op) is Op.WRITE), lo, hi)
+                         for op, lo, hi in blocks))
+        return Envelopes(arrays, 2 * int(np.prod(cell_shape))
+                         * np.asarray(combos).shape[0])
 
     # ------------------------------------------------------------ campaign
     def sweep(self, pop: Population, spec: SweepSpec,
@@ -306,6 +380,10 @@ class MarginEngine:
         temperature column.  Per-module safe refresh intervals are
         folded into the per-cell, per-op override columns.
 
+        The device reduces each test's grid to its per-(module, bank,
+        region) pass envelope; the host builds the bank and module
+        envelopes and selects the combos from those booleans.
+
         `regions` > 1 additionally reduces the SAME margin grid per
         (module, bank, subarray region): the tail-cell axis is the
         row-position axis, split into `regions` contiguous groups
@@ -313,59 +391,99 @@ class MarginEngine:
         evaluation — still ONE dispatch — and the hierarchy is exact
         (`ok == ok_region.all(regions).all(banks)`).
         """
-        n_mod = pop.n_modules
+        return self.sweeps([(pop, spec)], regions)[0]
+
+    def sweeps(self, campaigns: list[tuple[Population, SweepSpec]],
+               regions: int = 1) -> list[SweepResult]:
+        """`sweep` of each (population, spec) pair, one dispatch each.
+        Each dispatch is launched before the envelopes of the one
+        before it are fetched, so the device runs them back to back
+        while the host selects, and the grids of at most two
+        dispatches are live on the device at once.  (One at a time,
+        the device idles through each selection: the paper-scale
+        profile then takes a third longer on a TPU v5e.)"""
+        out, pending = [], []
+        for pop, spec in campaigns:
+            pending.append((spec, *self._launch(pop, spec, regions)))
+            if len(pending) == 2:
+                out.append(self._select(regions, *pending.pop(0)))
+        out.extend(self._select(regions, *p) for p in pending)
+        return out
+
+    def campaign_margins(self, pop: Population, spec: SweepSpec
+                         ) -> tuple[np.ndarray, ...]:
+        """The dense margins that `sweep` reduces on the device, copied
+        to the host: per test k, [n_cells, n_temps, n_combos_k] from
+        ONE dispatch of the same fused grid — for a caller that checks
+        the margins themselves (the kernel against a reference)."""
+        cols, blocks, _ = self._fused_columns(pop, spec)
+        read_m, write_m = self.margins(pop.flat_cells(), **cols)
+        return tuple((read_m if op is Op.READ else write_m)[:, lo:hi]
+                     .reshape(-1, len(spec.temps), test.combos.shape[0])
+                     for test, (op, lo, hi) in zip(spec.tests, blocks))
+
+    def _fused_columns(self, pop: Population, spec: SweepSpec
+                       ) -> tuple[dict, list, dict]:
+        """The fused grid of `spec` (see `sweep`): the `margins`
+        arguments of its columns, each test's (op, first, end) columns,
+        and the per-module refresh intervals per op."""
+        cpm = int(np.prod(pop.cells.shape[1:4]))     # cells per module
+        n_temps = len(spec.temps)
+        temps_arr = np.asarray(spec.temps, np.float32)
+        combo_blocks, temp_cols, blocks = [], [], []
+        off = 0
+        for test in spec.tests:
+            base = test.combos                        # [C, 5]
+            combo_blocks.append(np.tile(base, (n_temps, 1)))
+            temp_cols.append(np.repeat(temps_arr, base.shape[0]))
+            blocks.append((test.op, off, off + n_temps * base.shape[0]))
+            off = blocks[-1][2]
+        trefi_mod = {op: spec.op_trefi(op, pop.n_modules) for op in Op}
+        trefi_cells = {op: (None if trefi_mod[op] is None
+                            else np.repeat(trefi_mod[op], cpm))
+                       for op in Op}
+        cols = dict(combos=np.concatenate(combo_blocks, axis=0),
+                    temps_combo=np.concatenate(temp_cols, axis=0),
+                    trefi_read=trefi_cells[Op.READ],
+                    trefi_write=trefi_cells[Op.WRITE])
+        return cols, blocks, trefi_mod
+
+    def _launch(self, pop: Population, spec: SweepSpec, regions: int
+                ) -> tuple[Envelopes, dict]:
         ch, bk, kc = pop.cells.shape[1:4]
         assert regions >= 1 and kc % regions == 0, \
             f"regions={regions} must divide the {kc} tail cells " \
             f"(contiguous row-position groups)"
-        cpm = ch * bk * kc                           # cells per module
-        n_temps = len(spec.temps)
-        temps_arr = np.asarray(spec.temps, np.float32)
+        cols, blocks, trefi_mod = self._fused_columns(pop, spec)
+        # per-(bank, region) envelope: reduce over chips and the cells
+        # WITHIN each region's row-position group
+        env = self.envelopes(
+            pop.flat_cells(),
+            cell_shape=(pop.n_modules, ch, bk, regions, kc // regions),
+            axes=(1, 4), blocks=tuple(blocks), **cols)
+        return env, trefi_mod
 
-        blocks, temp_cols = [], []
-        for test in spec.tests:
-            base = test.combos                        # [C, 5]
-            blocks.append(np.tile(base, (n_temps, 1)))
-            temp_cols.append(np.repeat(temps_arr, base.shape[0]))
-        combos_all = np.concatenate(blocks, axis=0)
-        temps_all = np.concatenate(temp_cols, axis=0)
-
-        trefi_mod = {op: spec.op_trefi(op, n_mod) for op in Op}
-        trefi_cells = {op: (None if trefi_mod[op] is None
-                            else np.repeat(trefi_mod[op], cpm))
-                       for op in Op}
-
-        read_m, write_m = self.margins(
-            pop.flat_cells(), combos_all, temps_all,
-            trefi_read=trefi_cells[Op.READ],
-            trefi_write=trefi_cells[Op.WRITE])
-
+    def _select(self, regions: int, spec: SweepSpec, env: Envelopes,
+                trefi_mod: dict) -> SweepResult:
+        """The selection views from the per-(bank, region) envelopes."""
+        okr = env.fetch()
         with span("margin.reduce"):
-            margins, ok, chosen, sums = [], [], [], []
+            ok, chosen, sums = [], [], []
             ok_b, chosen_b, sums_b = [], [], []
             ok_r, chosen_r, sums_r = [], [], []
-            off = 0
-            for test in spec.tests:
-                c = test.combos.shape[0]
-                block = (read_m if test.op is Op.READ else write_m)
-                block = block[:, off:off + n_temps * c]
-                off += n_temps * c
-                m3 = block.reshape(-1, n_temps, c)        # [n_cells, T, C]
-                # per-(bank, region) envelope: reduce over chips and the
-                # cells WITHIN each region's row-position group
-                # ([modules, banks, regions, T, C]); the bank envelope is
-                # its intersection over regions and the module envelope the
-                # intersection over banks — identical booleans to the old
+            for test, okr_k in zip(spec.tests, okr):
+                # [modules, banks, regions, T, C]; the bank envelope is
+                # its intersection over regions and the module envelope
+                # the intersection over banks — identical booleans to a
                 # collapse over the whole cell hierarchy at every level
-                okr_k = (m3.reshape(n_mod, ch, bk, regions, kc // regions,
-                                    n_temps, c) >= 0.0).all(4).all(1)
+                okr_k = okr_k.reshape(okr_k.shape[:3] + (
+                    len(spec.temps), test.combos.shape[0]))
                 okb_k = okr_k.all(2)
                 ok_k = okb_k.all(1)
                 ch_k, s_k = select_combos(test.combos, ok_k, test.op,
                                           trefi_mod[test.op], self.std)
                 chb_k, sb_k = select_combos(test.combos, okb_k, test.op,
                                             trefi_mod[test.op], self.std)
-                margins.append(m3)
                 ok.append(ok_k)
                 chosen.append(ch_k)
                 sums.append(s_k)
@@ -378,8 +496,7 @@ class MarginEngine:
                     ok_r.append(okr_k)
                     chosen_r.append(chr_k)
                     sums_r.append(sr_k)
-        return SweepResult(spec=spec, std=self.std,
-                           margins=tuple(margins), ok=tuple(ok),
+        return SweepResult(spec=spec, std=self.std, ok=tuple(ok),
                            chosen=tuple(chosen), latency_sum=tuple(sums),
                            ok_bank=tuple(ok_b),
                            chosen_bank=tuple(chosen_b),
@@ -394,4 +511,4 @@ def _as_jnp(x: np.ndarray | None) -> jnp.ndarray | None:
 
 
 __all__ = ["Op", "OpSweep", "SweepSpec", "SweepResult", "MarginEngine",
-           "select_combos", "param_reductions"]
+           "Envelopes", "select_combos", "param_reductions"]

@@ -10,7 +10,9 @@ is the single-temperature special case kept for simple callers.
 Both pad the (cells, combos) grid to block multiples, transpose the
 small parameter vectors into lane-aligned layout, dispatch to the
 Pallas kernel on TPU (or `interpret=True` when requested) and to the
-pure-jnp oracle on CPU, then unpad.
+pure-jnp oracle on CPU, then unpad.  `padded_margin_sweep` stops
+before the unpadding, for a caller that reduces the padded grids in a
+program of its own.
 """
 
 from __future__ import annotations
@@ -57,6 +59,24 @@ def margin_sweep(cells: jnp.ndarray, combos: jnp.ndarray,
     for the TPU; raises elsewhere), 'pallas_interpret' (kernel body on
     the host — used by kernel tests), 'ref'.
     """
+    n, m = cells.shape[0], combos.shape[0]
+    read_m, write_m = padded_margin_sweep(
+        cells, combos, temps_combo, constants, impl, trefi_read_cells,
+        trefi_write_cells, bc=bc, bm=bm)
+    return read_m[:n, :m], write_m[:n, :m]
+
+
+def padded_margin_sweep(cells: jnp.ndarray, combos: jnp.ndarray,
+                        temps_combo: jnp.ndarray,
+                        constants: ChargeConstants = DEFAULT_CONSTANTS,
+                        impl: str = "auto",
+                        trefi_read_cells: jnp.ndarray | None = None,
+                        trefi_write_cells: jnp.ndarray | None = None,
+                        bc: int | None = None, bm: int | None = None
+                        ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`margin_sweep` without its unpadding: the grids as the kernel
+    wrote them, padded to its blocks, with the margins in the first n
+    rows and m columns (the ref impl pads nothing)."""
     impl = resolve_impl(impl)
     if impl == "ref":
         return ref.margin_sweep(cells, combos, temps_combo, constants,
@@ -77,10 +97,9 @@ def margin_sweep(cells: jnp.ndarray, combos: jnp.ndarray,
     # pad combos with the standard (always-safe) combo to avoid NaNs
     combos_t = _pad_to(combos6, 0, bm, 100.0).T
 
-    read_m, write_m = charge_sim.margin_grid(
+    return charge_sim.margin_grid(
         cells_t, combos_t, constants,
         interpret=(impl == "pallas_interpret"), bc=bc, bm=bm)
-    return read_m[:n, :m], write_m[:n, :m]
 
 
 def combo_margins(cells: jnp.ndarray, combos: jnp.ndarray, temp_c: float,
@@ -101,4 +120,5 @@ def margin_grid_flops(n_cells: int, n_combos: int) -> int:
     return int(n_cells) * int(n_combos) * per_elem
 
 
-__all__ = ["margin_sweep", "combo_margins", "margin_grid_flops"]
+__all__ = ["margin_sweep", "padded_margin_sweep", "combo_margins",
+           "margin_grid_flops"]

@@ -147,7 +147,6 @@ class TestController:
             for a, b in zip(getattr(one.sweep_result, f),
                             getattr(grp.sweep_result, f)):
                 assert np.array_equal(a, b), f
-        assert grp.sweep_result.margins == ()
 
     def test_reductions_deeper_when_cooler(self, controller):
         r55 = controller.average_reductions(55.0)

@@ -463,9 +463,14 @@ class TestBankTable:
 
     def test_sweep_result_drops_margin_grids(self, controller):
         """profile() keeps the selection views but not the
-        O(cells x combos) raw margin grids."""
+        O(cells x combos) raw margin grids: every array it holds is a
+        per-module view."""
         res = controller.sweep_result
-        assert res.margins == ()
+        m = controller.table.module_params.shape[0]
+        views = [a for f in dataclasses.fields(res)
+                 if isinstance(getattr(res, f.name), tuple)
+                 for a in getattr(res, f.name) if isinstance(a, np.ndarray)]
+        assert views and all(a.shape[0] == m for a in views)
         assert len(res.latency_sum_bank) == len(res.latency_sum) == 2
 
     def test_dynamic_per_bank_closure(self, controller, small_pop):

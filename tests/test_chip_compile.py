@@ -105,10 +105,40 @@ def test_margin_grid_compiles(one_chip, campaign):
     assert c.memory_analysis().output_size_in_bytes < V5E_HBM // 4
 
 
+@pytest.mark.parametrize("campaign", ["refresh", "profile-group"])
+def test_pass_envelopes_compile(one_chip, campaign):
+    """The device reduction of the profile's margin grids to pass
+    envelopes, at the kernel's padded output shapes: it reads the
+    float32 grids without a copy of them (its scratch is under a
+    quarter of their bytes) and returns only booleans."""
+    from repro.core.sweep import _pass_envelopes
+    cpm, m = _cells_per_module(), CALIBRATED_VARIATION.n_modules
+    v = CALIBRATED_VARIATION
+    if campaign == "refresh":
+        cols = refresh_grid().shape[0]
+        cell_shape = (m, v.n_chips, v.n_banks, v.n_cells)
+        axes, blocks = (3,), ((0, 0, cols), (1, 0, cols))
+    else:
+        cols = _profile_columns()
+        g = aldram.PROFILE_GRID_ELEMS // (cpm * cols)
+        cell_shape = (g, v.n_chips, v.n_banks, 1, v.n_cells)
+        half = len(aldram.DEFAULT_TEMP_BINS) * read_combo_grid(
+            DDR3_1600, 1.25).shape[0]
+        axes, blocks = (1, 4), ((0, 0, half), (1, half, cols))
+    n = cell_shape[0] * cpm
+    grid = _sds(one_chip, (_pad(n, charge_sim.BLOCK_CELLS),
+                           _pad(cols, charge_sim.BLOCK_COMBOS)))
+    c = _compile(_pass_envelopes, grid, grid, cell_shape=cell_shape,
+                 axes=axes, blocks=blocks)
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes // 4
+    assert mem.output_size_in_bytes < n * cols // 8
+
+
 def test_full_profile_campaign_is_chunked(one_chip):
     """The whole 115-module timing campaign as ONE dispatch: its two
-    margin grids alone take most of a v5e's HBM, and unpadding copies
-    them again — which is why `profile` runs it in module groups."""
+    margin grids alone take most of a v5e's HBM until they are reduced
+    to envelopes — which is why `profile` runs it in module groups."""
     cpm, m = _cells_per_module(), CALIBRATED_VARIATION.n_modules
     cols = _profile_columns()
     c = _compile(charge_sim.margin_grid,
@@ -119,7 +149,7 @@ def test_full_profile_campaign_is_chunked(one_chip):
     print(f"full-size profile campaign ({m * cpm} cells x {cols} "
           f"columns): {mem}")
     out = mem.output_size_in_bytes
-    assert 2 * out > V5E_HBM            # grids + their unpadded copies
+    assert 2 * out > V5E_HBM            # over half the chip's HBM
     assert m * cpm * cols > aldram.PROFILE_GRID_ELEMS
 
 

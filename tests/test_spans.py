@@ -4,8 +4,9 @@
     `TraceAnnotation`;
   * under a trace, each entry call is one root whose stages carry the
     documented span names, and the self times add up to the root;
-  * the `margin.fetch` bytes are the two margin grids of both profile
-    campaigns, counted from their shapes;
+  * the `margin.fetch` bytes are the pass envelopes of both profile
+    campaigns, counted from their shapes, and its `evals` the margins
+    the benchmark's work count credits a profile with;
   * results and dispatch counts are the same with tracing on and off;
   * the spans land in the written trace on a `/host:` plane, nested in
     the caller's annotation.
@@ -31,6 +32,18 @@ NAMES = {
                          "sim.dispatch", "sim.fetch"},
     "profile": {"aldram.profile", "margin.fetch", "margin.reduce"},
 }
+
+
+def _bench_module(name: str):
+    """bench/<name>.py, the chip benchmark's own module, by path."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", name + ".py")
+    spec = importlib.util.spec_from_file_location("bench_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture(scope="module")
@@ -152,17 +165,24 @@ def test_one_root_and_self_times_add_up(tmp_path, controller, small_pop,
 
 
 def test_margin_fetch_bytes_are_both_grids(tmp_path, controller, small_pop):
+    """Only the pass envelopes cross to the host, one byte a boolean:
+    the refresh campaign's [modules, chips, banks, grid] for each test,
+    the timing campaign's [modules, banks, bins, combos] for each."""
     call, _ = _entry("profile", controller, small_pop)
     call()
     _traced(tmp_path, call)
     fetch = spans.summary()["spans"]["margin.fetch"]
     prof = controller.profiler
-    n_cells = int(np.prod(small_pop.cells.shape[:4]))
-    columns = len(T.refresh_grid()) + 2 * sum(
-        len(prof.combo_grid(op)) for op in ("read", "write"))
-    # read and write grid, float32, refresh + timing campaign
-    assert fetch["bytes"] == 2 * 4 * n_cells * columns
+    m, ch, bk, kc = small_pop.cells.shape[:4]
+    bins = 2
+    combos = [len(prof.combo_grid(op)) for op in ("read", "write")]
+    refresh = 2 * m * ch * bk * len(T.refresh_grid())
+    timing = m * bk * bins * sum(combos)
+    assert fetch["bytes"] == refresh + timing
     assert fetch["n"] == 2
+    work = _bench_module("work")
+    assert fetch["evals"] == work.margin_evals(
+        m * ch * bk * kc, len(T.refresh_grid()), bins, combos)
 
 
 def test_counts_of_the_replay_spans(tmp_path, controller, small_pop):

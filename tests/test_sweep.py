@@ -14,6 +14,7 @@ from repro.core.aldram import ALDRAMController
 from repro.core.calibration import CALIBRATED_CONSTANTS
 from repro.core.profiler import Profiler
 from repro.core.sweep import MarginEngine, Op, OpSweep, SweepSpec
+from repro.core.variation import Population
 from repro.kernels.charge_sim import ops as charge_ops
 
 C = CALIBRATED_CONSTANTS
@@ -44,10 +45,11 @@ class TestFusedMatchesPerBin:
     def test_bit_for_bit_vs_per_bin_combo_margins(self, small_pop, campaign):
         """(a) one fused multi-temperature dispatch == per-bin
         `combo_margins` calls, bitwise, on the ref impl."""
-        prof, spec, res = campaign
+        prof, spec, _ = campaign
+        fused = prof.engine.campaign_margins(small_pop, spec)
         cpm = int(np.prod(small_pop.cells.shape[1:4]))
         cells = jnp.asarray(small_pop.flat_cells())
-        for k, test in enumerate(spec.tests):
+        for test, m3 in zip(spec.tests, fused):
             trefi_cells = jnp.asarray(
                 np.repeat(test.trefi_per_module(small_pop.n_modules), cpm))
             for ti, temp in enumerate(TEMPS):
@@ -55,8 +57,7 @@ class TestFusedMatchesPerBin:
                     cells, jnp.asarray(test.combos), temp, C,
                     impl="ref", trefi_cells=trefi_cells)
                 ref = np.asarray(r if test.op is Op.READ else w)
-                assert np.array_equal(res.margins[k][:, ti, :], ref), \
-                    (test.op, temp)
+                assert np.array_equal(m3[:, ti, :], ref), (test.op, temp)
 
     def test_shim_paths_match_engine(self, small_pop):
         """refresh_profile / timing_profile shims reproduce the raw
@@ -71,6 +72,123 @@ class TestFusedMatchesPerBin:
             Op.READ, prof.combo_grid(Op.READ), (55.0,), rp_read.safe))
         assert np.array_equal(tp.combos, res.chosen[0][:, 0, :])
         assert np.array_equal(tp.pass_per_module, res.ok[0][:, 0, :])
+
+
+class TestDeviceEnvelopes:
+    """The envelopes reduced on the device are, bitwise, the host's
+    reduction of the dense `MarginEngine.margins` grids of the same
+    dispatch layout (`campaign_margins`)."""
+
+    @pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
+    @pytest.mark.parametrize("regions", [1, 2])
+    def test_sweep_envelopes_match_host_reduction(self, small_pop, impl,
+                                                  regions):
+        pop = Population(small_pop.cells[:3, :, :, :4])
+        prof = Profiler(constants=C, grid_step=GRID_STEP, impl=impl)
+        spec = SweepSpec(
+            temps=(55.0, 85.0),
+            tests=(OpSweep(Op.READ, prof.combo_grid(Op.READ), 96.0),
+                   OpSweep(Op.WRITE, prof.combo_grid(Op.WRITE),
+                           np.float32([64.0, 80.0, 72.0]))))
+        res = prof.engine.sweep(pop, spec, regions=regions)
+        m, ch, bk, kc = pop.cells.shape[:4]
+        for k, m3 in enumerate(prof.engine.campaign_margins(pop, spec)):
+            okr = (m3.reshape(m, ch, bk, regions, kc // regions, 2, -1)
+                   >= 0.0).all(4).all(1)
+            assert np.array_equal(res.ok_bank[k], okr.all(2))
+            assert np.array_equal(res.ok[k], okr.all(2).all(1))
+            if regions > 1:
+                assert np.array_equal(res.ok_region[k], okr)
+            # the envelopes select: some combos pass, not all
+            assert 0 < res.ok[k].sum() < res.ok[k].size
+
+    @pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
+    def test_refresh_envelopes_match_host_reduction(self, small_pop, impl):
+        pop = Population(small_pop.cells[:3])
+        prof = Profiler(constants=C, grid_step=GRID_STEP, impl=impl)
+        grid = T.refresh_grid()
+        combos = np.repeat(np.asarray(prof.std.as_array())[None], len(grid),
+                           axis=0)
+        combos[:, 4] = grid
+        m, ch, bk, kc = pop.cells.shape[:4]
+        dense = prof.engine.margins(pop.flat_cells(), combos, temp_c=85.0)
+        host = [(g.reshape(m, ch, bk, kc, -1) >= 0.0).all(3) for g in dense]
+        dev = prof.engine.envelopes(
+            pop.flat_cells(), combos, cell_shape=(m, ch, bk, kc), axes=(3,),
+            blocks=((Op.READ, 0, len(grid)), (Op.WRITE, 0, len(grid))),
+            temp_c=85.0).fetch()
+        for h, d in zip(host, dev):
+            assert d.dtype == np.bool_ and np.array_equal(h, d)
+            assert 0 < h.sum() < h.size
+        for rp, h in zip(prof.refresh_campaign(pop, 85.0), host):
+            for a, b in zip(rp, prof._refresh_envelopes(h, grid)):
+                assert np.array_equal(a, b)
+
+    @pytest.mark.parametrize("shift", [0.0, 0.05])
+    def test_chip_smoke_margin_parity(self, small_pop, monkeypatch, shift):
+        """`chip_smoke.py`'s profile check — a kernel engine's dense
+        margins and device envelopes against the reference engine's —
+        reads zero flips and equal envelopes for the kernel, and sees a
+        kernel whose margins run `shift` high."""
+        import importlib.util
+        import pathlib
+        path = pathlib.Path(__file__).parents[1] / "chip_smoke.py"
+        mod = importlib.util.spec_from_file_location("chip_smoke", path)
+        smoke = importlib.util.module_from_spec(mod)
+        mod.loader.exec_module(smoke)
+        pop = Population(small_pop.cells[:3, :, :, :4])
+        prof = Profiler(constants=C, grid_step=GRID_STEP,
+                        impl="pallas_interpret")
+        spec = prof.campaign_spec((55.0, 85.0),
+                                  *prof.refresh_campaign(pop, 85.0))
+        if shift:
+            real = prof.engine.margins
+            monkeypatch.setattr(prof.engine, "margins", lambda *a, **k: tuple(
+                g + shift for g in real(*a, **k)))
+        got = smoke.margin_parity(
+            prof.engine, MarginEngine(constants=C, std=prof.std, impl="ref"),
+            pop, spec)
+        assert got["margin_cells_compared"] == (
+            int(np.prod(pop.cells.shape[:4])) * 2
+            * sum(t.combos.shape[0] for t in spec.tests))
+        if shift:
+            assert got["margin_max_abs_diff"] > shift / 2
+            assert got["pass_fail_flips"] > 0
+            assert got["envelopes_differing"] > 0
+        else:
+            assert got["margin_max_abs_diff"] < 1e-3
+            assert got["pass_fail_flips"] == got["envelopes_differing"] == 0
+
+    def test_sweeps_launch_each_group_ahead_of_its_fetch(self, small_pop,
+                                                          monkeypatch):
+        """Module groups: each dispatch is launched before the one
+        before it is fetched (the device never waits for the host's
+        selection), at most two in flight, and each group's result is
+        its own `sweep`."""
+        from repro.core import sweep as sweep_mod
+        prof = make_profiler()
+        spec = SweepSpec.single(Op.READ, prof.combo_grid(Op.READ), (85.0,))
+        groups = [(Population(small_pop.cells[lo:lo + 3]), spec)
+                  for lo in (0, 3, 6)]
+        alone = [prof.engine.sweep(p, s) for p, s in groups]
+        events = []
+        launch, fetch = MarginEngine.envelopes, sweep_mod.Envelopes.fetch
+
+        def spy_launch(self, *a, **k):
+            events.append("launch")
+            return launch(self, *a, **k)
+
+        def spy_fetch(self):
+            events.append("fetch")
+            return fetch(self)
+        monkeypatch.setattr(MarginEngine, "envelopes", spy_launch)
+        monkeypatch.setattr(sweep_mod.Envelopes, "fetch", spy_fetch)
+        got = prof.engine.sweeps(groups)
+        assert events == ["launch", "launch", "fetch", "launch", "fetch",
+                          "fetch"]
+        for a, b in zip(alone, got):
+            assert np.array_equal(a.ok[0], b.ok[0])
+            assert np.array_equal(a.chosen_bank[0], b.chosen_bank[0])
 
 
 class TestEnvelopeMonotonicity:
@@ -145,13 +263,13 @@ class TestDispatchCounts:
 
     def _spy(self, monkeypatch):
         calls = []
-        real = charge_ops.margin_sweep
+        real = charge_ops.padded_margin_sweep
 
         def spy(*args, **kwargs):
             calls.append((args[1].shape[0]))   # n_combos per dispatch
             return real(*args, **kwargs)
 
-        monkeypatch.setattr(charge_ops, "margin_sweep", spy)
+        monkeypatch.setattr(charge_ops, "padded_margin_sweep", spy)
         return calls
 
     def test_profile_is_two_dispatches(self, small_pop, monkeypatch):
