@@ -750,7 +750,10 @@ class ALDRAMController:
     def evaluate_system(self, pop: Population,
                         temps: tuple[float, ...] | None = None,
                         n: int = 4096, seed: int = 0,
-                        policies=None, engine=None) -> dict:
+                        policies=None, engine=None, traffic=None,
+                        std: T.TimingParams = T.DDR3_1600,
+                        rank_timings: T.RankTimings | None = None,
+                        t_burst_ns: float = 5.0) -> dict:
         """Close the loop from profiling to the paper's Fig. 4: replay
         the full workload pool under the timings the profiler actually
         measured, one temperature bin at a time — NOT the paper's
@@ -766,6 +769,13 @@ class ALDRAMController:
 
         Returns per-temperature-bin speedup summaries plus the raw
         latency/speedup grids and the campaign's `SimResult`.
+
+        `traffic` (a `dram_sim.KVSpec`) replaces the workload pool: the
+        same rows (`std` the baseline, JEDEC DDR3-1600 by default)
+        replay its streams on the geometry its address map describes,
+        with `rank_timings` (`SimSpec.rank_timings`) and `t_burst_ns`,
+        in one dispatch; the result then carries
+        {"temps", "rows", "result", "policies", "source"}.
         """
         from repro.core import dram_sim, perf_model
         with span("aldram.evaluate_system") as s:
@@ -776,13 +786,24 @@ class ALDRAMController:
             policies = policies or (dram_sim.OPEN_FCFS,)
             m = tbl.params.shape[0]
             rows = np.empty((1 + len(temps), 6), np.float32)
-            rows[0] = T.DDR3_1600.as_row()
+            rows[0] = std.as_row()
             mods = np.arange(m)
             for si, tc in enumerate(temps):
                 # all-safe row: max over modules per parameter at this bin
                 rows[1 + si] = tbl.lookup_many(
                     mods, np.full(m, tc)).max(axis=0)
+            # tREFI and tCL are not profiled: the standard's
+            rows[1:, 4:] = rows[0, 4:]
             s.count(rows=len(rows), policies=len(policies))
+            if traffic is not None:
+                from repro.core.sim_engine import SimEngine, SimSpec
+                res = (engine or SimEngine()).run(SimSpec(
+                    traces=traffic, timings=rows, policies=policies,
+                    n_banks=traffic.n_banks, n_ranks=traffic.n_ranks,
+                    bank_groups=traffic.bank_groups,
+                    rank_timings=rank_timings, t_burst_ns=t_burst_ns))
+                return {"temps": temps, "rows": rows, "result": res,
+                        "policies": policies, "source": "profiled-table"}
 
             em = perf_model.evaluate_many(rows, n=n, seed=seed, engine=engine,
                                           policies=policies,

@@ -433,9 +433,224 @@ class TenantSpec:
         return cache["traces"]
 
 
+def zipf_cdf_u32(n_items: int, theta: float) -> np.ndarray:
+    """Inverse-CDF table of Zipf(theta) over ranks 0..n_items-1 (rank i
+    drawn with probability proportional to (i + 1)**-theta), in 32-bit
+    fixed point: entry i = floor(2**32 * CDF(i)) for i < n_items - 1,
+    computed in float64.  A uniform 32-bit draw u selects rank
+    `searchsorted(table, u, side="right")` — integer arithmetic, the
+    same on every device; each rank's probability is exact to 2**-32."""
+    w = np.arange(1, n_items + 1, dtype=np.float64) ** -float(theta)
+    c = np.cumsum(w)
+    return np.floor(c[:-1] / c[-1] * 2.0 ** 32).astype(np.uint32)
+
+
+def fnv1a64_mod(x, n_items: int):
+    """YCSB's key scramble of a rank x < 2**32 (uint32 array):
+    `Math.abs(FNV-1a-64(x's 8 little-endian bytes)) % n_items`,
+    computed exactly in uint32 (hi, lo) pairs, since a TPU has no
+    64-bit integers.  `n_items` a power of two at most 2**32: the
+    remainder is then the low bits of |h|, and the low 32 bits of -h
+    are -lo."""
+    u32 = jnp.uint32
+    x = jnp.asarray(x).astype(u32)
+    hi = jnp.full(x.shape, 0xCBF29CE4, u32)
+    lo = jnp.full(x.shape, 0x84222325, u32)
+    for i in range(8):
+        if i < 4:
+            lo = lo ^ ((x >> u32(8 * i)) & u32(0xFF))
+        # h * (2**40 + 0x1B3) mod 2**64, with lo * 0x1B3 split in 16-bit
+        # halves so that its carry into hi is exact
+        a = (lo & u32(0xFFFF)) * u32(0x1B3)
+        b = (lo >> u32(16)) * u32(0x1B3)
+        s = (b & u32(0xFFFF)) << u32(16)
+        low = s + a
+        carry = (b >> u32(16)) + (low < s).astype(u32)
+        hi = hi * u32(0x1B3) + carry + (lo << u32(8))
+        lo = low
+    neg = hi >= u32(0x80000000)
+    return jnp.where(neg, u32(0) - lo, lo) & u32(n_items - 1)
+
+
+# The address map of `KVSpec`'s DDR4 channel: bit fields of a line
+# address (the byte address >> 6), low to high.  A 1 KB record slot is
+# 16 line addresses: 8 in each of 2 bank groups, one row.
+KV_ADDRESS_BITS = (("col_low", 3), ("group", 2), ("col_high", 4),
+                   ("bank", 2), ("rank", 1), ("row", 16))
+
+
+def kv_address(line_addr):
+    """(rank, group, bank, row, column) of int32 line addresses under
+    `KV_ADDRESS_BITS`; the column is col_high * 8 + col_low."""
+    f, shift = {}, 0
+    for name, bits in KV_ADDRESS_BITS:
+        f[name] = (line_addr >> shift) & ((1 << bits) - 1)
+        shift += bits
+    return (f["rank"], f["group"], f["bank"], f["row"],
+            f["col_high"] * 8 + f["col_low"])
+
+
+@dataclasses.dataclass(frozen=True)
+class KVSpec:
+    """DECLARATIVE key-value trace batch: YCSB core workload A (Cooper
+    et al., SoCC 2010) on one dual-rank DDR4 channel, fused into the
+    replay dispatch like `SynthSpec`.  Per stream (threefry key folded
+    with the stream index):
+
+      * operations arrive open loop, Poisson with mean `op_interval_ns`;
+      * each reads one record, and with probability `update_frac`
+        then writes one of its 10 fields of 100 bytes back;
+      * the record's rank is Zipf(`theta`) by the inverse CDF
+        (`zipf_cdf_u32`, passed in as a device input), scrambled to a
+        slot of `n_records` as YCSB's ScrambledZipfianGenerator does
+        (`fnv1a64_mod`);
+      * slot s holds bytes [1024 s, 1024 s + 1024): a read is its 16
+        lines, all arriving at the operation's time; an update adds
+        writes of the 2 lines from the one holding the field's first
+        byte;
+      * line addresses map to (rank, group, bank, row, column) by
+        `KV_ADDRESS_BITS`; the `Trace` carries bank = group * 4 + bank
+        and row = (row << 1) | rank, so `chan_rank` on one channel of
+        2 ranks recovers the rank.
+
+    Operations are drawn n // 16 + 1 per stream (each has at least 16
+    lines) and the first `n` lines kept."""
+
+    n: int
+    n_streams: int
+    seed: int = 0
+    update_frac: float = 0.5
+    theta: float = 0.99
+    n_records: int = 1 << 24
+    op_interval_ns: float = 100.0
+
+    # the channel the address map describes
+    n_ranks = 2
+    bank_groups = 4
+    n_banks = 16
+    LINES = 16            # 64 B lines of a 1 KB record slot
+    WRITE_LINES = 2
+    FIELDS = 10
+    FIELD_BYTES = 100
+
+    def __post_init__(self):
+        rec = int(self.n_records)
+        if rec & (rec - 1) or not 2 <= rec <= 1 << 24:
+            raise ValueError(f"n_records must be a power of two in "
+                             f"[2, 2**24], got {rec}")
+        object.__setattr__(self, "_cache", {})
+
+    def __len__(self) -> int:
+        return self.n_streams
+
+    @property
+    def n_ops(self) -> int:
+        """Operations drawn per stream."""
+        return self.n // self.LINES + 1
+
+    def stream_knobs(self):
+        """Per-stream threefry fold ids [T]."""
+        return (jnp.arange(self.n_streams, dtype=jnp.int32),)
+
+    def device_inputs(self) -> tuple:
+        """The Zipf table the synthesis reads, on the device (made and
+        copied once per spec)."""
+        cache = self._cache
+        if "cdf" not in cache:
+            cache["cdf"] = jnp.asarray(zipf_cdf_u32(self.n_records,
+                                                    self.theta))
+        return (cache["cdf"],)
+
+    def _draws(self, off):
+        """(Zipf uniforms u32, update flags, field ids, operation
+        arrival times) [n_ops] of stream `off`."""
+        m = self.n_ops
+        key = jax.random.fold_in(jax.random.PRNGKey(self.seed), off)
+        kz, ku, kf, ka = jax.random.split(key, 4)
+        u = jax.random.bits(kz, (m,), jnp.uint32)
+        update = jax.random.uniform(ku, (m,)) < self.update_frac
+        field = jax.random.randint(kf, (m,), 0, self.FIELDS)
+        t_op = jnp.cumsum(jax.random.exponential(ka, (m,))
+                          * self.op_interval_ns)
+        return u, update, field, t_op
+
+    def _op_ends(self, update):
+        lens = self.LINES + self.WRITE_LINES * update.astype(jnp.int32)
+        return jnp.cumsum(lens), lens
+
+    def synth_traced(self, knobs, cdf):
+        (offs,) = knobs
+        i32 = jnp.int32
+
+        def one(off):
+            u, update, field, t_op = self._draws(off)
+            slot = fnv1a64_mod(jnp.searchsorted(cdf, u, side="right"),
+                               self.n_records).astype(i32)
+            ends, lens = self._op_ends(update)
+            starts = ends - lens
+
+            def per_line(v):
+                """int32 per-operation values v, each on its
+                operation's lines: the steps between operations
+                scattered at their first lines, then a running sum
+                (exact; for 128 streams of 16384 lines on a TPU v5e a
+                gather of one index per line took ~21 ms, a search of
+                the operation ends per line ~235 ms)."""
+                steps = jnp.diff(v, prepend=jnp.zeros((1,), i32))
+                return jnp.cumsum(jnp.zeros((self.n,), i32).at[starts].add(
+                    steps, mode="drop"))
+
+            p = jnp.arange(self.n, dtype=i32) - per_line(starts)
+            rec = per_line(slot * self.FIELDS + field)
+            t = jax.lax.bitcast_convert_type(
+                per_line(jax.lax.bitcast_convert_type(t_op, i32)),
+                jnp.float32)
+            is_write = p >= self.LINES           # p: line of the operation
+            first = rec % self.FIELDS * self.FIELD_BYTES // 64
+            line = jnp.where(is_write, first + p - self.LINES, p)
+            rank, group, bank, row, _ = kv_address(
+                rec // self.FIELDS * self.LINES + line)
+            return Trace(t, group * 4 + bank, row * 2 + rank, is_write)
+
+        return jax.vmap(one)(offs)
+
+    def synth(self, cdf):
+        """The in-dispatch synthesis prologue: [T, n] `Trace` batch
+        (call under jit with `device_inputs()`)."""
+        return self.synth_traced(_runtime_knobs(self.stream_knobs()), cdf)
+
+    def op_count(self) -> int:
+        """YCSB operations with at least one line among the first `n`
+        of their stream, summed over the streams (one small launch,
+        cached on the spec)."""
+        cache = self._cache
+        if "ops" not in cache:
+            def count(off):
+                ends, lens = self._op_ends(self._draws(off)[1])
+                return jnp.sum(ends - lens < self.n)
+
+            cache["ops"] = int(jax.jit(jax.vmap(count))(
+                *self.stream_knobs()).sum())
+        return cache["ops"]
+
+    def materialize(self) -> tuple[Trace, ...]:
+        """Host-side tuple-of-`Trace`s view (one synthesis launch,
+        cached on the instance)."""
+        cache = self._cache
+        if "traces" not in cache:
+            from repro.core import perf_model          # lazy: no cycle
+            perf_model.synth_dispatch_count += 1
+            tb = jax.jit(self.synth)(*self.device_inputs())
+            fields = [np.asarray(f) for f in tb]
+            cache["traces"] = tuple(
+                Trace(*(f[i] for f in fields))
+                for i in range(len(self)))
+        return cache["traces"]
+
+
 # the declarative trace-axis types `sim_engine.SimSpec` accepts and
 # fuses into the replay dispatch
-SYNTH_SPECS = (SynthSpec, TenantSpec)
+SYNTH_SPECS = (SynthSpec, TenantSpec, KVSpec)
 
 
 def check_prefix_valid(valid, where: str = "replay"):
@@ -659,8 +874,28 @@ def _bank_state0(n_banks: int, mlp_window: int) -> BankState:
                      idx=jnp.zeros((), jnp.int32))
 
 
+class BankGroupError(NotImplementedError):
+    """A replay path that does not carry the rank and bank-group state
+    (`SimSpec.rank_timings`) was asked to replay a spec that has it.
+    Raised instead of replaying without the constraints."""
+
+
+# The time of a command never issued: so far in the past that no
+# rank or bank-group constraint measured from it can bind.
+NEVER = -1e30
+
+
+def split_chan(chan):
+    """(n_channels, n_ranks, t_burst, groups) of a static channel
+    geometry: `SimSpec.chan`'s (C, R, t_burst) or, with the rank and
+    bank-group state on, (C, R, t_burst, groups) where groups =
+    (n_groups, trrd_s, trrd_l, tfaw, tccd_s, tccd_l)."""
+    n_ch, n_rk, t_burst, *rest = chan
+    return n_ch, n_rk, t_burst, (tuple(rest[0]) if rest else None)
+
+
 def service_math(t, gate, open_b, act_b, wrd_b, rdy_b, rf, w, trcd,
-                 tras, twr, trp, tcl, closed):
+                 tras, twr, trp, tcl, closed, rank=None):
     """The per-request timing arithmetic on ALREADY-GATHERED bank
     state — pure elementwise jnp, shared verbatim by the three replay
     layouts (`_service`'s scalar gathers, `replay_rows`' timing-row
@@ -674,7 +909,37 @@ def service_math(t, gate, open_b, act_b, wrd_b, rdy_b, rf, w, trcd,
     latency, is_hit).  Latency is measured from *eligibility* (the
     closed-loop gate), not the nominal trace timestamp — under
     saturation the backlog belongs to the CPU-side stall model, not
-    to each DRAM access."""
+    to each DRAM access.
+
+    `rank` (optional) adds the rank and bank-group constraints of a
+    DDR4-style device: ((rank_act, group_act, faw_act, rank_col,
+    group_col, bus), (trrd_s, trrd_l, tfaw, tccd_s, tccd_l)) for a
+    request to rank k, bank group g — rank k's last ACT, group (k, g)'s
+    last ACT, rank k's fourth-last ACT, rank k's and group (k, g)'s
+    last column command (`NEVER` before the first), and the time the
+    channel's data bus frees (`NEVER` on one rank of one channel; the
+    caller then leaves it out of `gate`).  Then
+
+      ACT    = max(candidate, rank_act + tRRD_S, group_act + tRRD_L,
+                   faw_act + tFAW)
+      column = max(ACT + tRCD (a hit: start), bus, rank_col + tCCD_S,
+                   group_col + tCCD_L)
+      done   = column + tCL
+
+    where the candidate is the ACT the bank alone allows (start for an
+    empty bank, conflict start + tRP for a conflict).  So the bus holds
+    back the column command, not the next request's ACT, and ACTs are
+    issued in request order (FCFS) as the constraints allow.  An open
+    bank is ready for its next command at this column, not at `done`:
+    column commands to an open row pipeline, tCCD_L apart (a bank is
+    in its own group), and a conflict may precharge from the column on
+    (no tRTP).  The returns gain (column, stall): stall is the
+    column's delay past max(the column the bank alone allows, bus),
+    exactly 0 where no constraint binds (the same float operations
+    then give the same column).  The caller updates the rank state
+    after every request, and counts completions into the runtime
+    itself, since an open bank's `ready` no longer covers its last
+    data."""
     start = jnp.maximum(jnp.maximum(t, rdy_b), gate)
     is_hit = open_b == rf
     is_empty = open_b == -1
@@ -688,20 +953,103 @@ def service_math(t, gate, open_b, act_b, wrd_b, rdy_b, rf, w, trcd,
     data_start = jnp.where(
         is_hit, start,
         jnp.where(is_empty, start + trcd, conflict_start + trp + trcd))
+    if rank is not None:
+        (r_act, g_act, f_act, r_col, g_col, bus), (trrd_s, trrd_l, tfaw,
+                                                   tccd_s, tccd_l) = rank
+        act_at = jnp.maximum(jnp.maximum(act_new, r_act + trrd_s),
+                             jnp.maximum(g_act + trrd_l, f_act + tfaw))
+        act_new = jnp.where(is_hit, act_b, act_at)
+        bank_col = jnp.maximum(data_start, bus)
+        col = jnp.maximum(
+            jnp.maximum(jnp.where(is_hit, start, act_new + trcd), bus),
+            jnp.maximum(r_col + tccd_s, g_col + tccd_l))
+        stall = col - bank_col
+        data_start = col
     done = data_start + tcl
     wrd_new = jnp.where(w, done + twr, wrd_b)
     # closed-page: auto-precharge after the burst — the row is never
     # left open and the bank re-opens only after the precharge
     # (which itself waits out tRAS-from-ACT and write recovery)
     pre_start = jnp.maximum(jnp.maximum(done, act_new + tras), wrd_new)
-    ready_new = jnp.where(closed, pre_start + trp, done)
+    # with the rank state an open bank takes its next column command
+    # once this one has issued (tCCD_L of its group then spaces them);
+    # without it, once this one's data is done
+    ready_new = jnp.where(closed, pre_start + trp,
+                          done if rank is None else data_start)
     row_latched = jnp.where(closed, jnp.full_like(rf, -1), rf)
-    return (row_latched, act_new, wrd_new, ready_new, done,
-            done - jnp.maximum(t, gate), is_hit)
+    out = (row_latched, act_new, wrd_new, ready_new, done,
+           done - jnp.maximum(t, gate), is_hit)
+    return out if rank is None else out + (data_start, stall)
+
+
+class RankState(NamedTuple):
+    """Rank and bank-group controller state of a geometry with
+    `rank_timings` (C*R ranks of G groups each), shared by the scalar
+    (`replay_one`) and lane-major (`replay_rows`) scans; a trailing
+    lane axis (S,) rides every field in the latter."""
+
+    act: jnp.ndarray       # [CR(, S)] each rank's last ACT
+    col: jnp.ndarray       # [CR(, S)] each rank's last column command
+    g_act: jnp.ndarray     # [CR*G(, S)] each bank group's last ACT
+    g_col: jnp.ndarray     # [CR*G(, S)] each bank group's last column
+    faw: jnp.ndarray       # [CR, 4(, S)] each rank's last four ACTs
+    ptr: jnp.ndarray       # [CR(, S)] int32 ring slot of the oldest
+    stall: jnp.ndarray     # [(S)] summed constraint stall, ns
+
+
+def _rank_state0(n_ranks: int, n_groups: int, lanes=()) -> RankState:
+    never = jnp.full((n_ranks,) + lanes, NEVER)
+    return RankState(act=never, col=never,
+                     g_act=jnp.full((n_ranks * n_groups,) + lanes, NEVER),
+                     g_col=jnp.full((n_ranks * n_groups,) + lanes, NEVER),
+                     faw=jnp.full((n_ranks, 4) + lanes, NEVER),
+                     ptr=jnp.zeros((n_ranks,) + lanes, jnp.int32),
+                     stall=jnp.zeros(lanes))
+
+
+def rank_index(b, ch, rk, n_ranks: int, n_banks: int, n_groups: int):
+    """(global rank, global bank group) of a request to rank-level
+    bank `b` (group b // (n_banks // n_groups)) on channel `ch`,
+    rank `rk`."""
+    k = ch * n_ranks + rk
+    return k, k * n_groups + b // (n_banks // n_groups)
+
+
+def _rank_view(rs: RankState, k, g):
+    """((rank_act, group_act, faw_act, rank_col, group_col) of rank k,
+    group g — `service_math(rank=...)`'s state but the bus — and the
+    one-hot ring slot of rank k's fourth-last ACT)."""
+    ring = (jnp.arange(4).reshape((4,) + (1,) * rs.stall.ndim)
+            == rs.ptr[k])
+    f_act = jnp.sum(jnp.where(ring, rs.faw[k], 0.0), axis=0)
+    return (rs.act[k], rs.g_act[g], f_act, rs.col[k], rs.g_col[g]), ring
+
+
+def _rank_update(rs: RankState, k, g, ring, v, is_hit, act_new, col,
+                 stall) -> RankState:
+    """The rank state after a request (`v` False: padding, unchanged):
+    an ACT (any miss) becomes the rank's and the group's last and
+    replaces the rank's fourth-last in the ring; the column becomes
+    the rank's and the group's last."""
+    acted = v & ~is_hit
+
+    def put(x, i, new, when):
+        return x.at[i].set(jnp.where(when, new, x[i]))
+
+    return RankState(
+        act=put(rs.act, k, act_new, acted),
+        col=put(rs.col, k, col, v),
+        g_act=put(rs.g_act, g, act_new, acted),
+        g_col=put(rs.g_col, g, col, v),
+        faw=put(rs.faw, k, act_new, ring & acted),
+        ptr=put(rs.ptr, k, jnp.where(rs.ptr[k] == 3, 0, rs.ptr[k] + 1),
+                acted),
+        stall=rs.stall + jnp.where(v, stall, 0.0))
 
 
 def _service(s: BankState, t, b, r, w, trcd, tras, twr, trp, tcl,
-             closed, mlp_window: int, extra_gate=None, surcharge=None):
+             closed, mlp_window: int, extra_gate=None, surcharge=None,
+             rank=None):
     """Service ONE request: gathers bank `b`'s state, applies
     `service_math`, scatters the update back.  Shared bit-for-bit
     between `replay_one` (timing scalars fixed for the whole trace)
@@ -713,15 +1061,19 @@ def _service(s: BankState, t, b, r, w, trcd, tras, twr, trp, tcl,
     completion, latency and downstream readiness — the detected-error
     retry price of `repro.core.faults` (the bank stays busy through
     the JEDEC re-issue); None keeps the fault-free arithmetic
-    untouched.  Returns (next state, raw latency, row-hit flag,
-    completion time)."""
+    untouched.  `rank` (optional) is the gathered rank state of
+    `service_math(rank=...)`; the returns then gain (ACT time, column
+    time, constraint stall) for the caller's rank-state update.
+    Returns (next state, raw latency, row-hit flag, completion
+    time)."""
     gate = s.done_ring[s.idx % mlp_window]     # i-window completion
     if extra_gate is not None:
         gate = jnp.maximum(gate, extra_gate)
-    (row_latched, act_new, wrd_new, ready_new, done, lat,
-     is_hit) = service_math(t, gate, s.open_row[b], s.act_time[b],
-                            s.wr_done[b], s.ready[b], r, w, trcd, tras,
-                            twr, trp, tcl, closed)
+    args = (t, gate, s.open_row[b], s.act_time[b], s.wr_done[b],
+            s.ready[b], r, w, trcd, tras, twr, trp, tcl, closed)
+    res = service_math(*args) if rank is None else service_math(*args,
+                                                                rank)
+    row_latched, act_new, wrd_new, ready_new, done, lat, is_hit = res[:7]
     if surcharge is not None:
         done = done + surcharge
         lat = lat + surcharge
@@ -733,13 +1085,16 @@ def _service(s: BankState, t, b, r, w, trcd, tras, twr, trp, tcl,
                    ready=s.ready.at[b].set(ready_new),
                    done_ring=s.done_ring.at[s.idx % mlp_window].set(done),
                    idx=s.idx + 1)
-    return s2, lat, is_hit, done
+    if rank is None:
+        return s2, lat, is_hit, done
+    return s2, lat, is_hit, done, (act_new,) + res[7:]
 
 
 def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
                n_banks: int = 8, mlp_window: int = 8,
                n_channels: int = 1, n_ranks: int = 1, ileave=None,
-               t_burst: float = 5.0, fault=None, region_map=None):
+               t_burst: float = 5.0, fault=None, region_map=None,
+               groups=None):
     """Replay one trace under one stacked timing row and page policy.
 
     arrival/bank/row/is_write: [N] request stream; `valid`: [N] mask
@@ -787,11 +1142,22 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
     region_of(row, regions)]` in-scan — the request's subarray region
     resolves to a unique store row through the index map.  `regions`
     is derived from the map length; `regions == 1` with the identity
-    map and U == banks feeds the exact per-bank gather arithmetic."""
+    map and U == banks feeds the exact per-bank gather arithmetic.
+
+    `groups` (optional, STATIC: (n_groups, trrd_s, trrd_l, tfaw,
+    tccd_s, tccd_l)) adds the rank and bank-group constraints of
+    `service_math(rank=...)`: the carry gains a `RankState` over the
+    C*R ranks, a request's group is its rank-level bank // (n_banks
+    // n_groups), and the returns gain the summed constraint stall.
+    None compiles the exact path without them."""
     banked = tp_row.ndim == 2
     multi = n_channels * n_ranks > 1
     faulted = fault is not None
     regioned = region_map is not None
+    grouped = groups is not None
+    if grouped and (faulted or regioned):
+        raise BankGroupError("replay_one: no fault or region axis with "
+                             "the rank and bank-group state")
     if regioned:
         assert banked, "region_map requires a [U, 6] unique-row store"
         n_regions = region_map.shape[0] // n_banks
@@ -812,13 +1178,19 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
             t, b, r, w, v, u_k = req
         else:
             t, b, r, w, v = req
+        if grouped:
+            carry, rs = carry
         s, cf = carry if multi else (carry, None)
         if multi:
             ch, rk = chan_rank(b, r, il, n_channels, n_ranks, n_banks)
             gb = (ch * n_ranks + rk) * n_banks + b
             eg = cf[ch]
         else:
+            ch, rk = 0, 0
             gb, eg = b, None
+        if grouped:
+            k, grp = rank_index(b, ch, rk, n_ranks, n_banks, groups[0])
+            rk_in, ring = _rank_view(rs, k, grp)
         if regioned:
             g = b * n_regions + region_of(r, n_regions)
             tb = tp_row[region_map[g]]
@@ -839,10 +1211,14 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
             sur = jnp.where(det, j6[4] + f_row[faults.RETRY_NS], 0.0)
         else:
             sur = None
-        s2, lat, _, done = _service(s, t, gb, r, w, tc6[0], tc6[1],
-                                    tc6[2], tc6[3], tc6[4], closed,
-                                    mlp_window, extra_gate=eg,
-                                    surcharge=sur)
+        if grouped:
+            # the channel bus gates the column, not the request's start
+            rank = (rk_in + (NEVER if eg is None else eg,), groups[1:])
+            eg = None
+        s2, lat, is_hit, done, *aux = _service(
+            s, t, gb, r, w, tc6[0], tc6[1], tc6[2], tc6[3], tc6[4],
+            closed, mlp_window, extra_gate=eg, surcharge=sur,
+            rank=rank if grouped else None)
         if multi:
             # the channel data bus is busy for t_burst from the burst
             # start (done - tCL): later requests on this channel wait
@@ -850,6 +1226,9 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
             c1 = (s, cf)
         else:
             c2, c1 = s2, s
+        if grouped:
+            c2 = (c2, _rank_update(rs, k, grp, ring, v, is_hit, *aux[0]))
+            c1 = (c1, rs)
         # padding: keep every state component as-is and emit zero latency
         c3 = jax.tree_util.tree_map(
             lambda new, old: jnp.where(v, new, old), c2, c1)
@@ -866,6 +1245,8 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
 
     s0 = _bank_state0(n_channels * n_ranks * n_banks, mlp_window)
     carry0 = (s0, jnp.zeros((n_channels,))) if multi else s0
+    if grouped:
+        carry0 = (carry0, _rank_state0(n_channels * n_ranks, groups[0]))
     xs = (arrival, bank, row, is_write, valid)
     if faulted:
         carry0 = (carry0, faults.wd_state0(),
@@ -875,19 +1256,27 @@ def replay_one(arrival, bank, row, is_write, valid, tp_row, closed,
     c_end, lat = jax.lax.scan(step, carry0, xs)
     if faulted:
         c_end, _, cnt_end = c_end
+    if grouped:
+        c_end, rs_end = c_end
     s_end = c_end[0] if multi else c_end
     # runtime includes the trailing write-recovery window: the module is
     # busy until the last write has restored, not just until last data
     total = jnp.maximum(s_end.ready.max(), s_end.wr_done.max())
     if faulted:
         return lat, total, jnp.stack(cnt_end)
+    if grouped:
+        # the latest completion is among the last mlp_window (each
+        # request issues after the one mlp_window before completed)
+        return (lat, jnp.maximum(total, s_end.done_ring.max()),
+                rs_end.stall)
     return lat, total
 
 
 def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
                 n_banks: int = 8, mlp_window: int = 8,
                 n_channels: int = 1, n_ranks: int = 1, ileave=None,
-                t_burst: float = 5.0, fault=None, region_map=None):
+                t_burst: float = 5.0, fault=None, region_map=None,
+                groups=None):
     """Replay one trace under a whole [S, 6] STACK of timing rows in
     one `lax.scan` — the timing-row axis rides the minor (lane) axis
     of the carried bank state ([B, 4, S] packed as open-row/act/
@@ -933,11 +1322,19 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
     every LANE its own index map — the fleet-serve layout where the
     lane axis is the module axis and each module compresses
     differently.  Constant-region input replays bit-identical to the
-    per-bank [S, banks, 6] path."""
+    per-bank [S, banks, 6] path.
+
+    `groups` (optional, STATIC) matches `replay_one`: the carry gains a
+    lane-major `RankState` and the returns the [S] summed constraint
+    stall."""
     banked = timings.ndim == 3
     multi = n_channels * n_ranks > 1
     faulted = fault is not None
     regioned = region_map is not None
+    grouped = groups is not None
+    if grouped and (faulted or regioned):
+        raise BankGroupError("replay_rows: no fault or region axis with "
+                             "the rank and bank-group state")
     if regioned:
         assert banked, "region_map requires [S, U, 6] unique stores"
         n_regions = region_map.shape[-1] // n_banks
@@ -966,6 +1363,8 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
             t, b, r, w, v, u_k = req
         else:
             t, b, r, w, v = req
+        if grouped:
+            st, rs = st
         if multi:
             bs, ring, cf, idx = st      # [CRB, 4, S], [W, S], [C, S]
         else:
@@ -974,10 +1373,16 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
             ch, rk = chan_rank(b, r, il, n_channels, n_ranks, n_banks)
             gb = (ch * n_ranks + rk) * n_banks + b
         else:
+            ch, rk = 0, 0
             gb = b
+        if grouped:
+            k, grp = rank_index(b, ch, rk, n_ranks, n_banks, groups[0])
+            rk_in, fring = _rank_view(rs, k, grp)
         rowb = bs[gb]                   # [4, S] one gather per request
         gate0 = ring[idx % mlp_window]  # [S]
-        gate = (jnp.maximum(gate0, cf[ch]) if multi else gate0)
+        # with the rank state the channel bus gates the column instead
+        gate = (jnp.maximum(gate0, cf[ch]) if multi and not grouped
+                else gate0)
         rf = r.astype(jnp.float32)
         if regioned:
             g = b * n_regions + region_of(r, n_regions)
@@ -1000,10 +1405,12 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
             p = faults.error_prob(fpT, red, 0.0)
             _, det, sil = faults.error_draw(fpT, u_k, p)
             sur = jnp.where(det, j6[4] + fpT[faults.RETRY_NS], 0.0)
-        (latched, act_new, wrd_new, rdy_new, done, lat,
-         _) = service_math(t, gate, rowb[0], rowb[1], rowb[2], rowb[3],
-                           rf, w, tc_[0], tc_[1], tc_[2], tc_[3],
-                           tc_[4], closed)
+        args = (t, gate, rowb[0], rowb[1], rowb[2], rowb[3], rf, w,
+                tc_[0], tc_[1], tc_[2], tc_[3], tc_[4], closed)
+        res = (service_math(*args, (rk_in + (cf[ch] if multi else NEVER,),
+                                    groups[1:]))
+               if grouped else service_math(*args))
+        latched, act_new, wrd_new, rdy_new, done, lat, is_hit = res[:7]
         if faulted:
             done = done + sur
             lat = lat + sur
@@ -1020,6 +1427,9 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
             st2 = (bs2, ring2, cf2, idx2)
         else:
             st2 = (bs2, ring2, idx2)
+        if grouped:
+            st2 = (st2, _rank_update(rs, k, grp, fring, v, is_hit,
+                                     act_new, *res[7:]))
         if faulted:
             degraded = wd[4] > 0
             wd2, new_trip = faults.wd_update(fpT, wd, det, False,
@@ -1037,6 +1447,9 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
     st0 = (bs0, jnp.zeros((mlp_window, s_rows)))
     st0 += ((jnp.zeros((n_channels, s_rows)),) if multi else ())
     st0 += (jnp.zeros((), jnp.int32),)
+    if grouped:
+        st0 = (st0, _rank_state0(n_channels * n_ranks, groups[0],
+                                 (s_rows,)))
     xs = (arrival, bank, row, is_write, valid)
     if faulted:
         st0 = (st0, faults.wd_state0((s_rows,)),
@@ -1046,10 +1459,15 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
     st_end, lat = jax.lax.scan(step, st0, xs)
     if faulted:
         st_end, _, cnt_end = st_end
+    if grouped:
+        st_end, rs_end = st_end
     bse = st_end[0]
     total = jnp.maximum(bse[:, 3].max(0), bse[:, 2].max(0))
     if faulted:
         return lat.T, total, jnp.stack(cnt_end)   # + [NC, S]
+    if grouped:                          # + completions (replay_one)
+        return (lat.T, jnp.maximum(total, st_end[1].max(0)),
+                rs_end.stall)            # + [S]
     return lat.T, total                  # [S, N], [S]
 
 

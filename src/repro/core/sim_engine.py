@@ -70,11 +70,12 @@ import numpy as np
 from repro.core import faults
 from repro.core import timing as T
 from repro.core.autotune import ReplayConfig, ReplayTuner, replay_unit
-from repro.core.dram_sim import (OPEN_FCFS, SYNTH_SPECS, Policy,
-                                 SynthSpec, TenantSpec, Trace,
-                                 check_prefix_valid, frfcfs_perm,
+from repro.core.dram_sim import (OPEN_FCFS, SYNTH_SPECS, BankGroupError,
+                                 KVSpec, Policy, SynthSpec, TenantSpec,
+                                 Trace, check_prefix_valid, frfcfs_perm,
                                  frfcfs_reorder, replay_adaptive,
-                                 replay_rows, replay_rows_frfcfs)
+                                 replay_rows, replay_rows_frfcfs,
+                                 split_chan)
 from repro.core.spans import span
 from repro.core.thermal import ThermalSpec
 
@@ -124,10 +125,10 @@ class SimSpec:
     materialize O(grid * N) arrays host-side.  The host-stats reference
     path always materializes them (it needs the raw grid anyway)."""
 
-    # tuple of `Trace`s, or a `dram_sim.SynthSpec` / `TenantSpec` —
-    # the DECLARATIVE trace batch whose synthesis the engine fuses
-    # INTO the replay dispatch (the whole campaign is one launch)
-    traces: tuple[Trace, ...] | SynthSpec | TenantSpec
+    # tuple of `Trace`s, or a `dram_sim.SynthSpec` / `TenantSpec` /
+    # `KVSpec` — the DECLARATIVE trace batch whose synthesis the engine
+    # fuses INTO the replay dispatch (the whole campaign is one launch)
+    traces: tuple[Trace, ...] | SynthSpec | TenantSpec | KVSpec
     # [S, 6] rows | per-bank [S, banks, 6] | adaptive [K, S+1, 6] |
     # adaptive per-bank [K, S+1, banks, 6]
     timings: np.ndarray
@@ -163,6 +164,15 @@ class SimSpec:
     # the map in-scan.  None compiles the EXACT dense per-bank (or
     # per-module) path — a static branch, like `faults=None`.
     region_map: np.ndarray | None = None
+    # rank-level and bank-group timings (`timing.RankTimings`): None
+    # compiles the exact path without them; set, every rank carries its
+    # last ACT / column, a four-ACT window and per-group last ACT /
+    # column (`dram_sim.service_math(rank=...)`), with bank group =
+    # rank-level bank // (n_banks // bank_groups).  Static FCFS replay
+    # only (`backend` scan or pallas); other paths raise
+    # `dram_sim.BankGroupError`.
+    bank_groups: int = 1
+    rank_timings: "T.RankTimings | None" = None
 
     def __post_init__(self):
         tr = self.traces
@@ -178,6 +188,11 @@ class SimSpec:
         object.__setattr__(self, "traces", tr)
         assert self.n_channels >= 1 and self.n_ranks >= 1, \
             (self.n_channels, self.n_ranks)
+        if self.n_banks % self.bank_groups or (
+                self.bank_groups > 1 and self.rank_timings is None):
+            raise ValueError(
+                f"{self.bank_groups} bank groups need rank_timings and "
+                f"a bank count they divide ({self.n_banks})")
         object.__setattr__(
             self, "timings",
             _as_rows(self.timings) if self.thermal is None else
@@ -245,10 +260,21 @@ class SimSpec:
                 else None)
 
     @property
+    def grouped(self) -> bool:
+        """True when the replay carries the rank and bank-group state."""
+        return self.rank_timings is not None
+
+    @property
     def chan(self) -> tuple:
         """The STATIC channel geometry (n_channels, n_ranks,
-        t_burst_ns) threaded through the jitted replay bodies."""
-        return (self.n_channels, self.n_ranks, float(self.t_burst_ns))
+        t_burst_ns) threaded through the jitted replay bodies, plus
+        (bank_groups, trrd_s, trrd_l, tfaw, tccd_s, tccd_l) when the
+        spec is `grouped` (`dram_sim.split_chan`)."""
+        base = (self.n_channels, self.n_ranks, float(self.t_burst_ns))
+        if not self.grouped:
+            return base
+        return base + ((self.bank_groups,)
+                       + self.rank_timings.as_tuple(),)
 
     @property
     def ileave_codes(self) -> np.ndarray:
@@ -311,6 +337,11 @@ class SimSpec:
         via the retained Python loop, cached across calls) plus the
         [T, N] validity mask and the per-policy closed-page flags."""
         tr, pol = self.trace_tuple(), self.policies
+        if self.grouped and any(p.reorder_window > 1 and not p.closed
+                                for p in pol):
+            raise BankGroupError(
+                "frfcfs_reorder: the host FR-FCFS reorder keys open rows "
+                "on rank-level banks; replay rank-constrained specs FCFS")
         lens = [int(np.asarray(t.arrival).shape[0]) for t in tr]
         n = max(lens)
         tp_ = (len(tr), len(pol))
@@ -378,6 +409,10 @@ class SimResult:
     bin_switches: np.ndarray | None = None  # [T, P, K, C]
     bank_heat: np.ndarray | None = None     # [T, P, K, C, B] end C
     fault_counters: np.ndarray | None = None  # [..., F, N_COUNTERS]
+    # static replays: summed delay of each lane's column commands past
+    # what their banks alone allowed (tRRD, tFAW, tCCD); zeros without
+    # `SimSpec.rank_timings`
+    constraint_stall_ns: np.ndarray | None = None   # [T, P, S]
 
     def _counter(self, i: int):
         return (None if self.fault_counters is None
@@ -500,7 +535,11 @@ def _merged_replay(arrival, bank, row, is_write, valid, timings, closed,
     t, n = arrival.shape
     p = closed.shape[0]
     s = timings.shape[0]
-    n_ch, n_rk, t_burst = chan
+    n_ch, n_rk, t_burst, groups = split_chan(chan)
+    if groups is not None:
+        raise BankGroupError(
+            "backend='merged': replay_rows_frfcfs carries no rank or "
+            "bank-group state; replay with backend='scan' or 'pallas'")
     il = (jnp.zeros((p,), jnp.int32) if ileave is None
           else jnp.asarray(ileave, jnp.int32))
     lat = jnp.zeros((t, p, s, n))
@@ -626,12 +665,18 @@ def _device_thermal_diag(temps, bin_sel, valid):
     return tmax, tmean, switches
 
 
-def _synth_streams(synth):
+def _synth_inputs(synth) -> tuple:
+    """The device arrays a synthesis spec reads (a `KVSpec`'s Zipf
+    table), passed to the dispatch as arguments, not constants."""
+    return synth.device_inputs() if isinstance(synth, KVSpec) else ()
+
+
+def _synth_streams(synth, inputs=()):
     """In-dispatch synthesis prologue: a `SynthSpec` (static) becomes
     the [T, n] FCFS streams + an all-True valid mask, traced INSIDE
     the replay dispatch (threefry is deterministic, so the streams are
     bit-identical to `SynthSpec.materialize`)."""
-    tb = synth.synth()
+    tb = synth.synth(*inputs)
     valid = jnp.ones(tb.arrival.shape, bool)
     return tb.arrival, tb.bank, tb.row, tb.is_write, valid
 
@@ -674,11 +719,21 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
     stacks — a [G] map shared across lanes or an [S, G] per-lane map
     (G = banks * regions); every backend gathers each request's
     (bank, region) row through the map in-scan.
+
+    A `chan` with bank groups (`SimSpec.grouped`) replays FCFS through
+    the scan or the kernel, both carrying the rank state, and
+    `out["stall"]` carries the [T, P, S] summed constraint stall; the
+    FR-FCFS prepass, faults and regions raise `BankGroupError`.
     """
-    n_ch, n_rk, t_burst = chan
+    n_ch, n_rk, t_burst, groups = split_chan(chan)
+    if groups is not None and (reorder_plan or fault is not None
+                               or region_map is not None):
+        raise BankGroupError(
+            "the FR-FCFS prepass (frfcfs_perm), the faulted and the "
+            "region replays carry no rank or bank-group state")
     il = (jnp.zeros((closed.shape[0],), jnp.int32) if ileave is None
           else jnp.asarray(ileave, jnp.int32))
-    cnt = None
+    cnt = stall = None
     if fault is not None:
         f_rows, j_row, fkey = fault
         u = faults.fault_uniforms(fkey, valid.shape[0], valid.shape[1])
@@ -706,7 +761,7 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
                                    mlp_window, n_channels=n_ch,
                                    n_ranks=n_rk, ileave=i_,
                                    t_burst=t_burst, fault=fl,
-                                   region_map=region_map)
+                                   region_map=region_map, groups=groups)
 
             f_p = jax.vmap(one, in_axes=(0, 0, 0, 0, None, 0, 0, None))
             f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, 0, None, None, 0))
@@ -724,8 +779,12 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
             lat, total = res[:2]
             if fault is not None:
                 cnt = res[2]
+        if groups is not None:
+            stall = res[2]
 
     out = {"total": total}
+    if stall is not None:
+        out["stall"] = stall
     if "stats" in want:
         out["mean"], out["p99"] = _device_stats(lat, valid, p99_k)
     if "lat" in want:
@@ -774,7 +833,11 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
     """
     rm_ax = (0 if region_map is not None and region_map.ndim == 2
              else None)
-    n_ch, n_rk, t_burst = chan
+    n_ch, n_rk, t_burst, groups = split_chan(chan)
+    if groups is not None:
+        raise BankGroupError(
+            f"backend={backend!r}: the adaptive replay (replay_adaptive, "
+            f"adaptive_blocks) carries no rank or bank-group state")
     il = (jnp.zeros((closed.shape[0],), jnp.int32) if ileave is None
           else jnp.asarray(ileave, jnp.int32))
     if fault is not None:
@@ -875,7 +938,7 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
 def _replay_grid(synth, n_banks, mlp_window, reorder_plan, backend,
                  want, p99_k, bs, chan, arrival, bank, row, is_write,
                  valid, timings, closed, slacks, caps, ileave,
-                 region_map=None, fault=None):
+                 region_map=None, fault=None, synth_inputs=()):
     """ONE dispatch: (optional in-dispatch trace synthesis +) static
     replay grid — see `_static_body`.  `synth` (static) is None for
     materialized streams, or the campaign's `dram_sim.SynthSpec` /
@@ -885,10 +948,12 @@ def _replay_grid(synth, n_banks, mlp_window, reorder_plan, backend,
     the merged core's rolling-ring `all_valid` form).  `chan` (static)
     is the `SimSpec.chan` channel geometry; `ileave` the per-policy
     interleave-code column; `fault` the optional (fault_rows,
-    jedec_row, key) lane expansion of `_static_body`."""
+    jedec_row, key) lane expansion of `_static_body`; `synth_inputs`
+    the device arrays the synthesis reads (`_synth_inputs`)."""
     all_valid = synth is not None
     if all_valid:
-        arrival, bank, row, is_write, valid = _synth_streams(synth)
+        arrival, bank, row, is_write, valid = _synth_streams(
+            synth, synth_inputs)
     return _static_body(n_banks, mlp_window, reorder_plan, backend,
                         want, p99_k, bs, arrival, bank, row, is_write,
                         valid, timings, closed, slacks, caps,
@@ -901,13 +966,14 @@ def _replay_grid_adaptive(synth, n_banks, mlp_window, reorder_plan,
                           backend, want, p99_k, bs, chan, arrival,
                           bank, row, is_write, valid, tables, bins,
                           scns, tcfg, closed, slacks, caps, ileave,
-                          region_map=None, fault=None):
+                          region_map=None, fault=None, synth_inputs=()):
     """ONE dispatch: (optional in-dispatch trace synthesis +)
     closed-loop adaptive replay grid — see `_adaptive_body` and
     `_replay_grid`'s `synth` contract; `fault` the optional
     (fault_rows, key) fault axis of `_adaptive_body`."""
     if synth is not None:
-        arrival, bank, row, is_write, valid = _synth_streams(synth)
+        arrival, bank, row, is_write, valid = _synth_streams(
+            synth, synth_inputs)
     return _adaptive_body(n_banks, mlp_window, reorder_plan, backend,
                           want, p99_k, bs, arrival, bank, row,
                           is_write, valid, tables, bins, scns, tcfg,
@@ -920,7 +986,7 @@ def _replay_grid_adaptive(synth, n_banks, mlp_window, reorder_plan,
 def _bracket_grid(synth, n_banks, mlp_window, reorder_plan, backend,
                   p99_k, n_real, bs, chan, arrival, bank, row,
                   is_write, valid, tables, bins, scns, tcfg, closed,
-                  slacks, caps, base_row, ileave):
+                  slacks, caps, base_row, ileave, synth_inputs=()):
     """ONE dispatch for the whole adaptive-vs-bracket evaluation
     (`perf_model.evaluate_adaptive`'s inner loop): in-dispatch
     synthesis (when `synth` is set) + the adaptive campaign + the
@@ -938,7 +1004,8 @@ def _bracket_grid(synth, n_banks, mlp_window, reorder_plan, backend,
     "temp_peak" [n_real]} with both halves reduced via "stats".
     """
     if synth is not None:
-        arrival, bank, row, is_write, valid = _synth_streams(synth)
+        arrival, bank, row, is_write, valid = _synth_streams(
+            synth, synth_inputs)
     out_a = _adaptive_body(n_banks, mlp_window, reorder_plan, backend,
                            ("stats",), p99_k, bs, arrival, bank, row,
                            is_write, valid, tables, bins, scns, tcfg,
@@ -1306,17 +1373,21 @@ class SimEngine:
                 return _replay_grid(synth, spec.n_banks,
                                     spec.mlp_window, plan, backend,
                                     want, p99_k, bs, chan, *streams,
-                                    *extras, fault=fault)
+                                    *extras, fault=fault,
+                                    synth_inputs=_synth_inputs(synth))
             if kind == "adaptive":
                 return _replay_grid_adaptive(
                     synth, spec.n_banks, spec.mlp_window, plan,
                     backend, want, p99_k, bs, chan, *streams, *extras,
-                    fault=fault)
+                    fault=fault, synth_inputs=_synth_inputs(synth))
             return _bracket_grid(synth, spec.n_banks, spec.mlp_window,
                                  plan, backend, p99_k, n_real, bs,
-                                 chan, *streams, *extras)
+                                 chan, *streams, *extras,
+                                 synth_inputs=_synth_inputs(synth))
         assert fault is None, \
             "fault campaigns are single-device (no mesh sharding yet)"
+        assert not _synth_inputs(synth), \
+            "KVSpec campaigns are single-device (no mesh sharding yet)"
         assert self.stats == "device" and self.reorder == "device", \
             "sharded campaigns need device stats + device reorder"
         n_dev = self.mesh.shape["campaign"]
@@ -1375,6 +1446,11 @@ class SimEngine:
             (synth, arrival, bank, row, is_write, valid_d, valid, closed,
              slacks, caps, plan) = self._streams(spec, fuse)
             s.count(streams=valid.shape[0], requests=valid.size)
+            if spec.grouped:
+                s.count(ranks=spec.n_channels * spec.n_ranks,
+                        bank_groups=spec.bank_groups)
+            if isinstance(spec.traces, KVSpec):
+                s.count(ops=spec.traces.op_count())
         self.dispatch_count += 1
         fa = spec.faults
         f_on = spec.fault_on
@@ -1431,10 +1507,13 @@ class SimEngine:
                         for x in (mean, p99, total, lat))
                     cnt = np.zeros(total.shape + (faults.N_COUNTERS,),
                                    np.int32)
+                stall = (np.asarray(out["stall"]) if "stall" in out
+                         else np.zeros_like(total))
                 return SimResult(spec=spec, mean_latency_ns=mean,
                                  p99_latency_ns=p99, total_ns=total,
                                  latencies=lat, valid=valid,
-                                 fault_counters=cnt)
+                                 fault_counters=cnt,
+                                 constraint_stall_ns=stall)
 
         scns, bins, tcfg = spec.thermal.pack()
         fault = (None if not f_on else

@@ -31,7 +31,9 @@ in them:
 
   sim.prep       stream packing: SimSpec's split of a batched Trace,
                  SimEngine's packing and reorder plan [streams,
-                 requests: the packed [streams, n] slots]
+                 requests: the packed [streams, n] slots; with rank
+                 timings ranks, bank_groups; for a `KVSpec` ops: the
+                 YCSB operations with a line among the streams']
   sim.dispatch   the launch of a synthesis or replay program
   sim.fetch      the replay's results turned into numpy arrays
   margin.fetch   the wait for one margin dispatch and the copy of its

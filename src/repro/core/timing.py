@@ -77,6 +77,46 @@ DDR3_1600 = TimingParams(trcd=13.75, tras=35.0, twr=15.0, trp=13.75)
 ALDRAM_55C_EVAL = DDR3_1600.scaled(1 - 0.27, 1 - 0.32, 1 - 0.33, 1 - 0.18)
 
 
+@dataclasses.dataclass(frozen=True)
+class RankTimings:
+    """Rank-level and bank-group timings (ns) of a DDR4-style device,
+    which AL-DRAM leaves at their JEDEC values:
+
+      trrd_s / trrd_l  ACT -> ACT in one rank, different / same bank group
+      tfaw             window holding at most four ACTs of one rank
+      tccd_s / tccd_l  column -> column in one rank, different / same
+                       bank group
+    """
+
+    trrd_s: float
+    trrd_l: float
+    tfaw: float
+    tccd_s: float
+    tccd_l: float
+
+    def as_tuple(self) -> tuple[float, ...]:
+        return (float(self.trrd_s), float(self.trrd_l), float(self.tfaw),
+                float(self.tccd_s), float(self.tccd_l))
+
+
+# JEDEC JESD79-4 DDR4-2400 (speed bin 16-16-16, tCK 0.833 ns = 1.2 GHz),
+# every value in whole clocks as a controller programs them: tRCD = tRP
+# = CL = 16 nCK, tRAS = 39 nCK (>= 32 ns), tWR = 18 nCK (15 ns); 8 Gb x8
+# (1 KB page): tRRD_S = 4 nCK, tRRD_L = 6 nCK (>= 4.9 ns), tFAW = 26 nCK
+# (>= 21 ns), tCCD_S = 4 nCK, tCCD_L = 6 nCK (>= 5 ns).
+DDR4_2400_TCK_NS = 1.0 / 1.2
+DDR4_2400 = TimingParams(trcd=16 * DDR4_2400_TCK_NS,
+                         tras=39 * DDR4_2400_TCK_NS,
+                         twr=18 * DDR4_2400_TCK_NS,
+                         trp=16 * DDR4_2400_TCK_NS,
+                         tcl=16 * DDR4_2400_TCK_NS)
+DDR4_2400_RANK = RankTimings(trrd_s=4 * DDR4_2400_TCK_NS,
+                             trrd_l=6 * DDR4_2400_TCK_NS,
+                             tfaw=26 * DDR4_2400_TCK_NS,
+                             tccd_s=4 * DDR4_2400_TCK_NS,
+                             tccd_l=6 * DDR4_2400_TCK_NS)
+
+
 def stack_timing(params: "Sequence[TimingParams]") -> np.ndarray:
     """Stack timing-parameter sets into the [S, 6] row matrix a batched
     replay campaign sweeps in one dispatch (see `as_row` for columns)."""

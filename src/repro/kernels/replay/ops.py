@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.dram_sim import check_prefix_valid
+from repro.core.dram_sim import check_prefix_valid, split_chan
 from repro.kernels import resolve_impl
 from repro.kernels.replay import ref, replay
 
@@ -60,6 +60,10 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
     stack — a [G] map shared across lanes or an [S, G] per-lane map
     (G = banks * regions); the kernel path tiles it to [G, S_pad] and
     gathers through it in VMEM.
+
+    A `chan` with bank groups (`dram_sim.split_chan`) replays with the
+    rank and bank-group state; the returns gain the [T, P, S] summed
+    constraint stall.
     """
     check_prefix_valid(valid, "replay_grid")
     impl = resolve_impl(impl)
@@ -122,6 +126,8 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
     # [G, N, S_pad] -> [T, P, S, N]
     lat = lat[:, :, :s].reshape(t, p, n, s).transpose(0, 1, 3, 2)
     total = total[:, :s].reshape(t, p, s)
+    if split_chan(chan)[3] is not None:
+        return lat, total, out[2][:, :s].reshape(t, p, s)
     if fault is None:
         return lat, total
     cnt = jnp.stack([c[:, :s].reshape(t, p, s) for c in out[2:]],

@@ -10,7 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.dram_sim import replay_adaptive, replay_one
+from repro.core.dram_sim import replay_adaptive, replay_one, split_chan
 
 
 @functools.partial(jax.jit,
@@ -36,8 +36,12 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
     switches `timings` to the mask-compressed [S, U, 6] unique-store
     stack: a [G] map is shared across timing lanes, an [S, G] map
     rides the lane vmap so every lane gathers through its own index
-    map (the fleet-serve per-module layout)."""
-    n_ch, n_rk, t_burst = chan
+    map (the fleet-serve per-module layout).
+
+    A `chan` with bank groups (`dram_sim.split_chan`) replays with the
+    rank and bank-group state and returns gain the [T, P, S] summed
+    constraint stall."""
+    n_ch, n_rk, t_burst, groups = split_chan(chan)
     il = (jnp.zeros((arrival.shape[1],), jnp.int32) if ileave is None
           else jnp.asarray(ileave, jnp.int32))
     rm_ax = (0 if region_map is not None and region_map.ndim == 2
@@ -51,7 +55,7 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
                               mlp_window, n_channels=n_ch,
                               n_ranks=n_rk, ileave=i_,
                               t_burst=t_burst, fault=(fr, j_row, uu),
-                              region_map=rm)
+                              region_map=rm, groups=groups)
 
         f_s = jax.vmap(one_f, in_axes=(None, None, None, None, None,
                                        0, None, None, 0, None, rm_ax))
@@ -66,7 +70,7 @@ def replay_grid(arrival, bank, row, is_write, valid, timings, closed,
     def one(a, b, r, w, v, tp, c, i_, rm):
         return replay_one(a, b, r, w, v, tp, c, n_banks, mlp_window,
                           n_channels=n_ch, n_ranks=n_rk, ileave=i_,
-                          t_burst=t_burst, region_map=rm)
+                          t_burst=t_burst, region_map=rm, groups=groups)
 
     f_s = jax.vmap(one, in_axes=(None, None, None, None, None, 0,
                                  None, None, rm_ax))

@@ -45,6 +45,15 @@ each data transfer.  Per-bank timing tables keep their rank-level
 per-channel.  C*R == 1 compiles the exact single-channel kernel (the
 channel branches are static).
 
+A geometry with bank groups (`chan` = (C, R, t_burst, groups), see
+`dram_sim.split_chan`) adds the rank state of `dram_sim.RankState` as
+scratch tiles — each rank's last ACT and column [C*R, BLOCK_ROWS],
+each group's last ACT and column [C*R*G, BLOCK_ROWS], the four-ACT
+ring [C*R*4, BLOCK_ROWS] with its int32 slot pointer [C*R,
+BLOCK_ROWS] — gathered and updated with the same one-hot masks, the
+ring slot per lane, and a per-lane stall output row; without groups
+the kernel is the exact one before them.
+
 Per-cell flags (closed page, interleave code) and the small constant
 rows (JEDEC row, thermal constants) sit whole in SMEM; per-lane
 outputs are [G, 1, lanes] rows so every block meets the (8, 128)
@@ -66,7 +75,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import faults
-from repro.core.dram_sim import chan_rank, region_of, service_math
+from repro.core.dram_sim import (NEVER, BankGroupError, chan_rank,
+                                 rank_index, region_of, service_math,
+                                 split_chan)
 from repro.core.power import access_energy_from_terms
 from repro.core.thermal import ambient_at, heat_decay, overheat_sum
 
@@ -142,6 +153,8 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
         # shared maps broadcast) — the request's (bank, region) slot
         # resolves to a unique row via two chained one-hot reduces
         map_ref, *refs = refs
+    n_ch, n_rk, t_burst, groups = split_chan(chan)
+    grouped = groups is not None
     if faulted:
         # extra inputs: lane-tiled fault rows [F_COLS, bs], the JEDEC
         # fallback column [6, 1], per-cell issue-order uniforms [1, N];
@@ -151,11 +164,14 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
          sil_ref, trp_ref, deg_ref, prb_ref, open_s, act_s, wrd_s,
          rdy_s, ring_s, cf_s, wde_s, wdb_s, wdc_s, wdp_s,
          wdt_s) = refs
+    elif grouped:
+        (lat_ref, total_ref, stall_ref, open_s, act_s, wrd_s, rdy_s,
+         ring_s, cf_s, ract_s, rcol_s, gact_s, gcol_s, faw_s,
+         fptr_s) = refs
     else:
         (lat_ref, total_ref, open_s, act_s, wrd_s, rdy_s, ring_s,
          cf_s) = refs
     bs = lat_ref.shape[-1]
-    n_ch, n_rk, t_burst = chan
     multi = n_ch * n_rk > 1          # static: C*R == 1 keeps the
     nb_tot = n_ch * n_rk * n_banks   # original single-channel kernel
     cell = pl.program_id(0)
@@ -178,6 +194,16 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
         bank_iota_b = jax.lax.broadcasted_iota(jnp.int32,
                                                (n_banks, bs), 0)
         chan_iota = jax.lax.broadcasted_iota(jnp.int32, (n_ch, bs), 0)
+    if grouped:
+        n_g, rank_t = groups[0], groups[1:]
+        cr = n_ch * n_rk
+        rank_iota = jax.lax.broadcasted_iota(jnp.int32, (cr, bs), 0)
+        grp_iota = jax.lax.broadcasted_iota(jnp.int32, (cr * n_g, bs), 0)
+        faw_iota = jax.lax.broadcasted_iota(jnp.int32, (cr * 4, bs), 0)
+        for s_ in (ract_s, rcol_s, gact_s, gcol_s, faw_s):
+            s_[...] = jnp.full(s_.shape, NEVER, jnp.float32)
+        fptr_s[...] = jnp.zeros((cr, bs), jnp.int32)
+        stall_ref[...] = jnp.zeros((1, 1, bs), jnp.float32)
 
     # scratch persists across grid steps — re-arm the controller state
     open_s[...] = jnp.full((nb_tot, bs), -1.0, jnp.float32)
@@ -209,6 +235,7 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
             gb = (ch * n_rk + rank) * n_banks + b
             cm = chan_iota == ch              # one-hot channel row
         else:
+            ch, rank = 0, 0
             gb = b
         bm = bank_iota == gb                  # one-hot bank rows
         rm = ring_iota == (k % mlp_window)    # one-hot ring slot
@@ -219,9 +246,25 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
         rdy_b = jnp.sum(jnp.where(bm, rdy_s[...], 0.0), axis=0)
         gate = jnp.sum(jnp.where(rm, ring_s[...], 0.0), axis=0)
         if multi:
-            # channel bus contention joins the issue gate
+            # channel bus contention joins the issue gate (with the
+            # rank state: the column, in service_math)
             cf_b = jnp.sum(jnp.where(cm, cf_s[...], 0.0), axis=0)
-            gate = jnp.maximum(gate, cf_b)
+            if not grouped:
+                gate = jnp.maximum(gate, cf_b)
+        if grouped:
+            # the request's rank and bank-group rows, and per lane the
+            # ring slot of the rank's fourth-last ACT
+            kr, grp = rank_index(b, ch, rank, n_rk, n_banks, n_g)
+            km = rank_iota == kr
+            gm = grp_iota == grp
+            ptr = jnp.sum(jnp.where(km, fptr_s[...], 0), axis=0)
+            fm = faw_iota == (kr * 4 + ptr)[None, :]
+            rank_in = (jnp.sum(jnp.where(km, ract_s[...], 0.0), axis=0),
+                       jnp.sum(jnp.where(gm, gact_s[...], 0.0), axis=0),
+                       jnp.sum(jnp.where(fm, faw_s[...], 0.0), axis=0),
+                       jnp.sum(jnp.where(km, rcol_s[...], 0.0), axis=0),
+                       jnp.sum(jnp.where(gm, gcol_s[...], 0.0), axis=0),
+                       cf_b if multi else NEVER)
         if regioned:
             # chained one-hot gather: (bank, region) slot -> unique
             # row index (per lane, via the map tile) -> timing lanes
@@ -258,9 +301,11 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
         # the per-request timing model itself is the SHARED elementwise
         # helper (repro.core.dram_sim.service_math) — only the one-hot
         # gather/scatter layout is kernel-specific
-        (row_latched, act_new, wrd_new, rdy_new, done, lat,
-         _) = service_math(t, gate, open_b, act_b, wrd_b, rdy_b, rf, w,
-                           tc[0], tc[1], tc[2], tc[3], tc[4], closed)
+        args = (t, gate, open_b, act_b, wrd_b, rdy_b, rf, w, tc[0],
+                tc[1], tc[2], tc[3], tc[4], closed)
+        res = (service_math(*args, (rank_in, rank_t)) if grouped
+               else service_math(*args))
+        row_latched, act_new, wrd_new, rdy_new, done, lat, is_hit = res[:7]
         if faulted:
             # detected-error retry: re-issue at the JEDEC row keeps
             # the bank busy through the retry (same arithmetic as
@@ -280,6 +325,20 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
             # bus busy for t_burst ns from the burst start (done - tCL)
             busy = done - tc[4] + t_burst
             cf_s[...] = jnp.where(cm & v, busy, cf_s[...])
+        if grouped:
+            # dram_sim._rank_update, tile for tile
+            col, stall = res[7:]
+            acted = v & ~is_hit
+            ract_s[...] = jnp.where(km & acted, act_new, ract_s[...])
+            gact_s[...] = jnp.where(gm & acted, act_new, gact_s[...])
+            faw_s[...] = jnp.where(fm & acted, act_new, faw_s[...])
+            fptr_s[...] = jnp.where(km & acted,
+                                    jnp.where(ptr == 3, 0, ptr + 1),
+                                    fptr_s[...])
+            rcol_s[...] = jnp.where(km & v, col, rcol_s[...])
+            gcol_s[...] = jnp.where(gm & v, col, gcol_s[...])
+            stall_ref[0, 0, :] = stall_ref[0, 0, :] + jnp.where(
+                v, stall, 0.0)
         if faulted:
             degraded = wd[4] > 0
             wd2, new_trip = faults.wd_update(flt, wd, det, False,
@@ -301,6 +360,10 @@ def _kernel(closed_ref, il_ref, arr_ref, bank_ref, row_ref, wr_ref,
     jax.lax.fori_loop(0, n_req, body, 0)
     total_ref[0, 0, :] = jnp.maximum(jnp.max(rdy_s[...], axis=0),
                                      jnp.max(wrd_s[...], axis=0))
+    if grouped:
+        # completions too: an open bank's ready is its last column
+        total_ref[0, 0, :] = jnp.maximum(total_ref[0, 0, :],
+                                         jnp.max(ring_s[...], axis=0))
 
 
 def _adaptive_kernel(closed_ref, arr_ref, bank_ref, row_ref, wr_ref,
@@ -727,7 +790,8 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
     G = flattened (trace x policy) cells.  Returns (latency [G, N, S],
     total runtime [G, S]); with `fault` = (fault tile [F_COLS, S],
     JEDEC column [6, 1], uniforms [G, N]) also the five [G, S] int32
-    fault counters (detected, silent, trips, degraded, probes).
+    fault counters (detected, silent, trips, degraded, probes); with
+    bank groups in `chan` also the [G, S] summed constraint stall.
 
     `region_map` (optional int32 [banks*regions, S] lane-tiled index
     map) switches `timings_t` to the mask-compressed PER-REGION
@@ -737,6 +801,10 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
     banked = timings_t.ndim == 3
     faulted = fault is not None
     regioned = region_map is not None
+    groups = split_chan(chan)[3]
+    if groups is not None and (faulted or regioned):
+        raise BankGroupError("replay_blocks: no fault or region axis "
+                             "with the rank and bank-group state")
     s = timings_t.shape[-1]
     nb_tot = chan[0] * chan[1] * n_banks
     assert timings_t.shape[-2] == 6 and s % bs == 0, (timings_t.shape, bs)
@@ -777,6 +845,14 @@ def replay_blocks(closed_col, ileave_col, arrival, bank, row, is_write,
         pltpu.VMEM((mlp_window, bs), jnp.float32),  # done_ring
         pltpu.VMEM((chan[0], bs), jnp.float32),   # chan bus-free
     ]
+    if groups is not None:
+        cr = chan[0] * chan[1]
+        out_specs.append(_lane_row(bs))              # constraint stall
+        out_shape.append(jax.ShapeDtypeStruct((g, 1, s), jnp.float32))
+        scratch += [pltpu.VMEM((cr, bs), jnp.float32)] * 2 + [
+            pltpu.VMEM((cr * groups[0], bs), jnp.float32)] * 2 + [
+            pltpu.VMEM((cr * 4, bs), jnp.float32),   # four-ACT ring
+            pltpu.VMEM((cr, bs), jnp.int32)]         # its slot pointer
     if faulted:
         flt_t, jed_col, u = fault
         in_specs += [

@@ -19,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import aldram, faults, thermal
 from repro.core.calibration import CALIBRATED_VARIATION
 from repro.core.charge import DEFAULT_CONSTANTS
-from repro.core.timing import (DDR3_1600, read_combo_grid,
+from repro.core.timing import (DDR3_1600, DDR4_2400_RANK,
+                               DDR4_2400_TCK_NS, read_combo_grid,
                                refresh_grid, write_combo_grid)
 from repro.kernels.charge_sim import charge_sim
 from repro.kernels.replay import replay
@@ -159,17 +160,25 @@ def _streams(sh, g, n):
 
 
 @pytest.mark.parametrize("variant", ["static", "per-bank", "4-channel",
-                                     "faulted", "regions"])
+                                     "faulted", "regions", "ddr4-groups"])
 def test_replay_blocks_compiles(one_chip, variant):
     """The static replay kernel at the smoke run's shapes: the Fig. 4
     campaign (per-module and per-bank rows; the fused thermal
     campaign's static bracket has the same shape), the traffic
-    campaign's 4 channels, a faulted and a region-compressed launch."""
+    campaign's 4 channels, a faulted and a region-compressed launch,
+    and the YCSB cell's dual-rank DDR4 channel with its rank and
+    bank-group state (128 streams x 2 policies x 16384 requests)."""
     sh = one_chip
     g = G_TRAFFIC if variant == "4-channel" else G_FIG4
+    n = N
     tim = _sds(sh, (6, 128))
     kw = {}
-    if variant == "per-bank":
+    if variant == "ddr4-groups":
+        g, n = 256, 16384
+        kw["n_banks"] = 16
+        kw["chan"] = (1, 2, 4 * DDR4_2400_TCK_NS,
+                      (4,) + DDR4_2400_RANK.as_tuple())
+    elif variant == "per-bank":
         tim = _sds(sh, (8, 6, 128))
     elif variant == "4-channel":
         kw["chan"] = (4, 1, 5.0)
@@ -180,7 +189,7 @@ def test_replay_blocks_compiles(one_chip, variant):
         tim = _sds(sh, (13, 6, 128))
         kw["region_map"] = _sds(sh, (64, 128), jnp.int32)
     c = _compile(replay.replay_blocks, _sds(sh, (g, 1)),
-                 _sds(sh, (g, 1), jnp.int32), *_streams(sh, g, N), tim,
+                 _sds(sh, (g, 1), jnp.int32), *_streams(sh, g, n), tim,
                  **kw)
     assert "tpu_custom_call" in c.as_text()
 

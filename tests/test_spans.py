@@ -201,6 +201,30 @@ def test_counts_of_the_replay_spans(tmp_path, controller, small_pop):
     assert got["sim.dispatch"]["n"] == 2
 
 
+def test_counts_of_the_kv_replay_spans(tmp_path, controller, small_pop):
+    """A YCSB campaign's `sim.prep` counts its ranks, bank groups and
+    operations."""
+    from repro.core.dram_sim import KVSpec
+    kv = KVSpec(n=96, n_streams=2, seed=3, n_records=1 << 12)
+
+    def call():
+        out = controller.evaluate_system(
+            small_pop, traffic=kv, std=T.DDR4_2400,
+            rank_timings=T.DDR4_2400_RANK, engine=SimEngine(),
+            policies=(OPEN_FCFS, Policy(page="closed")))
+        return out["result"].constraint_stall_ns
+
+    call()
+    _traced(tmp_path, call)
+    prep = spans.summary()["spans"]["sim.prep"]
+    assert prep["n"] == 1
+    assert (prep["streams"], prep["requests"]) == (2, 2 * 96)
+    assert (prep["ranks"], prep["bank_groups"]) == (2, 4)
+    # each operation is 16 or 18 lines: 96 lines hold 6 of them
+    assert prep["ops"] == kv.op_count() == 2 * 6
+    assert spans.summary()["spans"]["aldram.evaluate_system"]["rows"] == 3
+
+
 @pytest.mark.parametrize("kind", list(NAMES))
 def test_spans_in_the_written_trace(tmp_path, controller, small_pop, kind):
     from jax.profiler import ProfileData
