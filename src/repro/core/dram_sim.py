@@ -57,7 +57,9 @@ Scheduling-policy axis:
     starvation cap), approximating a first-ready FCFS scheduler.
     `frfcfs_perm` is the jitted JAX formulation of the same scheduler
     (a `lax.scan` over the pending window) that `sim_engine` runs as a
-    prepass INSIDE the campaign dispatch — parity-tested
+    prepass INSIDE the campaign dispatch (the kernel backends run its
+    lane-parallel Pallas form, `repro.kernels.replay.frfcfs`) —
+    parity-tested
     request-for-request against the Python reference, which is retained
     as the host path (and cached across `SimSpec.pack()` calls).
 """

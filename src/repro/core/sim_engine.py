@@ -28,7 +28,9 @@ device-resident:
     `dram_sim.frfcfs_perm` as a prepass INSIDE the dispatch (the jitted
     JAX formulation is parity-tested request-for-request against the
     retained Python loop, so this changes where the permutation is
-    computed, never what it is),
+    computed, never what it is); the kernel backends compute it with
+    the `frfcfs_blocks` Pallas kernel instead, a (trace, policy) pair
+    per lane, element for element the same permutation,
   * stats="device" — masked mean/p99 and the thermal diagnostics
     (temp_max / temp_mean / bin_switches) reduce on-device and only
     [grid]-shaped summaries cross the host boundary,
@@ -473,14 +475,18 @@ def _eff_window(arrival: np.ndarray, valid: np.ndarray, window: int,
 
 def _reorder_prepass(arrival, bank, row, is_write, valid, slacks, caps,
                      reorder_plan: tuple, n_banks: int,
-                     n_policies: int):
+                     n_policies: int, backend: str = "scan"):
     """In-dispatch FR-FCFS prepass: [T, N] FCFS streams -> [T, P, N]
     per-policy issue orders, all on device.  `reorder_plan` (static)
     groups the policy columns with a window >= 2 by window size as
     `(window, eff, idx)` entries — each group pays an O(N * eff)
     permutation scan sized to its EXACT slack-horizon buffer bound
     (`_eff_window`), not the nominal window; window-0 policies
-    broadcast the FCFS stream untouched."""
+    broadcast the FCFS stream untouched.  With `backend`
+    "pallas"/"pallas_interpret" one `frfcfs_blocks` kernel permutes
+    every (trace, policy) pair of the plan at once, a pair per lane
+    (`repro.kernels.replay.frfcfs`); the permutations are the scan's,
+    element for element."""
     t, n = arrival.shape
 
     def bcast(x):
@@ -492,23 +498,54 @@ def _reorder_prepass(arrival, bank, row, is_write, valid, slacks, caps,
 
     perm = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, None],
                             (t, n_policies, n))
-    for window, eff, idx in reorder_plan:
-        sel = np.asarray(idx, np.int32)
+    if backend in ("pallas", "pallas_interpret"):
+        from repro.kernels.replay import frfcfs
+        sel = np.asarray([i for _, _, idx in reorder_plan for i in idx],
+                         np.int32)
+        windows = tuple(w for w, _, idx in reorder_plan for _ in idx)
+        buf = max(min(e, n) for _, e, _ in reorder_plan)
+        perm = perm.at[:, sel, :].set(frfcfs.reorder_perm(
+            arrival, bank, row, valid, windows, slacks[sel], caps[sel],
+            buf, n_banks, interpret=backend == "pallas_interpret"))
+    else:
+        for window, eff, idx in reorder_plan:
+            sel = np.asarray(idx, np.int32)
 
-        def one(a, b, r, v, s_, c_, w=window, e=eff):
-            return frfcfs_perm(a, b, r, v, w, s_, c_, min(e, n),
-                               n_banks)
+            def one(a, b, r, v, s_, c_, w=window, e=eff):
+                return frfcfs_perm(a, b, r, v, w, s_, c_, min(e, n),
+                                   n_banks)
 
-        f_p = jax.vmap(one, in_axes=(None, None, None, None, 0, 0))
-        f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, None, None))
-        perm = perm.at[:, sel, :].set(
-            f_tp(arrival, bank, row, valid, slacks[sel], caps[sel]))
+            f_p = jax.vmap(one, in_axes=(None, None, None, None, 0, 0))
+            f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, None, None))
+            perm = perm.at[:, sel, :].set(
+                f_tp(arrival, bank, row, valid, slacks[sel], caps[sel]))
 
-    def gather(x):
-        return jnp.take_along_axis(bcast(x), perm, axis=2)
+    # one gather of the four fields as int32 columns: a TPU gather
+    # costs per index, so on a v5e the [T, P, N, 4] row gather takes a
+    # seventh of the time of four scalar gathers (bit-identical: the
+    # arrival travels as its float32 bits)
+    cols = jnp.stack([
+        jax.lax.bitcast_convert_type(arrival.astype(jnp.float32),
+                                     jnp.int32),
+        bank.astype(jnp.int32), row.astype(jnp.int32),
+        is_write.astype(jnp.int32)], axis=-1)
+    g = jnp.take_along_axis(
+        jnp.broadcast_to(cols[:, None], (t, n_policies, n, 4)),
+        perm[..., None], axis=2, mode="promise_in_bounds")
+    return (jax.lax.bitcast_convert_type(g[..., 0], jnp.float32),
+            g[..., 1].astype(bank.dtype), g[..., 2].astype(row.dtype),
+            g[..., 3].astype(is_write.dtype))
 
-    return (gather(arrival), gather(bank), gather(row),
-            gather(is_write))
+
+def _prepass_lanes(plan: tuple, backend: str, n_traces: int) -> dict:
+    """The `sim.dispatch` counts of one launch: (trace, policy) pairs
+    permuted by the `frfcfs_blocks` kernel (`prepass_lanes`) and by a
+    `lax.scan` (`prepass_scan_lanes`: `frfcfs_perm`, or the merged
+    core's scheduler)."""
+    pairs = n_traces * sum(len(idx) for _, _, idx in plan)
+    kernel = backend in ("pallas", "pallas_interpret")
+    return {"prepass_lanes": pairs if kernel else 0,
+            "prepass_scan_lanes": 0 if kernel else pairs}
 
 
 def _merged_replay(arrival, bank, row, is_write, valid, timings, closed,
@@ -750,7 +787,7 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
         if arrival.ndim == 2:
             a3, b3, r3, w3 = _reorder_prepass(
                 arrival, bank, row, is_write, valid, slacks, caps,
-                reorder_plan, n_banks, closed.shape[0])
+                reorder_plan, n_banks, closed.shape[0], backend)
         else:
             a3, b3, r3, w3 = arrival, bank, row, is_write
 
@@ -847,7 +884,7 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
     if arrival.ndim == 2:
         a3, b3, r3, w3 = _reorder_prepass(
             arrival, bank, row, is_write, valid, slacks, caps,
-            reorder_plan, n_banks, closed.shape[0])
+            reorder_plan, n_banks, closed.shape[0], backend)
     else:
         a3, b3, r3, w3 = arrival, bank, row, is_write
 
@@ -1473,7 +1510,8 @@ class SimEngine:
             want = (("stats",) + (("lat",)
                                   if "latencies" in spec.collect else ())
                     if self.stats == "device" else ("lat",))
-            with span("sim.dispatch"):
+            with span("sim.dispatch",
+                      **_prepass_lanes(plan, backend, valid.shape[0])):
                 rm = (None if spec.region_map is None
                       else jnp.asarray(spec.region_map))
                 out = self._dispatch(
@@ -1525,7 +1563,8 @@ class SimEngine:
             want += ("bins",) if "bins" in spec.collect else ()
         else:
             want = ("lat", "temps", "bins")
-        with span("sim.dispatch"):
+        with span("sim.dispatch",
+                  **_prepass_lanes(plan, backend, valid.shape[0])):
             out = self._dispatch(
                 "adaptive", spec, synth, plan, backend, want,
                 _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
@@ -1612,7 +1651,8 @@ class SimEngine:
         scns, bins, tcfg = spec.thermal.pack()
         n_real = len(scns) if n_real is None else int(n_real)
         self.dispatch_count += 1
-        with span("sim.dispatch"):
+        with span("sim.dispatch",
+                  **_prepass_lanes(plan, backend, valid.shape[0])):
             out = self._dispatch(
                 "bracket", spec, synth, plan, backend, ("stats",),
                 _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),

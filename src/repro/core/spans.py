@@ -34,7 +34,11 @@ in them:
                  requests: the packed [streams, n] slots; with rank
                  timings ranks, bank_groups; for a `KVSpec` ops: the
                  YCSB operations with a line among the streams']
-  sim.dispatch   the launch of a synthesis or replay program
+  sim.dispatch   the launch of a synthesis or replay program [of a
+                 replay, prepass_lanes: the (trace, policy) pairs of
+                 its FR-FCFS plan that the `frfcfs_blocks` kernel
+                 permutes, prepass_scan_lanes: those a `lax.scan`
+                 permutes]
   sim.fetch      the replay's results turned into numpy arrays
   margin.fetch   the wait for one margin dispatch and the copy of its
                  pass envelopes to the host (of its dense grids, for

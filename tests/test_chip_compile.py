@@ -249,3 +249,34 @@ def test_largest_request_count_compiles(one_chip, kernel):
     with pytest.raises(ValueError, match="requests per stream"):
         launch(n + 8)
     assert n >= N
+
+
+@pytest.mark.parametrize("case", ["fig4", "four-chip-shard", "largest"])
+def test_frfcfs_blocks_compiles(one_chip, case):
+    """The FR-FCFS prepass kernel at the Fig. 4 campaign's plan (70
+    streams x windows 16 and 64, a 38-slot exact buffer: two lane
+    blocks), at one shard of the four-chip traffic campaign (4 streams
+    x 3 policies of window 16), and at the largest N
+    `frfcfs.max_requests` states (one more request raises before
+    lowering)."""
+    from repro.kernels.replay import frfcfs
+    sh = one_chip
+    t, n, windows, buf = {
+        "fig4": (G_FIG4, N, (16, 64), 38),
+        "four-chip-shard": (4, N, (16, 16, 16), 16),
+        "largest": (2, frfcfs.max_requests(40), (16, 64), 40)}[case]
+    q = len(windows)
+
+    def launch(n):
+        f = jax.jit(lambda a, b, r, v, s, c: frfcfs.reorder_perm(
+            a, b, r, v, windows, s, c, buf))
+        return _compile(f, _sds(sh, (t, n)), _sds(sh, (t, n), jnp.int32),
+                        _sds(sh, (t, n), jnp.int32),
+                        _sds(sh, (t, n), jnp.bool_), _sds(sh, (q,)),
+                        _sds(sh, (q,), jnp.int32))
+
+    assert "tpu_custom_call" in launch(n).as_text()
+    if case == "largest":
+        assert n >= N
+        with pytest.raises(ValueError, match="requests per stream"):
+            launch(n + 8)

@@ -201,6 +201,28 @@ def test_counts_of_the_replay_spans(tmp_path, controller, small_pop):
     assert got["sim.dispatch"]["n"] == 2
 
 
+@pytest.mark.parametrize("backend", ["scan", "pallas_interpret"])
+def test_prepass_counts_of_the_dispatch_span(tmp_path, controller,
+                                             small_pop, backend):
+    """`sim.dispatch` counts the (trace, policy) pairs of the replay's
+    FR-FCFS plan, under the kernel or the scan that permutes them: the
+    70-stream pool under one reordering policy of two."""
+    pols = (OPEN_FCFS, Policy(reorder_window=8))
+
+    def call():
+        controller.evaluate_system(small_pop, n=32, seed=3,
+                                   engine=SimEngine(backend=backend),
+                                   policies=pols)
+
+    call()
+    _traced(tmp_path, call)
+    got = spans.summary()["spans"]["sim.dispatch"]
+    pairs = 2 * len(perf_model.WORKLOADS)
+    kernel = backend == "pallas_interpret"
+    assert got["prepass_lanes"] == (pairs if kernel else 0)
+    assert got["prepass_scan_lanes"] == (0 if kernel else pairs)
+
+
 def test_counts_of_the_kv_replay_spans(tmp_path, controller, small_pop):
     """A YCSB campaign's `sim.prep` counts its ranks, bank groups and
     operations."""
