@@ -21,10 +21,8 @@ pipelines and reports the end-to-end wall-clock ratios:
     synthesis + FR-FCFS + replay + statistics are truly ONE dispatch
     (`dispatches=1` total, `synth_dispatch_count` never moves); the
     FR-FCFS pending buffer shrinks to its EXACT slack-horizon bound,
-    and the replay core (scan / scheduler-fused merged scan / Pallas
-    kernel, Pallas lane-block size, fusion on/off) is AUTOTUNED per
-    backend and campaign size by `SimEngine.autotune` during the
-    untimed warm-up.
+    and `backend="auto"` picks the replay core from the platform (the
+    Pallas kernel on the TPU, the scan elsewhere).
 
 All pipelines replay the same multiset of requests (threefry makes
 the in-dispatch synthesis bit-identical to the materialized batch),
@@ -47,10 +45,7 @@ from benchmarks.common import emit
 
 
 def run(fast: bool = False) -> dict:
-    import jax
-
     from repro.core import dram_sim, perf_model
-    from repro.core.autotune import ReplayTuner
     from repro.core.dram_sim import Policy, SynthSpec, Trace
     from repro.core.sim_engine import SimEngine, SimSpec
     from repro.core.timing import DDR3_1600, stack_timing
@@ -76,18 +71,11 @@ def run(fast: bool = False) -> dict:
 
     fast_eng = SimEngine()                                 # device/device
     ref_eng = SimEngine(stats="host", reorder="host")      # the old path
-    # path="" keeps the bench hermetic (no cross-run disk cache)
-    fused_eng = SimEngine(backend="auto",
-                          tuner=ReplayTuner(
-                              platform=jax.default_backend(), path=""))
+    fused_eng = SimEngine(backend="auto")
 
-    fast_eng.run(spec)                       # untimed compile warm-up
-    # untimed autotune: profiles every candidate replay config on this
-    # campaign (compiling each), records the winner for backend="auto"
-    tuned = fused_eng.autotune(spec_fused, reps=max(2, reps - 1))
-    tuned_tag = "{}+bs{}+fuse{}".format(
-        tuned.backend, tuned.block_rows or "auto",
-        int(tuned.fuse_synth))
+    fast_eng.run(spec)                       # untimed compile warm-ups
+    spec_fused.trace_tuple()                 # exact FR-FCFS buffer caps
+    fused_eng.run(spec_fused)
     dram_sim._REORDER_CACHE.clear()
     res_ref = ref_eng.run(spec)
 
@@ -145,10 +133,10 @@ def run(fast: bool = False) -> dict:
     emit("sim_fastpath_campaign", med_fused * 1e6,
          "speedup={:.1f}x|speedup_fused={:.1f}x|vs_fast={:.2f}x|"
          "ref={:.2f}s|fast={:.2f}s|fused={:.2f}s|grid=35x2x{}|n={}|"
-         "stats_rel={:.1e}|tuned={}|dispatches={}".format(
+         "stats_rel={:.1e}|dispatches={}".format(
              speedup, speedup_fused, med_fast / med_fused, med_ref,
              med_fast, med_fused, n_rows, n, max(rel, rel_fused),
-             tuned_tag, dispatches_per_run))
+             dispatches_per_run))
     return {
         "speedup": speedup, "ref_s": med_ref, "fast_s": med_fast,
         "fused_s": med_fused, "speedup_fused": speedup_fused,
@@ -158,7 +146,6 @@ def run(fast: bool = False) -> dict:
         "stats_rel_err": rel, "stats_rel_err_fused": rel_fused,
         "n": n, "grid": f"35x2x{n_rows}",
         "windows": [p.reorder_window for p in policies],
-        "tuned": tuned_tag,
         "dispatches": {"replay_per_run": dispatches_per_run,
                        "synth": 1,
                        "fused_total_per_run": fused_replays // reps,

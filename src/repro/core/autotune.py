@@ -8,28 +8,17 @@ This is the TPU-framework transfer of the paper's idea (DESIGN.md §3):
   guardband  ~ one sweep step     -> quantile + k*sigma margin
 
 Used by runtime/straggler.py (adaptive collective timeouts),
-data/pipeline.py (adaptive prefetch depth) and the kernel block-size
-tables.  The worst-case STATIC value plays the role of the JEDEC
-timing: `select` never returns something less safe than the profiled
+runtime/fault.py (heartbeat miss budgets) and data/pipeline.py
+(adaptive prefetch depth); `from_sweep` builds one from a profile.
+The worst-case STATIC value plays the role of the JEDEC timing:
+`select` never returns something less safe than the profiled
 guardbanded envelope, and unprofiled bins fall back to the static
 worst case — the same conservative semantics as the paper's controller.
-
-`ReplayTuner` turns the same table inward, onto the simulator itself:
-the replay-dispatch configuration (`ReplayConfig`: backend core,
-Pallas lane-block size, synthesis fusion) is the adaptive parameter,
-the campaign's (kind, log2-size) bin is the condition, and the
-conservative lax.scan default is the static worst case every
-unprofiled bin falls back to.  `SimEngine.autotune` profiles the
-candidates and records winners here; `SimEngine(backend="auto")`
-consults the table at run time.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
-import math
-import os
 
 import numpy as np
 
@@ -124,161 +113,3 @@ class AdaptiveTable:
         v = self.select(unit, condition)
         wc = self.static_worst_case
         return (wc - v) / wc if self.higher_is_safer else (v - wc) / wc
-
-
-# --------------------------------------------------------------------
-# Replay-dispatch autotuning (SimEngine backend/tile selection)
-# --------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ReplayConfig:
-    """One replay-dispatch configuration the tuner scores: which
-    replay core (`SimEngine.backend` value, "auto" excluded), the
-    Pallas lane-block size (None = kernel default BLOCK_ROWS) and
-    whether a `SynthSpec` trace axis synthesizes inside the dispatch."""
-
-    backend: str = "scan"
-    block_rows: int | None = None
-    fuse_synth: bool = True
-
-
-def replay_unit(adaptive: bool, banked: bool,
-                channels: bool = False, regioned: bool = False) -> int:
-    """Campaign-kind unit of the tuner table: the replay shapes
-    (static/adaptive x per-module/per-bank x single/multi-channel x
-    dense/region-compressed) tune independently.  Units 0-3 are the
-    historical single-channel kinds (stored tables stay valid); a
-    multi-channel campaign (`SimSpec.n_channels * n_ranks > 1` —
-    different state footprint and gather pattern) offsets by 4; a
-    region-compressed campaign (`SimSpec.region_map` — the extra
-    in-scan index-map gather changes the dispatch cost profile)
-    offsets by 8."""
-    return ((8 if regioned else 0) + (4 if channels else 0)
-            + (2 if adaptive else 0) + (1 if banked else 0))
-
-
-# log2(request count) bin edges: campaigns within a bin share a tuned
-# config (dispatch cost is dominated by N; the grid axes just vmap)
-REPLAY_SIZE_BINS = (10.0, 12.0, 14.0, 17.0, 24.0)
-
-# candidate 0 is ALWAYS the conservative scan default — it is the
-# static worst case unprofiled bins fall back to
-# (a 64-lane block does not lower on the TPU once the padded lane axis
-# exceeds one block, so the kernel candidates start at 128 lanes)
-_CANDIDATES = {
-    "tpu": (ReplayConfig("scan"),
-            ReplayConfig("pallas", 128),
-            ReplayConfig("pallas", 256),
-            ReplayConfig("merged"),
-            ReplayConfig("merged", fuse_synth=False)),
-    # interpret-mode Pallas is a pure-Python step loop — never a
-    # performance candidate off-TPU
-    "cpu": (ReplayConfig("scan"),
-            ReplayConfig("scan", fuse_synth=False),
-            ReplayConfig("merged"),
-            ReplayConfig("merged", fuse_synth=False)),
-}
-
-
-@dataclasses.dataclass
-class ReplayTuner:
-    """Profiled (backend, block_rows, fuse_synth) selection per
-    (campaign kind, size bin), with `AdaptiveTable` fallback
-    semantics: `lookup` on an unprofiled bin answers candidate 0 (the
-    scan default), exactly like the timing controller answering JEDEC
-    above its hottest profiled bin.
-
-    The table persists as JSON — `path` wins, else the
-    REPRO_AUTOTUNE_PATH env var, else
-    ~/.cache/repro/replay_tune_<platform>.json; path="" disables the
-    disk cache.  Stored entries whose candidate list no longer matches
-    (different platform/candidate set) are dropped on load."""
-
-    platform: str = "cpu"
-    path: str | None = None
-    candidates: tuple[ReplayConfig, ...] = ()
-
-    def __post_init__(self):
-        if not self.candidates:
-            if self.platform not in _CANDIDATES:
-                raise ValueError(
-                    f"ReplayTuner has no candidate list for platform "
-                    f"{self.platform!r} (known: {sorted(_CANDIDATES)}); "
-                    f"pass `candidates` explicitly")
-            self.candidates = _CANDIDATES[self.platform]
-        self.table = AdaptiveTable(condition_bins=REPLAY_SIZE_BINS,
-                                   static_worst_case=0.0,
-                                   higher_is_safer=False)
-        self.timings: dict[tuple[int, int], list[float]] = {}
-        self._load()
-
-    # -------------------------------------------------------- persist
-    def _resolve_path(self) -> str | None:
-        if self.path == "":
-            return None
-        if self.path:
-            return self.path
-        env = os.environ.get("REPRO_AUTOTUNE_PATH")
-        if env:
-            return env
-        return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            f"replay_tune_{self.platform}.json")
-
-    def _load(self):
-        p = self._resolve_path()
-        if not p or not os.path.exists(p):
-            return
-        try:
-            with open(p) as f:
-                data = json.load(f)
-        except (OSError, ValueError):
-            return
-        if data.get("candidates") != [dataclasses.asdict(c)
-                                      for c in self.candidates]:
-            return
-        for key, idx in data.get("table", {}).items():
-            u, b = (int(x) for x in key.split(","))
-            if 0 <= int(idx) < len(self.candidates):
-                self.table._table[(u, b)] = float(idx)
-
-    def _save(self):
-        p = self._resolve_path()
-        if not p:
-            return
-        os.makedirs(os.path.dirname(p), exist_ok=True)
-        data = {
-            "platform": self.platform,
-            "candidates": [dataclasses.asdict(c)
-                           for c in self.candidates],
-            "table": {f"{u},{b}": int(v) for (u, b), v
-                      in self.table._table.items()},
-        }
-        with open(p, "w") as f:
-            json.dump(data, f, indent=1, sort_keys=True)
-
-    # --------------------------------------------------------- select
-    def _condition(self, n: int) -> float:
-        return math.log2(max(int(n), 1))
-
-    def lookup(self, unit: int, n: int) -> ReplayConfig:
-        """The profiled config for a campaign of `n` requests —
-        candidate 0 (scan default) when the bin is unprofiled."""
-        idx = int(self.table.select(unit, self._condition(n)))
-        return self.candidates[idx]
-
-    def tune(self, unit: int, n: int, measure
-             ) -> tuple[ReplayConfig, list[float]]:
-        """Score every candidate with `measure(config) -> seconds`
-        (supplied by the engine — the tuner never imports it), record
-        the winner's index in the table, persist, and return
-        (winning config, per-candidate times)."""
-        times = [float(measure(cfg)) for cfg in self.candidates]
-        best = int(np.argmin(times))
-        b = self.table._bin(self._condition(n))
-        if b < len(self.table.condition_bins):
-            # beyond the last bin `select` always answers candidate 0,
-            # so (like AdaptiveTable.observe) there is nothing to store
-            self.table._table[(unit, b)] = float(best)
-            self.timings[(unit, b)] = times
-            self._save()
-        return self.candidates[best], times

@@ -849,9 +849,8 @@ def region_of(row, regions: int):
     position stripes the row's within-subarray offset falls in.  Exact
     contiguous nesting across resolution levels (l | R implies
     `region_of(r, l) == region_of(r, R) // (R // l)`), so one R-region
-    table answers every coarser level by integer division.  `row` may
-    be int or float32 (exact below 2**24 — the packed-stream form of
-    the merged scheduler core); `regions` is static."""
+    table answers every coarser level by integer division.  `regions`
+    is static."""
     r_i = row.astype(jnp.int32) if row.dtype != jnp.int32 else row
     return (r_i % SUBARRAY_ROWS) * regions // SUBARRAY_ROWS
 
@@ -1471,224 +1470,6 @@ def replay_rows(arrival, bank, row, is_write, valid, timings, closed,
         return (lat.T, jnp.maximum(total, st_end[1].max(0)),
                 rs_end.stall)            # + [S]
     return lat.T, total                  # [S, N], [S]
-
-
-def replay_rows_frfcfs(arrival, bank, row, is_write, valid, timings,
-                       closed, window, slack_ns, cap, max_window: int,
-                       n_banks: int = 8, mlp_window: int = 8,
-                       all_valid: bool = False, n_channels: int = 1,
-                       n_ranks: int = 1, ileave=None,
-                       t_burst: float = 5.0, fault=None,
-                       region_map=None):
-    """MERGED FR-FCFS-lite + replay: one `lax.scan` that both picks the
-    next request to issue (the `frfcfs_perm` pending-buffer scheduler)
-    and services it against the `replay_rows` lane-major bank state —
-    replacing the two-scan prepass (permute, gather, replay) with a
-    single pass over the stream.  Halves the sequential step count of
-    a reordered campaign and skips the [T, P, N] gather entirely;
-    bit-identical to `replay_rows(frfcfs_perm-permuted stream)` by
-    construction: the scheduler carry mirrors `frfcfs_perm` operation
-    for operation (same eligibility mask, same promotion/starvation
-    arithmetic, same buffer shift) and the service arithmetic is the
-    shared `service_math`.
-
-    `window`/`slack_ns`/`cap`/`closed` are traced scalars (per-policy
-    campaign columns — `window <= 1` degenerates to in-order FCFS so
-    every policy rides one vmapped dispatch); `max_window` is the
-    static pending-buffer size (>= every policy's window; the engine
-    shrinks it to the exact slack-horizon bound, see
-    `sim_engine._eff_window`).  `all_valid=True` (static) asserts the
-    stream has no padding and swaps the mod-indexed MLP ring for a
-    pure roll — cheaper on sublane hardware and exact because the
-    issue counter then advances every step.
-
-    With `n_channels`/`n_ranks` > 1 the SERVICE half carries the
-    [C*R*B, 4, S] channelized bank state and the [C, S] bus-free gate
-    of `replay_rows` (same `chan_rank(ileave)` in-scan mapping); the
-    SCHEDULER half stays channel-agnostic (its open-row prediction is
-    keyed on the rank-level bank id, exactly like `frfcfs_perm`), so
-    the merged core remains bit-identical to prepass + channelized
-    `replay_rows`.
-
-    Returns (latency [S, N] in ISSUE order — the same positional
-    order the prepass pipeline emits — and total runtime [S]).
-    Padding must be a suffix of `valid` (`check_prefix_valid`).
-
-    `fault` (optional, STATIC branch) matches `replay_rows`:
-    `(fault_rows [S, faults.F_COLS], jedec_row [6], u [N])` with the
-    uniform stream consumed positionally by ISSUE step — exactly the
-    order the prepass pipeline consumes it, so the merged core stays
-    bit-identical to prepass + faulted `replay_rows`.  Returns then
-    gain [faults.N_COUNTERS, S] int32 counters.
-
-    `region_map` (optional int32 [G] or [S, G]) matches `replay_rows`:
-    `timings` is then the [S, U, 6] unique-row stack and the SERVICE
-    half gathers each request's region row through the map in-scan
-    (the scheduler half stays address-keyed and is untouched, so
-    merged stays bit-identical to prepass + regioned replay)."""
-    n = arrival.shape[0]
-    w = max_window
-    assert 1 <= w <= n, (w, n)
-    banked = timings.ndim == 3
-    multi = n_channels * n_ranks > 1
-    faulted = fault is not None
-    regioned = region_map is not None
-    if regioned:
-        assert banked, "region_map requires [S, U, 6] unique stores"
-        n_regions = region_map.shape[-1] // n_banks
-        assert region_map.shape[-1] == n_banks * n_regions
-        per_lane_map = region_map.ndim == 2
-        if per_lane_map:
-            assert region_map.shape[0] == timings.shape[0], \
-                (region_map.shape, timings.shape)
-            lane_i = jnp.arange(timings.shape[0])
-    if faulted:
-        f_rows, j_row, u_arr = fault
-        fpT = f_rows.T                  # [F_COLS, S] lane columns
-        j6 = (j_row[0], j_row[1], j_row[2], j_row[3], j_row[5])
-        jsum = j_row[0] + j_row[1] + j_row[2] + j_row[3]
-    il = (jnp.asarray(0 if ileave is None else ileave, jnp.int32)
-          if multi else None)
-    if not banked:
-        trcd, tras, twr, trp, tcl = (timings[:, 0], timings[:, 1],
-                                     timings[:, 2], timings[:, 3],
-                                     timings[:, 5])
-    s_rows = timings.shape[0]
-    slots = jnp.arange(w, dtype=jnp.int32)
-    slack = jnp.asarray(slack_ns, jnp.float32)
-    # request stream packed [5, N+1]: arrival/bank/row/is_write/valid
-    # as float32 (exact for bank/row ids below 2**24) plus a sentinel
-    # column refilled once the stream runs dry — its row (-2) can
-    # never match an open-row prediction (-1 = precharged, >= 0 real),
-    # and its validity 0 keeps it out of every eligibility mask, so it
-    # drains in order exactly like `frfcfs_perm`'s padded tail.
-    stream = jnp.concatenate([
-        jnp.stack([arrival.astype(jnp.float32),
-                   bank.astype(jnp.float32), row.astype(jnp.float32),
-                   is_write.astype(jnp.float32),
-                   valid.astype(jnp.float32)]),
-        jnp.array([[0.0], [0.0], [-2.0], [0.0], [0.0]], jnp.float32),
-    ], axis=1)
-
-    nb_tot = n_channels * n_ranks * n_banks
-    bs0 = jnp.concatenate([jnp.full((nb_tot, 1, s_rows), -1.0),
-                           jnp.zeros((nb_tot, 3, s_rows))], axis=1)
-    state0 = (stream[:, :w],                        # pending buffer
-              jnp.full((n_banks,), -1.0, jnp.float32),  # open-row pred
-              jnp.zeros((), jnp.int32),             # defer counter
-              jnp.asarray(w, jnp.int32),            # next refill
-              bs0, jnp.zeros((mlp_window, s_rows)),
-              jnp.zeros((n_channels, s_rows)),      # chan bus free
-              jnp.zeros((), jnp.int32))
-
-    def step(st, u_k):
-        if faulted:
-            st, wd, cnt = st
-        buf, open_pred, defer, nxt, bs, ring, cf, idx = st
-        # --- scheduler: pick the issue slot (mirrors frfcfs_perm) ---
-        b_int = buf[1].astype(jnp.int32)
-        hit = open_pred[b_int] == buf[2]
-        horizon = buf[0, 0] + slack
-        elig = (hit & (buf[0] <= horizon) & (buf[4] > 0)
-                & (slots < window))
-        promo = elig.any() & (defer < cap)
-        pick = jnp.where(promo, jnp.argmax(elig), 0).astype(jnp.int32)
-        req = buf[:, pick]
-        t, rf, v = req[0], req[2], req[4] > 0
-        b = req[1].astype(jnp.int32)
-        wr = req[3] > 0
-        open_pred = open_pred.at[b].set(rf)
-        defer = jnp.where(pick > 0, defer + 1, 0)
-        refill = stream[:, jnp.minimum(nxt, n)]
-        shifted = jnp.concatenate([buf[:, 1:], refill[:, None]], axis=1)
-        buf2 = jnp.where(slots[None, :] >= pick, shifted, buf)
-        # --- service: replay_rows' lane-major bank state ---
-        if multi:
-            row_i = rf.astype(jnp.int32)
-            ch, rk = chan_rank(b, row_i, il, n_channels, n_ranks,
-                               n_banks)
-            gb = (ch * n_ranks + rk) * n_banks + b
-        else:
-            gb = b
-        rowb = bs[gb]                          # [4, S]
-        if all_valid:
-            gate0 = ring[0]
-        else:
-            gate0 = ring[idx % mlp_window]     # [S]
-        gate = jnp.maximum(gate0, cf[ch]) if multi else gate0
-        if regioned:
-            g_id = b * n_regions + region_of(rf, n_regions)
-            if per_lane_map:
-                tb = timings[lane_i, region_map[:, g_id]]
-            else:
-                tb = timings[:, region_map[g_id], :]
-            tc_ = (tb[:, 0], tb[:, 1], tb[:, 2], tb[:, 3], tb[:, 5])
-        elif banked:
-            tb = timings[:, b, :]              # [S, 6]
-            tc_ = (tb[:, 0], tb[:, 1], tb[:, 2], tb[:, 3], tb[:, 5])
-        else:
-            tc_ = (trcd, tras, twr, trp, tcl)
-        if faulted:
-            is_probe, use_agg = faults.wd_gate(fpT, wd)
-            tc_ = tuple(jnp.where(use_agg, a, jb)
-                        for a, jb in zip(tc_, j6))
-            red = jnp.maximum(
-                1.0 - (tc_[0] + tc_[1] + tc_[2] + tc_[3]) / jsum, 0.0)
-            p_e = faults.error_prob(fpT, red, 0.0)
-            _, det, sil = faults.error_draw(fpT, u_k, p_e)
-            sur = jnp.where(det, j6[4] + fpT[faults.RETRY_NS], 0.0)
-        (latched, act_new, wrd_new, rdy_new, done, lat,
-         _) = service_math(t, gate, rowb[0], rowb[1], rowb[2], rowb[3],
-                           rf, wr, tc_[0], tc_[1], tc_[2], tc_[3],
-                           tc_[4], closed)
-        if faulted:
-            done = done + sur
-            lat = lat + sur
-            wrd_new = jnp.where(wr, wrd_new + sur, wrd_new)
-            rdy_new = rdy_new + sur
-        new_row = jnp.stack([jnp.broadcast_to(latched, (s_rows,)),
-                             act_new, wrd_new, rdy_new])
-        if all_valid:
-            bs2 = bs.at[gb].set(new_row)
-            ring2 = jnp.concatenate([ring[1:], done[None]])
-            idx2 = idx + 1
-            lat_out = lat
-            cf2 = (cf.at[ch].set(done - tc_[4] + t_burst) if multi
-                   else cf)
-        else:
-            bs2 = bs.at[gb].set(jnp.where(v, new_row, rowb))
-            ring2 = ring.at[idx % mlp_window].set(
-                jnp.where(v, done, gate0))
-            idx2 = idx + v.astype(jnp.int32)
-            lat_out = jnp.where(v, lat, 0.0)
-            cf2 = (cf.at[ch].set(jnp.where(v, done - tc_[4] + t_burst,
-                                           cf[ch])) if multi else cf)
-        st2 = (buf2, open_pred, defer, nxt + 1, bs2, ring2, cf2, idx2)
-        if faulted:
-            degraded = wd[4] > 0
-            wd2, new_trip = faults.wd_update(fpT, wd, det, False,
-                                            is_probe)
-            if not all_valid:
-                wd2 = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(v, new, old), wd2, wd)
-            cnt2 = faults.counter_update(cnt, v, det, sil, new_trip,
-                                         degraded, is_probe)
-            return (st2, wd2, cnt2), lat_out
-        return st2, lat_out
-
-    if faulted:
-        state0 = (state0, faults.wd_state0((s_rows,)),
-                  tuple(jnp.zeros((s_rows,), jnp.int32)
-                        for _ in range(faults.N_COUNTERS)))
-        st_end, lat = jax.lax.scan(step, state0, u_arr, length=n)
-        st_end, _, cnt_end = st_end
-        bse = st_end[4]
-        total = jnp.maximum(bse[:, 3].max(0), bse[:, 2].max(0))
-        return lat.T, total, jnp.stack(cnt_end)
-    (_, _, _, _, bse, _, _, _), lat = jax.lax.scan(
-        step, state0, None, length=n)
-    total = jnp.maximum(bse[:, 3].max(0), bse[:, 2].max(0))
-    return lat.T, total                        # [S, N], [S]
 
 
 class AdaptiveState(NamedTuple):

@@ -199,8 +199,8 @@ def fault_uniforms(key, n_traces: int, n: int) -> jnp.ndarray:
     """[T, N] threefry error uniforms, one stream per trace row, folded
     per row exactly like `SynthSpec` — generated INSIDE the campaign
     dispatch (call under jit), positional by ISSUE order and shared
-    across timing/fault lanes (common random numbers), so scan, merged
-    and Pallas backends consume the identical bits."""
+    across timing/fault lanes (common random numbers), so the scan and
+    Pallas backends consume the identical bits."""
     def one(i):
         return jax.random.uniform(jax.random.fold_in(key, i), (n,),
                                   jnp.float32)

@@ -71,13 +71,11 @@ import numpy as np
 
 from repro.core import faults
 from repro.core import timing as T
-from repro.core.autotune import ReplayConfig, ReplayTuner, replay_unit
 from repro.core.dram_sim import (OPEN_FCFS, SYNTH_SPECS, BankGroupError,
                                  KVSpec, Policy, SynthSpec, TenantSpec,
                                  Trace, check_prefix_valid, frfcfs_perm,
                                  frfcfs_reorder, replay_adaptive,
-                                 replay_rows, replay_rows_frfcfs,
-                                 split_chan)
+                                 replay_rows, split_chan)
 from repro.core.spans import span
 from repro.core.thermal import ThermalSpec
 
@@ -539,100 +537,12 @@ def _reorder_prepass(arrival, bank, row, is_write, valid, slacks, caps,
 
 def _prepass_lanes(plan: tuple, backend: str, n_traces: int) -> dict:
     """The `sim.dispatch` counts of one launch: (trace, policy) pairs
-    permuted by the `frfcfs_blocks` kernel (`prepass_lanes`) and by a
-    `lax.scan` (`prepass_scan_lanes`: `frfcfs_perm`, or the merged
-    core's scheduler)."""
+    permuted by the `frfcfs_blocks` kernel (`prepass_lanes`) and by the
+    `frfcfs_perm` `lax.scan` (`prepass_scan_lanes`)."""
     pairs = n_traces * sum(len(idx) for _, _, idx in plan)
     kernel = backend in ("pallas", "pallas_interpret")
     return {"prepass_lanes": pairs if kernel else 0,
             "prepass_scan_lanes": 0 if kernel else pairs}
-
-
-def _merged_replay(arrival, bank, row, is_write, valid, timings, closed,
-                   slacks, caps, reorder_plan: tuple, n_banks: int,
-                   mlp_window: int, all_valid: bool,
-                   chan: tuple = (1, 1, 5.0), ileave=None, fault=None,
-                   region_map=None):
-    """The `backend="merged"` replay core: [T, N] FCFS streams ->
-    (lat [T, P, S, N], total [T, P, S]) with the FR-FCFS schedule
-    FUSED into the replay scan itself (`dram_sim.replay_rows_frfcfs`)
-    — one pass per (trace, policy-group) instead of permute + gather +
-    replay, with the pending buffer shrunk to each group's exact
-    `_eff_window` bound.  Non-reordering policies replay via the plain
-    lane-major scan.  Latencies land in ISSUE order, exactly like the
-    prepass pipeline's permuted streams — the statistics reduce the
-    same multiset in the same order, so the two fast paths are
-    bit-identical cell for cell.
-
-    `fault` (optional) = (fault_rows [S, faults.F_COLS], jedec_row
-    [6], uniforms [T, N]) per-lane fault scenarios: the uniforms are
-    consumed positionally by ISSUE step in both cores, so the fused
-    and prepass pipelines stay bit-identical; the return gains the
-    [T, P, S, faults.N_COUNTERS] int32 counter grid."""
-    t, n = arrival.shape
-    p = closed.shape[0]
-    s = timings.shape[0]
-    n_ch, n_rk, t_burst, groups = split_chan(chan)
-    if groups is not None:
-        raise BankGroupError(
-            "backend='merged': replay_rows_frfcfs carries no rank or "
-            "bank-group state; replay with backend='scan' or 'pallas'")
-    il = (jnp.zeros((p,), jnp.int32) if ileave is None
-          else jnp.asarray(ileave, jnp.int32))
-    lat = jnp.zeros((t, p, s, n))
-    total = jnp.zeros((t, p, s))
-    cnt = (None if fault is None
-           else jnp.zeros((t, p, s, faults.N_COUNTERS), jnp.int32))
-    u_tn = None if fault is None else fault[2]
-    grouped: set[int] = set()
-    for _, _, idx in reorder_plan:
-        grouped.update(idx)
-    ident = tuple(j for j in range(p) if j not in grouped)
-
-    if ident:
-        sel = np.asarray(ident, np.int32)
-
-        def plain(a, b, r, w, v, c, i_, uu=None):
-            fl = None if fault is None else (fault[0], fault[1], uu)
-            return replay_rows(a, b, r, w, v, timings, c, n_banks,
-                               mlp_window, n_channels=n_ch,
-                               n_ranks=n_rk, ileave=i_, t_burst=t_burst,
-                               fault=fl, region_map=region_map)
-
-        f_p = jax.vmap(plain, in_axes=(None,) * 5 + (0, 0, None))
-        f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, 0, None, None, 0))
-        out = f_tp(arrival, bank, row, is_write, valid, closed[sel],
-                   il[sel], u_tn)
-        lat = lat.at[:, sel].set(out[0])
-        total = total.at[:, sel].set(out[1])
-        if fault is not None:       # [T, Psel, NC, S] -> [T,Psel,S,NC]
-            cnt = cnt.at[:, sel].set(out[2].transpose(0, 1, 3, 2))
-
-    for window, eff, idx in reorder_plan:
-        sel = np.asarray(idx, np.int32)
-
-        def fused(a, b, r, w, v, c, s_, cp, i_, uu=None, _w=window,
-                  _e=eff):
-            fl = None if fault is None else (fault[0], fault[1], uu)
-            return replay_rows_frfcfs(a, b, r, w, v, timings, c, _w,
-                                      s_, cp, min(_e, n), n_banks,
-                                      mlp_window, all_valid=all_valid,
-                                      n_channels=n_ch, n_ranks=n_rk,
-                                      ileave=i_, t_burst=t_burst,
-                                      fault=fl, region_map=region_map)
-
-        f_p = jax.vmap(fused, in_axes=(None,) * 5 + (0, 0, 0, 0, None))
-        f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, 0, None, None, None,
-                                      None, 0))
-        out = f_tp(arrival, bank, row, is_write, valid, closed[sel],
-                   slacks[sel], caps[sel], il[sel], u_tn)
-        lat = lat.at[:, sel].set(out[0])
-        total = total.at[:, sel].set(out[1])
-        if fault is not None:
-            cnt = cnt.at[:, sel].set(out[2].transpose(0, 1, 3, 2))
-    if fault is None:
-        return lat, total
-    return lat, total, cnt
 
 
 def _p99_k(valid: np.ndarray) -> int:
@@ -719,19 +629,16 @@ def _synth_streams(synth, inputs=()):
 
 
 def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
-                 p99_k, bs, arrival, bank, row, is_write, valid,
-                 timings, closed, slacks, caps, all_valid=False,
-                 chan=(1, 1, 5.0), ileave=None, fault=None,
-                 region_map=None):
+                 p99_k, arrival, bank, row, is_write, valid, timings,
+                 closed, slacks, caps, chan=(1, 1, 5.0), ileave=None,
+                 fault=None, region_map=None):
     """Shared static-timing replay body (traced under a jit wrapper):
     replay every (trace, policy, timing row) cell and reduce.
 
     Fast path: arrival/bank/row/is_write are [T, N] FCFS streams; the
     FR-FCFS prepass (`reorder_plan` non-empty) materializes the
-    [T, P, N] per-policy issue orders on device, or — with
-    backend="merged" — the scheduler fuses into the replay scan and no
-    [T, P, N] streams ever materialize.  Reference path: the arrays
-    arrive [T, P, N], already host-reordered, with an empty plan.
+    [T, P, N] per-policy issue orders on device.  Reference path: the
+    arrays arrive [T, P, N], already host-reordered, with an empty plan.
     valid: [T, N] (shared across policies — reordering permutes only
     the valid prefix); timings: [S, 6] or per-bank [S, B, 6];
     closed/slacks/caps: [P].  `want` (static) selects the outputs:
@@ -739,10 +646,8 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
     raw [T, P, S, N] latency grid; total runtime [T, P, S] is always
     returned (an exact max reduction, so its in-dispatch order cannot
     perturb bits).  `backend` (static) picks the replay core: "scan"
-    is the lane-stacked `dram_sim.replay_rows` lax.scan, "merged" the
-    scheduler-fused `dram_sim.replay_rows_frfcfs` scan,
-    "pallas"/"pallas_interpret" the `repro.kernels.replay` kernel
-    (lane-block size `bs`, None = kernel default).
+    is the lane-stacked `dram_sim.replay_rows` lax.scan,
+    "pallas"/"pallas_interpret" the `repro.kernels.replay` kernel.
 
     `fault` (optional) = (fault_rows [S, faults.F_COLS], jedec_row
     [6], threefry key): per-LANE fault scenarios — the engine expands
@@ -775,49 +680,40 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
         f_rows, j_row, fkey = fault
         u = faults.fault_uniforms(fkey, valid.shape[0], valid.shape[1])
         fault = (f_rows, j_row, u)
-    if backend == "merged" and arrival.ndim == 2:
-        res = _merged_replay(
-            arrival, bank, row, is_write, valid, timings, closed,
-            slacks, caps, reorder_plan, n_banks, mlp_window, all_valid,
-            chan=chan, ileave=il, fault=fault, region_map=region_map)
+    if arrival.ndim == 2:
+        a3, b3, r3, w3 = _reorder_prepass(
+            arrival, bank, row, is_write, valid, slacks, caps,
+            reorder_plan, n_banks, closed.shape[0], backend)
+    else:
+        a3, b3, r3, w3 = arrival, bank, row, is_write
+
+    if backend == "scan":
+        def one(a, b, r, w, v, c, i_, uu=None):
+            fl = None if fault is None else (f_rows, j_row, uu)
+            return replay_rows(a, b, r, w, v, timings, c, n_banks,
+                               mlp_window, n_channels=n_ch,
+                               n_ranks=n_rk, ileave=i_,
+                               t_burst=t_burst, fault=fl,
+                               region_map=region_map, groups=groups)
+
+        f_p = jax.vmap(one, in_axes=(0, 0, 0, 0, None, 0, 0, None))
+        f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, 0, None, None, 0))
+        res = f_tp(a3, b3, r3, w3, valid, closed, il,
+                   None if fault is None else u)
+        lat, total = res[:2]
+        if fault is not None:       # [T, P, NC, S] -> [T, P, S, NC]
+            cnt = res[2].transpose(0, 1, 3, 2)
+    else:
+        from repro.kernels.replay import ops as replay_ops
+        res = replay_ops.replay_grid(
+            a3, b3, r3, w3, valid, timings, closed, n_banks,
+            mlp_window, impl=backend, chan=chan, ileave=il,
+            fault=fault, region_map=region_map)
         lat, total = res[:2]
         if fault is not None:
             cnt = res[2]
-    else:
-        if arrival.ndim == 2:
-            a3, b3, r3, w3 = _reorder_prepass(
-                arrival, bank, row, is_write, valid, slacks, caps,
-                reorder_plan, n_banks, closed.shape[0], backend)
-        else:
-            a3, b3, r3, w3 = arrival, bank, row, is_write
-
-        if backend in ("scan", "merged"):
-            def one(a, b, r, w, v, c, i_, uu=None):
-                fl = None if fault is None else (f_rows, j_row, uu)
-                return replay_rows(a, b, r, w, v, timings, c, n_banks,
-                                   mlp_window, n_channels=n_ch,
-                                   n_ranks=n_rk, ileave=i_,
-                                   t_burst=t_burst, fault=fl,
-                                   region_map=region_map, groups=groups)
-
-            f_p = jax.vmap(one, in_axes=(0, 0, 0, 0, None, 0, 0, None))
-            f_tp = jax.vmap(f_p, in_axes=(0, 0, 0, 0, 0, None, None, 0))
-            res = f_tp(a3, b3, r3, w3, valid, closed, il,
-                       None if fault is None else u)
-            lat, total = res[:2]
-            if fault is not None:   # [T, P, NC, S] -> [T, P, S, NC]
-                cnt = res[2].transpose(0, 1, 3, 2)
-        else:
-            from repro.kernels.replay import ops as replay_ops
-            res = replay_ops.replay_grid(
-                a3, b3, r3, w3, valid, timings, closed, n_banks,
-                mlp_window, impl=backend, bs=bs, chan=chan, ileave=il,
-                fault=fault, region_map=region_map)
-            lat, total = res[:2]
-            if fault is not None:
-                cnt = res[2]
-        if groups is not None:
-            stall = res[2]
+    if groups is not None:
+        stall = res[2]
 
     out = {"total": total}
     if stall is not None:
@@ -832,7 +728,7 @@ def _static_body(n_banks, mlp_window, reorder_plan, backend, want,
 
 
 def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
-                   p99_k, bs, arrival, bank, row, is_write, valid,
+                   p99_k, arrival, bank, row, is_write, valid,
                    tables, bins, scns, tcfg, closed, slacks, caps,
                    chan=(1, 1, 5.0), ileave=None, fault=None,
                    region_map=None):
@@ -852,10 +748,8 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
     kernel (`repro.kernels.replay`), whose OWN accumulator tiles
     produce the thermal diagnostics on-device — the raw O(grid * N)
     temperature/bin traces only materialize when "temps"/"bins" are
-    asked for.  "scan"/"merged" run the vmapped
-    `dram_sim.replay_adaptive` scan (the scheduler-fused merged core
-    is static-timing only, so "merged" degrades to the scan + prepass
-    here).
+    asked for.  "scan" runs the vmapped `dram_sim.replay_adaptive`
+    scan.
 
     `fault` (optional) = (fault_rows [F, faults.F_COLS], threefry
     key): the fault axis rides INNERMOST (a trailing F grid axis on
@@ -900,7 +794,7 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
         emit_raw = ("temps" in want) or ("bins" in want)
         res = replay_ops.replay_grid_adaptive(
             a3, b3, r3, w3, valid, tables, bins, scns, tcfg,
-            closed, n_banks, mlp_window, impl=backend, bs=bs,
+            closed, n_banks, mlp_window, impl=backend,
             emit_raw=emit_raw, fault=fault, region_map=region_map)
         lat, total, temps, bin_sel, bank_heat, diag = res[:6]
         if fault is not None:
@@ -971,36 +865,33 @@ def _adaptive_body(n_banks, mlp_window, reorder_plan, backend, want,
     return out
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 def _replay_grid(synth, n_banks, mlp_window, reorder_plan, backend,
-                 want, p99_k, bs, chan, arrival, bank, row, is_write,
-                 valid, timings, closed, slacks, caps, ileave,
-                 region_map=None, fault=None, synth_inputs=()):
+                 want, p99_k, chan, arrival, bank, row, is_write, valid,
+                 timings, closed, slacks, caps, ileave, region_map=None,
+                 fault=None, synth_inputs=()):
     """ONE dispatch: (optional in-dispatch trace synthesis +) static
     replay grid — see `_static_body`.  `synth` (static) is None for
     materialized streams, or the campaign's `dram_sim.SynthSpec` /
     `TenantSpec`: the stream/valid arguments are then ignored
     placeholders and the FCFS streams are synthesized INSIDE this same
-    dispatch (every synthetic trace is full-length, which also unlocks
-    the merged core's rolling-ring `all_valid` form).  `chan` (static)
-    is the `SimSpec.chan` channel geometry; `ileave` the per-policy
-    interleave-code column; `fault` the optional (fault_rows,
-    jedec_row, key) lane expansion of `_static_body`; `synth_inputs`
-    the device arrays the synthesis reads (`_synth_inputs`)."""
-    all_valid = synth is not None
-    if all_valid:
+    dispatch.  `chan` (static) is the `SimSpec.chan` channel geometry;
+    `ileave` the per-policy interleave-code column; `fault` the
+    optional (fault_rows, jedec_row, key) lane expansion of
+    `_static_body`; `synth_inputs` the device arrays the synthesis
+    reads (`_synth_inputs`)."""
+    if synth is not None:
         arrival, bank, row, is_write, valid = _synth_streams(
             synth, synth_inputs)
     return _static_body(n_banks, mlp_window, reorder_plan, backend,
-                        want, p99_k, bs, arrival, bank, row, is_write,
-                        valid, timings, closed, slacks, caps,
-                        all_valid=all_valid, chan=chan, ileave=ileave,
-                        fault=fault, region_map=region_map)
+                        want, p99_k, arrival, bank, row, is_write, valid,
+                        timings, closed, slacks, caps, chan=chan,
+                        ileave=ileave, fault=fault, region_map=region_map)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 def _replay_grid_adaptive(synth, n_banks, mlp_window, reorder_plan,
-                          backend, want, p99_k, bs, chan, arrival,
+                          backend, want, p99_k, chan, arrival,
                           bank, row, is_write, valid, tables, bins,
                           scns, tcfg, closed, slacks, caps, ileave,
                           region_map=None, fault=None, synth_inputs=()):
@@ -1012,16 +903,16 @@ def _replay_grid_adaptive(synth, n_banks, mlp_window, reorder_plan,
         arrival, bank, row, is_write, valid = _synth_streams(
             synth, synth_inputs)
     return _adaptive_body(n_banks, mlp_window, reorder_plan, backend,
-                          want, p99_k, bs, arrival, bank, row,
+                          want, p99_k, arrival, bank, row,
                           is_write, valid, tables, bins, scns, tcfg,
                           closed, slacks, caps, chan=chan,
                           ileave=ileave, fault=fault,
                           region_map=region_map)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 def _bracket_grid(synth, n_banks, mlp_window, reorder_plan, backend,
-                  p99_k, n_real, bs, chan, arrival, bank, row,
+                  p99_k, n_real, chan, arrival, bank, row,
                   is_write, valid, tables, bins, scns, tcfg, closed,
                   slacks, caps, base_row, ileave, synth_inputs=()):
     """ONE dispatch for the whole adaptive-vs-bracket evaluation
@@ -1044,7 +935,7 @@ def _bracket_grid(synth, n_banks, mlp_window, reorder_plan, backend,
         arrival, bank, row, is_write, valid = _synth_streams(
             synth, synth_inputs)
     out_a = _adaptive_body(n_banks, mlp_window, reorder_plan, backend,
-                           ("stats",), p99_k, bs, arrival, bank, row,
+                           ("stats",), p99_k, arrival, bank, row,
                            is_write, valid, tables, bins, scns, tcfg,
                            closed, slacks, caps, chan=chan,
                            ileave=ileave)
@@ -1058,10 +949,9 @@ def _bracket_grid(synth, n_banks, mlp_window, reorder_plan, backend,
     rows = jnp.concatenate([base[None], jnp.take(tab0, worst, axis=0)],
                            axis=0)
     out_s = _static_body(n_banks, mlp_window, reorder_plan, backend,
-                         ("stats",), p99_k, bs, arrival, bank, row,
+                         ("stats",), p99_k, arrival, bank, row,
                          is_write, valid, rows, closed, slacks, caps,
-                         all_valid=synth is not None, chan=chan,
-                         ileave=ileave)
+                         chan=chan, ileave=ileave)
     return {"adaptive": out_a, "static": out_s, "worst_bin": worst,
             "temp_peak": peak}
 
@@ -1098,7 +988,7 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
 
     `kind` (static): "static" | "adaptive" | "bracket".  `statics`:
     (synth, n_banks, mlp_window, reorder_plan, backend, want, p99_k,
-    bs, chan, n_real).  `per_stream`: the [T]-leading tree — the
+    chan, n_real).  `per_stream`: the [T]-leading tree — the
     packed (arrival, bank, row, is_write, valid) streams, or a
     declarative spec's `stream_knobs()` rows when synthesis is fused
     (each device then synthesizes only its shard, threefry-identical
@@ -1108,7 +998,7 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
     the two replay halves, so worst-bin provisioning still sees the
     GLOBAL peak."""
     P_ = jax.sharding.PartitionSpec
-    (synth, n_banks, mlp_window, plan, backend, want, p99_k, bs, chan,
+    (synth, n_banks, mlp_window, plan, backend, want, p99_k, chan,
      n_real) = statics
     sh, rep = P_("campaign"), P_()
 
@@ -1123,22 +1013,22 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
         if kind == "static":
             timings, closed, slacks, caps, ileave, region_map = extras
             return _static_body(
-                n_banks, mlp_window, plan, backend, want, p99_k, bs,
+                n_banks, mlp_window, plan, backend, want, p99_k,
                 arrival, bank, row, is_write, valid, timings, closed,
-                slacks, caps, all_valid=synth is not None, chan=chan,
-                ileave=ileave, region_map=region_map)
+                slacks, caps, chan=chan, ileave=ileave,
+                region_map=region_map)
         if kind == "adaptive":
             (tables, bins, scns, tcfg, closed, slacks, caps, ileave,
              region_map) = extras
             return _adaptive_body(
-                n_banks, mlp_window, plan, backend, want, p99_k, bs,
+                n_banks, mlp_window, plan, backend, want, p99_k,
                 arrival, bank, row, is_write, valid, tables, bins,
                 scns, tcfg, closed, slacks, caps, chan=chan,
                 ileave=ileave, region_map=region_map)
         (tables, bins, scns, tcfg, closed, slacks, caps, base_row,
          ileave) = extras
         out_a = _adaptive_body(
-            n_banks, mlp_window, plan, backend, ("stats",), p99_k, bs,
+            n_banks, mlp_window, plan, backend, ("stats",), p99_k,
             arrival, bank, row, is_write, valid, tables, bins, scns,
             tcfg, closed, slacks, caps, chan=chan, ileave=ileave)
         peak = out_a["temp_max"][:, :, 0, :n_real].max(axis=(0, 1))
@@ -1149,10 +1039,9 @@ def _sharded_grid(mesh, kind, statics, per_stream, extras):
         rows = jnp.concatenate(
             [base[None], jnp.take(tab0, worst, axis=0)], axis=0)
         out_s = _static_body(
-            n_banks, mlp_window, plan, backend, ("stats",), p99_k, bs,
+            n_banks, mlp_window, plan, backend, ("stats",), p99_k,
             arrival, bank, row, is_write, valid, rows, closed, slacks,
-            caps, all_valid=synth is not None, chan=chan,
-            ileave=ileave)
+            caps, chan=chan, ileave=ileave)
         return {"adaptive": out_a, "static": out_s, "worst_bin": worst,
                 "temp_peak": peak}
 
@@ -1238,26 +1127,18 @@ class SimEngine:
 
     Knobs (see module docstring):
 
-      backend — "scan" (default: vmapped lax.scan), "merged"
-                (FR-FCFS fused into the replay scan — no [T, P, N]
-                streams materialize), "pallas" (the
+      backend — "scan" (default: vmapped lax.scan), "pallas" (the
                 repro.kernels.replay kernels compiled for the TPU,
                 static AND single-channel adaptive; raises off the
                 TPU), "pallas_interpret" (the same kernel bodies run
-                on the host), "auto" (the attached `tuner`'s profiled
-                choice, else pallas on TPU — scan for a multi-channel
-                adaptive campaign — and scan elsewhere).
+                on the host), "auto" (pallas on TPU — scan for a
+                multi-channel adaptive campaign — and scan elsewhere).
       stats   — "device" (default: in-dispatch reductions, only
                 [grid]-shaped summaries transferred, raw grids gated
                 by SimSpec.collect) or "host" (bit-exact numpy
                 reference, raw grids always materialized).
       reorder — "device" (default: FR-FCFS prepass inside the
                 dispatch) or "host" (retained Python loop in pack()).
-      tuner   — optional `autotune.ReplayTuner`; `autotune(spec)`
-                profiles every candidate (backend, block_rows,
-                fuse_synth) config on the campaign and records the
-                winner per (campaign kind, size bin), which
-                backend="auto" then consults.
       mesh    — optional `jax.sharding.Mesh` with a "campaign" axis
                 (see `launch.mesh.make_campaign_mesh`): every run then
                 goes through the `shard_map` path, partitioning the
@@ -1268,8 +1149,8 @@ class SimEngine:
                 default device stats + device reorder.
 
     A `SimSpec` whose trace axis is a declarative `dram_sim.SynthSpec`
-    / `TenantSpec` fuses the trace synthesis INTO the dispatch (unless
-    the resolved config says otherwise): synthesis + FR-FCFS + replay
+    / `TenantSpec` fuses the trace synthesis INTO the dispatch (under
+    the device reorder): synthesis + FR-FCFS + replay
     + statistics are then truly one launch — and under a mesh each
     device synthesizes ONLY its shard of streams.
     """
@@ -1278,11 +1159,10 @@ class SimEngine:
     backend: str = "scan"
     stats: str = "device"
     reorder: str = "device"
-    tuner: "ReplayTuner | None" = None
     mesh: "jax.sharding.Mesh | None" = None
 
     def __post_init__(self):
-        assert self.backend in ("auto", "scan", "merged", "pallas",
+        assert self.backend in ("auto", "scan", "pallas",
                                 "pallas_interpret"), self.backend
         assert self.stats in ("device", "host"), self.stats
         assert self.reorder in ("device", "host"), self.reorder
@@ -1290,44 +1170,13 @@ class SimEngine:
             assert "campaign" in self.mesh.axis_names, \
                 "campaign mesh needs a 'campaign' axis"
 
-    def _tuner_key(self, spec: SimSpec):
-        """(campaign-kind unit, request count) — the tuner table key.
-        Region-compressed campaigns tune under the `replay_unit`
-        region offset, with the region count folded into the size
-        condition (the in-scan map gather scales with regions the way
-        dispatch cost scales with N)."""
-        n = (spec.traces.n if spec.synth is not None else
-             max(int(np.asarray(t.arrival).shape[0])
-                 for t in spec.traces))
-        adaptive = spec.thermal is not None
-        banked = (spec.timings.ndim - (1 if adaptive else 0)) == 3
-        regioned = spec.region_map is not None
-        if regioned:
-            n *= spec.region_map.shape[-1] // spec.n_banks
-        return replay_unit(adaptive, banked,
-                           channels=spec.n_channels * spec.n_ranks > 1,
-                           regioned=regioned), n
-
-    def _resolve(self, spec: SimSpec,
-                 config: "ReplayConfig | None" = None):
-        """(backend, fuse_synth, block_rows) for one run: an explicit
-        `config` wins; otherwise backend="auto" + an attached tuner
-        answers with the profiled candidate for this campaign's
-        (kind, size) bin — falling back, AdaptiveTable-style, to
-        candidate 0 (the conservative scan default) on unprofiled
-        bins.  "auto" without a tuner picks the kernel on the TPU
-        (the scan for a multi-channel adaptive campaign, which the
-        single-channel adaptive kernel cannot replay) and the scan
-        elsewhere; an explicit "pallas" off the TPU raises."""
-        cfg = config
-        if cfg is None and self.backend == "auto" and \
-                self.tuner is not None:
-            cfg = self.tuner.lookup(*self._tuner_key(spec))
-        if cfg is None:
-            backend, fuse, bs = self.backend, True, None
-        else:
-            backend, fuse, bs = cfg.backend, cfg.fuse_synth, \
-                cfg.block_rows
+    def _resolve(self, spec: SimSpec) -> str:
+        """The replay core for one run: the explicit `backend`, or for
+        "auto" the kernel on the TPU (the scan for a multi-channel
+        adaptive campaign, which the single-channel adaptive kernel
+        cannot replay) and the scan elsewhere; an explicit "pallas"
+        off the TPU raises."""
+        backend = self.backend
         platform = jax.default_backend()
         if backend == "auto":
             multi_adaptive = (spec.thermal is not None
@@ -1340,15 +1189,7 @@ class SimEngine:
                 f"TPU, and JAX's default backend is {platform!r}; ask "
                 f"for backend='pallas_interpret' to run the kernel "
                 f"bodies on the host")
-        return backend, fuse, bs
-
-    def _backend(self) -> str:
-        return self._resolve(
-            SimSpec(traces=(Trace(np.zeros(1, np.float32),
-                                  np.zeros(1, np.int32),
-                                  np.zeros(1, np.int32),
-                                  np.zeros(1, bool)),),
-                    timings=np.zeros((1, 6), np.float32)))[0]
+        return backend
 
     def _inputs(self, spec: SimSpec):
         """(stream arrays ([T,N] fast / [T,P,N] reference), valid,
@@ -1370,19 +1211,17 @@ class SimEngine:
                 jnp.asarray(spec.closed_flags), jnp.asarray(slacks),
                 jnp.asarray(caps), plan)
 
-    def _streams(self, spec: SimSpec, fuse: bool):
+    def _streams(self, spec: SimSpec):
         """Resolve the campaign streams: returns (synth, arrival, bank,
         row, is_write, valid_device, valid_host, closed, slacks, caps,
-        plan).  When the trace axis is a `SynthSpec` and fusion is on
-        (device reorder only — the host reorder loop needs concrete
-        arrays), the stream slots are scalar placeholders and `synth`
-        carries the static spec into the dispatch; the reorder plan
-        then takes its EXACT buffer caps from the spec's cached
-        materialization when one exists (e.g. warmed by `autotune`) —
-        threefry determinism makes the in-dispatch streams bit-equal
-        to it — and the nominal window otherwise."""
-        synth = spec.synth if (fuse and self.reorder == "device") \
-            else None
+        plan).  When the trace axis is a `SynthSpec` under the device
+        reorder (the host reorder loop needs concrete arrays), the
+        stream slots are scalar placeholders and `synth` carries the
+        static spec into the dispatch; the reorder plan then takes its
+        EXACT buffer caps from the spec's cached materialization when
+        one exists — threefry determinism makes the in-dispatch
+        streams bit-equal to it — and the nominal window otherwise."""
+        synth = spec.synth if self.reorder == "device" else None
         if synth is None:
             return (None,) + self._inputs(spec)
         valid = np.ones((len(synth), synth.n), bool)
@@ -1398,7 +1237,7 @@ class SimEngine:
                 jnp.asarray(caps), plan)
 
     def _dispatch(self, kind, spec, synth, plan, backend, want, p99_k,
-                  bs, streams, extras, n_real=0, fault=None):
+                  streams, extras, n_real=0, fault=None):
         """Route one campaign launch: the plain jitted grid, or — when
         a `mesh` is attached — the `shard_map` path (trace axis
         partitioned across the "campaign" devices, per-stream inputs
@@ -1409,17 +1248,17 @@ class SimEngine:
             if kind == "static":
                 return _replay_grid(synth, spec.n_banks,
                                     spec.mlp_window, plan, backend,
-                                    want, p99_k, bs, chan, *streams,
+                                    want, p99_k, chan, *streams,
                                     *extras, fault=fault,
                                     synth_inputs=_synth_inputs(synth))
             if kind == "adaptive":
                 return _replay_grid_adaptive(
                     synth, spec.n_banks, spec.mlp_window, plan,
-                    backend, want, p99_k, bs, chan, *streams, *extras,
+                    backend, want, p99_k, chan, *streams, *extras,
                     fault=fault, synth_inputs=_synth_inputs(synth))
             return _bracket_grid(synth, spec.n_banks, spec.mlp_window,
-                                 plan, backend, p99_k, n_real, bs,
-                                 chan, *streams, *extras,
+                                 plan, backend, p99_k, n_real, chan,
+                                 *streams, *extras,
                                  synth_inputs=_synth_inputs(synth))
         assert fault is None, \
             "fault campaigns are single-device (no mesh sharding yet)"
@@ -1435,7 +1274,7 @@ class SimEngine:
         n = synth.n if synth is not None else streams[0].shape[-1]
         self.shard_shape = (n_dev, t_pad // n_dev, int(n))
         statics = (synth, spec.n_banks, spec.mlp_window, plan, backend,
-                   want, p99_k, bs, chan, n_real)
+                   want, p99_k, chan, n_real)
         out = _sharded_grid(self.mesh, kind, statics, per_stream,
                             extras)
         if kind == "bracket":
@@ -1446,42 +1285,11 @@ class SimEngine:
                     "temp_peak": out["temp_peak"]}
         return {k: v[:t] for k, v in out.items()}
 
-    def autotune(self, spec: SimSpec, reps: int = 3) -> "ReplayConfig":
-        """Profile every candidate replay configuration on THIS
-        campaign and record the winner in the tuner's table (persisted
-        to disk), which `backend="auto"` consults on later runs of any
-        same-kind/size campaign.  Creates a platform-default
-        `ReplayTuner` when none is attached.  Materializes a
-        `SynthSpec` trace axis once up front, so the reorder plan gets
-        its exact buffer caps for BOTH the profiled and the later
-        fused runs.  Dispatch accounting stays honest — each profiling
-        run increments `dispatch_count` like any other, so call this
-        during warmup, not inside a measured section."""
-        import time
-        if self.tuner is None:
-            self.tuner = ReplayTuner(platform=jax.default_backend())
-        if spec.synth is not None:
-            spec.trace_tuple()    # warm cache -> exact reorder caps
-        unit, n = self._tuner_key(spec)
-
-        def measure(cfg: "ReplayConfig") -> float:
-            self.run(spec, config=cfg)            # compile + warm
-            best = np.inf
-            for _ in range(max(1, reps)):
-                t0 = time.perf_counter()
-                self.run(spec, config=cfg)
-                best = min(best, time.perf_counter() - t0)
-            return best
-
-        cfg, _ = self.tuner.tune(unit, n, measure)
-        return cfg
-
-    def run(self, spec: SimSpec,
-            config: "ReplayConfig | None" = None) -> SimResult:
-        backend, fuse, bs = self._resolve(spec, config)
+    def run(self, spec: SimSpec) -> SimResult:
+        backend = self._resolve(spec)
         with span("sim.prep") as s:
             (synth, arrival, bank, row, is_write, valid_d, valid, closed,
-             slacks, caps, plan) = self._streams(spec, fuse)
+             slacks, caps, plan) = self._streams(spec)
             s.count(streams=valid.shape[0], requests=valid.size)
             if spec.grouped:
                 s.count(ranks=spec.n_channels * spec.n_ranks,
@@ -1516,7 +1324,7 @@ class SimEngine:
                       else jnp.asarray(spec.region_map))
                 out = self._dispatch(
                     "static", spec, synth, plan, backend, want,
-                    _p99_k(valid), bs,
+                    _p99_k(valid),
                     (arrival, bank, row, is_write, valid_d),
                     (jnp.asarray(timings), closed, slacks, caps,
                      jnp.asarray(spec.ileave_codes), rm), fault=fault)
@@ -1567,7 +1375,7 @@ class SimEngine:
                   **_prepass_lanes(plan, backend, valid.shape[0])):
             out = self._dispatch(
                 "adaptive", spec, synth, plan, backend, want,
-                _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
+                _p99_k(valid), (arrival, bank, row, is_write, valid_d),
                 (jnp.asarray(spec.timings), jnp.asarray(bins),
                  jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
                  caps, jnp.asarray(spec.ileave_codes),
@@ -1619,8 +1427,7 @@ class SimEngine:
                              fault_counters=cnt)
 
     def run_bracket(self, spec: SimSpec, base_row,
-                    n_real: int | None = None,
-                    config: "ReplayConfig | None" = None) -> dict:
+                    n_real: int | None = None) -> dict:
         """The adaptive-vs-static-worst-case bracket
         (`perf_model.evaluate_adaptive`'s two replay launches) as ONE
         dispatch: the adaptive campaign runs, its per-scenario
@@ -1643,10 +1450,10 @@ class SimEngine:
             "run_bracket carries no fault axis — run() the faulted spec"
         assert spec.region_map is None, \
             "run_bracket carries no region axis — run() the spec"
-        backend, fuse, bs = self._resolve(spec, config)
+        backend = self._resolve(spec)
         with span("sim.prep") as s:
             (synth, arrival, bank, row, is_write, valid_d, valid, closed,
-             slacks, caps, plan) = self._streams(spec, fuse)
+             slacks, caps, plan) = self._streams(spec)
             s.count(streams=valid.shape[0], requests=valid.size)
         scns, bins, tcfg = spec.thermal.pack()
         n_real = len(scns) if n_real is None else int(n_real)
@@ -1655,7 +1462,7 @@ class SimEngine:
                   **_prepass_lanes(plan, backend, valid.shape[0])):
             out = self._dispatch(
                 "bracket", spec, synth, plan, backend, ("stats",),
-                _p99_k(valid), bs, (arrival, bank, row, is_write, valid_d),
+                _p99_k(valid), (arrival, bank, row, is_write, valid_d),
                 (jnp.asarray(spec.timings), jnp.asarray(bins),
                  jnp.asarray(scns), jnp.asarray(tcfg), closed, slacks,
                  caps, jnp.asarray(base_row, jnp.float32),
@@ -1686,5 +1493,4 @@ def default_engine() -> SimEngine:
 
 
 __all__ = ["Policy", "OPEN_FCFS", "SimSpec", "SimResult", "SimEngine",
-           "SynthSpec", "TenantSpec", "ThermalSpec", "ReplayConfig",
-           "ReplayTuner", "default_engine"]
+           "SynthSpec", "TenantSpec", "ThermalSpec", "default_engine"]

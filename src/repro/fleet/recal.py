@@ -314,31 +314,6 @@ class FleetEngine:
         rows[..., 4] = np.minimum.accumulate(rows[..., 4], axis=1)
         return rows
 
-    # ----------------------------------------------------- autotuning
-    def autotune_epoch(self, reps: int = 3):
-        """Profile the replay configuration on an EPOCH-SHAPED
-        campaign — the exact [1 + modules, banks, 6] per-bank timing
-        stack and workload set every serve step replays — and record
-        the winner in the sim engine's tuner table under the
-        per-bank-static campaign kind and the epoch's request-count
-        size bin.  A `SimEngine(backend="auto", tuner=...)` fleet then
-        serves every epoch with the profiled config (the serve-time
-        `SimSpec` resolves to the same tuner key).  Dispatch
-        accounting stays honest — profiling runs count — so call this
-        before `run()`, never inside a measured section.  Returns the
-        winning `ReplayConfig`."""
-        spec = self.spec
-        banks = self.pop.n_banks
-        tb = trace_batch(spec.n_requests, spec.seed, banks)
-        traces = tuple(Trace(*(np.asarray(f)[i] for f in tb))
-                       for i in spec.workload_rows)
-        timings = np.broadcast_to(
-            self._jrow, (1 + self.pop.n_modules, banks, 6)
-        ).astype(np.float32)
-        return self.sim.autotune(
-            SimSpec(traces=traces, timings=timings, n_banks=banks),
-            reps=reps)
-
     def _install(self, table: TimingTable, rows_bins: np.ndarray,
                  idx: np.ndarray | None = None) -> TimingTable:
         """Deploy `rows_bins` as a new table VERSION via

@@ -211,7 +211,6 @@ REFUSED = {
     "frfcfs_prepass": (dict(policies=(Policy(reorder_window=16),)), {}),
     "frfcfs_host": (dict(policies=(Policy(reorder_window=16),)),
                     dict(reorder="host")),
-    "merged": ({}, dict(backend="merged")),
     "faulted": (dict(faults=faults.FaultSpec(scenarios=(
         faults.FaultScenario(name="errors", err_scale=1.0),))), {}),
     "regioned": (dict(timings=np.repeat(
@@ -247,4 +246,4 @@ def test_run_bracket_refuses():
 def test_auto_sends_a_grouped_campaign_to_the_kernel(monkeypatch):
     spec = _kv_spec(bank_groups=4, rank_timings=DDR4_2400_RANK)
     monkeypatch.setattr(sim_engine.jax, "default_backend", lambda: "tpu")
-    assert SimEngine(backend="auto")._resolve(spec)[0] == "pallas"
+    assert SimEngine(backend="auto")._resolve(spec) == "pallas"

@@ -3,7 +3,7 @@
 Three brackets:
   * ZERO-FAULT PARITY — `SimSpec(faults=FaultSpec.none())` is
     bit-identical to a spec with NO fault axis on every backend
-    (static scan/merged/pallas_interpret and adaptive
+    (static scan/pallas_interpret and adaptive
     scan/pallas_interpret, device and host stats), up to the trailing
     F=1 axis.  The no-fault spec compiles the EXACT unfaulted code
     path — the same static-branch pinning as the C*R==1 channel case.
@@ -70,7 +70,7 @@ SENS = faults.FaultSpec(scenarios=(
                          wd_jump_c=8.0, wd_probe=12, wd_recover_n=2),
 ), seed=7)
 
-STATIC_BACKENDS = ("scan", "merged", "pallas_interpret")
+STATIC_BACKENDS = ("scan", "pallas_interpret")
 ADAPTIVE_BACKENDS = ("scan", "pallas_interpret")
 
 
@@ -182,7 +182,7 @@ class TestFaultedStatic:
         # detect_frac=1.0: the watchdog lane never corrupts silently
         assert rf.silent_errors[..., 2].sum() == 0
 
-    @pytest.mark.parametrize("be", ("merged", "pallas_interpret"))
+    @pytest.mark.parametrize("be", ("pallas_interpret",))
     def test_cross_backend_bit_exact(self, static_res, be):
         a, b = static_res["scan"][2], static_res[be][2]
         assert np.array_equal(a.fault_counters, b.fault_counters)

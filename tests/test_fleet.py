@@ -297,51 +297,3 @@ class TestRegionFleet:
         assert idx is not None and idx.shape == (4, pop.n_banks, 2)
         dense = FleetEngine._dense(rows[:, 0], idx)
         assert dense.shape == (4, pop.n_banks, 2, 6)
-
-
-class TestEpochAutotune:
-    """`FleetEngine.autotune_epoch` profiles the EXACT epoch-shaped
-    campaign ([1 + modules, banks, 6] per-bank stack, the spec's
-    workload set and request count) and the serving loop then consults
-    the tuner under that same key on every epoch dispatch."""
-
-    def test_autotune_records_epoch_key_and_serve_consults_it(self,
-                                                              tmp_path):
-        from repro.core.autotune import ReplayTuner, replay_unit
-        from repro.core.sim_engine import SimEngine
-
-        cfg = tiny_cfg(4, 3)
-        pop = sample_population(jax.random.PRNGKey(7), cfg)
-        spec = FleetSpec(n_epochs=2, workload_rows=(0,),
-                         n_requests=256, seed=0)
-        tuner = ReplayTuner(platform="cpu",
-                            path=str(tmp_path / "tune.json"))
-        sim = SimEngine(backend="auto", tuner=tuner)
-        eng = FleetEngine(pop, spec, var_cfg=cfg, sim=sim)
-
-        # the epoch campaign is per-bank static single-channel
-        unit = replay_unit(adaptive=False, banked=True, channels=False)
-        b = tuner.table._bin(tuner._condition(spec.n_requests))
-        assert (unit, b) not in tuner.table._table
-        winner = eng.autotune_epoch(reps=1)
-        assert (unit, b) in tuner.table._table, \
-            "autotune_epoch must record the epoch-shaped size bin"
-        assert winner in tuner.candidates
-        # a fresh tuner loads the persisted entry back
-        assert ReplayTuner(platform="cpu",
-                           path=str(tmp_path / "tune.json")).lookup(
-                               unit, spec.n_requests) == winner
-
-        # spy: every serving-epoch dispatch resolves its config
-        # through the tuner with the epoch key
-        seen = []
-        orig = tuner.lookup
-
-        def spy(unit_, n_):
-            seen.append((unit_, n_))
-            return orig(unit_, n_)
-
-        tuner.lookup = spy
-        eng.run()
-        assert len(seen) >= spec.n_epochs
-        assert all(k == (unit, spec.n_requests) for k in seen), seen

@@ -1,6 +1,5 @@
-"""Fused synth->replay dispatch, exact FR-FCFS buffer shrink, replay
-autotuner, and the padding-suffix invariant: the PR-7 fast-path
-contracts.
+"""Fused synth->replay dispatch, exact FR-FCFS buffer shrink and the
+padding-suffix invariant: the fast-path contracts.
 
   * a `SynthSpec` trace axis makes synthesis part of the ONE replay
     dispatch, bit-identical to materializing the batch first
@@ -10,8 +9,6 @@ contracts.
     two-dispatch host formulation;
   * `_eff_window` shrinks the FR-FCFS pending buffer to its exact
     slack-horizon bound without changing the permutation;
-  * `ReplayTuner` round-trips its table through JSON and falls back
-    to the conservative scan default on unprofiled bins;
   * interior-invalid masks are rejected loudly everywhere a replay
     layout would silently desynchronize.
 """
@@ -22,7 +19,6 @@ import numpy as np
 import pytest
 
 from repro.core import dram_sim, perf_model
-from repro.core.autotune import ReplayConfig, ReplayTuner, replay_unit
 from repro.core.dram_sim import OPEN_FCFS, Policy, SynthSpec, Trace
 from repro.core.sim_engine import SimEngine, SimSpec, _eff_window
 from repro.core.thermal import (ThermalConfig, ThermalSpec, diurnal,
@@ -184,54 +180,6 @@ class TestEffWindow:
         arr = np.array([[5.0, 3.0, 8.0]], np.float32)
         valid = np.ones((1, 3), bool)
         assert _eff_window(arr, valid, 16, 30.0) == 16
-
-
-class TestReplayTuner:
-    def test_roundtrip_and_fallback(self, tmp_path):
-        path = str(tmp_path / "tune.json")
-        tuner = ReplayTuner(platform="cpu", path=path)
-        assert tuner.candidates[0] == ReplayConfig("scan")
-        # unprofiled bin -> the conservative scan default
-        assert tuner.lookup(replay_unit(False, False), 1024) == \
-            ReplayConfig("scan")
-
-        def measure(cfg):
-            return 1.0 if cfg.backend == "merged" and cfg.fuse_synth \
-                else 2.0
-
-        best, times = tuner.tune(replay_unit(False, False), 1024,
-                                 measure)
-        assert best == ReplayConfig("merged")
-        assert len(times) == len(tuner.candidates)
-        assert tuner.lookup(replay_unit(False, False), 1024) == best
-        # other units stay at the default
-        assert tuner.lookup(replay_unit(True, False), 1024) == \
-            ReplayConfig("scan")
-        # a fresh tuner reloads the profile from disk
-        again = ReplayTuner(platform="cpu", path=path)
-        assert again.lookup(replay_unit(False, False), 1024) == best
-        # a tuner with a DIFFERENT candidate list must drop the stale
-        # profile instead of dereferencing foreign indices
-        other = ReplayTuner(platform="cpu", path=path,
-                            candidates=(ReplayConfig("scan"),))
-        assert other.lookup(replay_unit(False, False), 1024) == \
-            ReplayConfig("scan")
-
-    def test_engine_auto_consults_tuner(self, tmp_path):
-        synth = _small_synth()
-        rows = stack_timing([DDR3_1600, ALDRAM_55C_EVAL])
-        spec = SimSpec(traces=synth, timings=rows,
-                       policies=(Policy(reorder_window=8),))
-        eng = SimEngine(backend="auto",
-                        tuner=ReplayTuner(platform="cpu", path=""))
-        tuned = eng.autotune(spec, reps=1)
-        assert tuned in eng.tuner.candidates
-        ref = SimEngine().run(spec)
-        res = eng.run(spec)
-        np.testing.assert_allclose(res.mean_latency_ns,
-                                   ref.mean_latency_ns, rtol=1e-5)
-        np.testing.assert_allclose(res.total_ns, ref.total_ns,
-                                   rtol=1e-5)
 
 
 class TestPrefixInvariant:

@@ -3,7 +3,7 @@
 region-map gather correctness in-scan, the lossless unique-rows
 compressor, `TimingTable.patch` shape/rank validation, the region
 controller end-to-end (profile -> levels -> verify -> one-dispatch
-system evaluation), and the autotuner's region campaign units."""
+system evaluation)."""
 
 import dataclasses
 
@@ -84,26 +84,26 @@ def assert_identical(ra, rb, fields=("total_ns", "mean_latency_ns",
         assert np.array_equal(np.asarray(a), np.asarray(b)), f
 
 
+BACKENDS = ("scan", "pallas_interpret")
+
+
 class TestRegionsOneBitIdentity:
     """Acceptance: `regions=1` (an identity region map over the
     per-bank stack) compiles the EXACT per-bank path — bit-identical
     latencies on every backend, static and adaptive, faults on/off."""
 
-    BACKENDS = ("scan", "merged", "pallas_interpret")
-
-    def test_static_identity_map_every_backend(self):
+    @pytest.mark.parametrize("be", BACKENDS)
+    def test_static_identity_map_every_backend(self, be):
         rows = bank_rows()
         traces = (synth(0, 256), synth(1, 129, row_hit=0.2))
         idmap = np.arange(N_BANKS, dtype=np.int32)
-        for be in self.BACKENDS:
-            eng = SimEngine(backend=be)
-            rb = eng.run(SimSpec(traces=traces, timings=rows,
-                                 collect=("latencies",)))
-            rr = eng.run(SimSpec(traces=traces, timings=rows,
-                                 region_map=idmap,
-                                 collect=("latencies",)))
-            assert_identical(rb, rr)
-            assert np.array_equal(rb.latencies, rr.latencies), be
+        eng = SimEngine(backend=be)
+        rb = eng.run(SimSpec(traces=traces, timings=rows,
+                             collect=("latencies",)))
+        rr = eng.run(SimSpec(traces=traces, timings=rows,
+                             region_map=idmap, collect=("latencies",)))
+        assert_identical(rb, rr)
+        assert np.array_equal(rb.latencies, rr.latencies)
 
     def test_static_per_lane_identity_map(self):
         """A 2-dim [S, banks] identity map (one map per timing lane)
@@ -134,26 +134,25 @@ class TestRegionsOneBitIdentity:
         return (SimSpec(timings=stack_b, **kw),
                 SimSpec(timings=stack_b, region_map=idmap, **kw))
 
-    def test_adaptive_identity_map(self):
-        for be in ("scan", "pallas_interpret"):
-            eng = SimEngine(backend=be)
-            sb, sr = self._adaptive_specs()
-            rb, rr = eng.run(sb), eng.run(sr)
-            assert_identical(rb, rr)
-            assert np.array_equal(rb.latencies, rr.latencies), be
-            assert np.array_equal(rb.bins, rr.bins), be
-            assert np.array_equal(rb.bank_heat, rr.bank_heat), be
+    @pytest.mark.parametrize("be", BACKENDS)
+    def test_adaptive_identity_map(self, be):
+        eng = SimEngine(backend=be)
+        sb, sr = self._adaptive_specs()
+        rb, rr = eng.run(sb), eng.run(sr)
+        assert_identical(rb, rr)
+        assert np.array_equal(rb.latencies, rr.latencies)
+        assert np.array_equal(rb.bins, rr.bins)
+        assert np.array_equal(rb.bank_heat, rr.bank_heat)
 
-    def test_adaptive_identity_map_with_faults(self):
-        for be in ("scan", "pallas_interpret"):
-            eng = SimEngine(backend=be)
-            sb, sr = self._adaptive_specs(ACTIVE)
-            rb, rr = eng.run(sb), eng.run(sr)
-            assert_identical(rb, rr)
-            assert np.array_equal(rb.latencies, rr.latencies), be
-            assert np.array_equal(rb.fault_counters,
-                                  rr.fault_counters), be
-            assert rr.detected_errors.sum() > 0    # the axis is live
+    @pytest.mark.parametrize("be", BACKENDS)
+    def test_adaptive_identity_map_with_faults(self, be):
+        eng = SimEngine(backend=be)
+        sb, sr = self._adaptive_specs(ACTIVE)
+        rb, rr = eng.run(sb), eng.run(sr)
+        assert_identical(rb, rr)
+        assert np.array_equal(rb.latencies, rr.latencies)
+        assert np.array_equal(rb.fault_counters, rr.fault_counters)
+        assert rr.detected_errors.sum() > 0        # the axis is live
 
     def test_adaptive_per_stack_identity_map(self):
         """A [K, G] per-stack map rides the table axis."""
@@ -215,13 +214,11 @@ class TestRegionGather:
                        policies=(dram_sim.OPEN_FCFS,
                                  dram_sim.Policy(page="closed")))
         ref = SimEngine(backend="scan").run(spec)
-        for be in ("merged", "pallas_interpret"):
-            res = SimEngine(backend=be).run(spec)
-            for f in ("total_ns", "mean_latency_ns", "p99_latency_ns"):
-                np.testing.assert_allclose(
-                    np.asarray(getattr(res, f)),
-                    np.asarray(getattr(ref, f)), rtol=1e-5,
-                    err_msg=f"{be}:{f}")
+        res = SimEngine(backend="pallas_interpret").run(spec)
+        for f in ("total_ns", "mean_latency_ns", "p99_latency_ns"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(res, f)),
+                np.asarray(getattr(ref, f)), rtol=1e-5, err_msg=f)
 
     def test_adaptive_single_region_trace_matches_module_stack(self):
         """The adaptive replay gathers (selected bin, map[bank,
@@ -546,33 +543,3 @@ class TestRegionController:
         # the compressed timing axis really is smaller than dense
         assert res["rows"].shape[1] <= res["region_map"].shape[0]
         assert res["region_map"].shape == (region_pop.n_banks * 4,)
-
-
-class TestTunerRegionUnits:
-    """Satellite: a region-compressed campaign consults the tuner
-    under the `replay_unit` region offset with the region count folded
-    into the size condition."""
-
-    def test_region_spec_consults_region_unit(self):
-        from repro.core.autotune import ReplayTuner, replay_unit
-        tuner = ReplayTuner(platform="cpu", path="")
-        seen = []
-        orig = tuner.lookup
-
-        def spy(unit, n):
-            seen.append((unit, n))
-            return orig(unit, n)
-
-        tuner.lookup = spy
-        eng = SimEngine(backend="auto", tuner=tuner)
-        rows, idmap = region_rows()          # G = 16, regions = 2
-        eng.run(SimSpec(traces=(synth(0, 96),), timings=rows,
-                        region_map=idmap))
-        unit = replay_unit(adaptive=False, banked=True, channels=False,
-                           regioned=True)
-        assert unit == 9                     # 8 (region) + 1 (banked)
-        assert seen == [(unit, 96 * 2)]
-        # the dense per-bank campaign keeps its historical unit
-        seen.clear()
-        eng.run(SimSpec(traces=(synth(0, 96),), timings=bank_rows()))
-        assert seen == [(replay_unit(adaptive=False, banked=True), 96)]

@@ -250,6 +250,39 @@ class TestEngineBackend:
         assert np.array_equal(res_pl.bin_switches, res_sc.bin_switches)
 
 
+class TestResolve:
+    """`backend="auto"` picks the replay core from the platform and
+    the campaign alone: the kernel on the TPU, except for a
+    multi-channel adaptive campaign (the adaptive kernel is
+    single-channel), and the scan elsewhere.  Resolving compiles
+    nothing."""
+
+    @pytest.mark.parametrize("platform,adaptive,channels,expected", [
+        ("tpu", False, 1, "pallas"),
+        ("tpu", False, 2, "pallas"),
+        ("tpu", True, 1, "pallas"),
+        ("tpu", True, 2, "scan"),
+        ("cpu", True, 1, "scan"),
+    ], ids=["tpu-static-1ch", "tpu-static-2ch", "tpu-adaptive-1ch",
+            "tpu-adaptive-2ch", "cpu"])
+    def test_auto(self, monkeypatch, platform, adaptive, channels,
+                  expected):
+        tr = (dram_sim.synth_trace(jax.random.PRNGKey(7), 32),)
+        if adaptive:
+            spec = SimSpec(
+                traces=tr, timings=np.stack([ALDRAM_55C_EVAL.as_row(),
+                                             DDR3_1600.as_row()])[None],
+                n_channels=channels,
+                thermal=ThermalSpec(scenarios=(steady(48.0),),
+                                    temp_bins=(55.0,)))
+        else:
+            spec = SimSpec(traces=tr, timings=DDR3_1600,
+                           n_channels=channels)
+        monkeypatch.setattr(sim_engine.jax, "default_backend",
+                            lambda: platform)
+        assert SimEngine(backend="auto")._resolve(spec) == expected
+
+
 class TestNoSilentFallback:
     """Off the TPU a request for the compiled kernels raises; nothing
     reroutes it to interpret mode, the scan or the jnp reference."""
@@ -298,11 +331,6 @@ class TestNoSilentFallback:
         # the scan replays it when asked
         res = SimEngine(backend="scan").run(spec)
         assert np.isfinite(res.mean_latency_ns).all()
-
-    def test_tuner_refuses_unknown_platform(self):
-        from repro.core.autotune import ReplayTuner
-        with pytest.raises(ValueError, match="no candidate list"):
-            ReplayTuner(platform="gpu", path="")
 
     def test_request_count_above_chip_limit_raises(self):
         """Above the SMEM/VMEM bound a launch fails with a clear error
